@@ -8,14 +8,19 @@ threaded decode/augment/normalize + a prefetching ring buffer that keeps
 the chips fed, SURVEY.md §7).
 
 The library is compiled on first use with g++ (no pybind11 — plain C ABI
-via ctypes) and cached under native/build/. Every entry point has a
-pure-Python fallback so the package works without a toolchain:
-`available()` reports which plane is active.
+via ctypes) from native/dataplane.cpp AS THE CHECKOUT HAS IT: the cached
+object under native/build/ carries the source's hash in its name, so a
+library left on disk by another source tree is never loaded. Every entry
+point has a pure-Python plane so the package works without a toolchain;
+when the build fails the reason is logged once and `available()` /
+`.native` report which plane is active.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -26,37 +31,49 @@ import numpy as np
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_ROOT, "native", "dataplane.cpp")
-_SO = os.path.join(_ROOT, "native", "build", "libbigdl_dataplane.so")
+
+logger = logging.getLogger("bigdl_tpu.dataset.native")
 
 _lib = None
 _lib_lock = threading.Lock()
+_unavailable = False    # a failed build is logged once, not retried
 
 
-def _build() -> Optional[str]:
-    if not os.path.exists(_SRC):
-        # prebuilt library without source (installed layout) — use as-is
-        return _SO if os.path.exists(_SO) else None
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-           "-shared", "-o", _SO, _SRC]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    return _SO
+def _build() -> str:
+    """Path of the library built from THIS checkout's source, building
+    it if absent. Raises OSError / SubprocessError when there is no
+    source or no working toolchain."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_ROOT, "native", "build",
+                      f"libbigdl_dataplane-{digest}.so")
+    if not os.path.exists(so):
+        os.makedirs(os.path.dirname(so), exist_ok=True)
+        tmp = f"{so}.{os.getpid()}.tmp"
+        subprocess.run(
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
+             "-shared", "-o", tmp, _SRC],
+            check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)     # atomic: a concurrent builder is harmless
+    return so
 
 
 def _load():
-    global _lib
-    if _lib is not None:
+    global _lib, _unavailable
+    if _lib is not None or _unavailable:
         return _lib
     with _lib_lock:
-        if _lib is not None:
+        if _lib is not None or _unavailable:
             return _lib
-        so = _build()
-        if so is None:
+        try:
+            so = _build()
+        except (OSError, subprocess.SubprocessError) as e:
+            _unavailable = True
+            detail = getattr(e, "stderr", None) or b""
+            logger.warning(
+                "native data plane unavailable (%s %s) — running the "
+                "pure-Python plane", e,
+                detail.decode(errors="replace")[-400:])
             return None
         lib = ctypes.CDLL(so)
         u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -87,28 +104,18 @@ def _load():
         lib.bdl_prefetcher_create.restype = ctypes.c_void_p
         lib.bdl_prefetcher_next.argtypes = [ctypes.c_void_p, f32p, i32p]
         lib.bdl_prefetcher_destroy.argtypes = [ctypes.c_void_p]
-        try:
-            lib.bdl_resize_bilinear.argtypes = [f32p, f32p] + \
-                [ctypes.c_int] * 6
-            lib._has_resize = True
-        except AttributeError:
-            lib._has_resize = False
-        try:
-            # newer symbols — a prebuilt .so from an older source tree
-            # may lack them; the rest of the native plane still works
-            lib.bdl_file_prefetcher_create.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
-                ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.c_uint64, ctypes.c_int, ctypes.c_int,
-                ctypes.c_int, f32p, f32p, i64p,
-                ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-                ctypes.POINTER(ctypes.c_int)]
-            lib.bdl_file_prefetcher_create.restype = ctypes.c_void_p
-            lib.bdl_prefetcher_next_u8.argtypes = [ctypes.c_void_p, u8p,
-                                                   i32p]
-            lib._has_file_prefetcher = True
-        except AttributeError:
-            lib._has_file_prefetcher = False
+        lib.bdl_resize_bilinear.argtypes = [f32p, f32p] + \
+            [ctypes.c_int] * 6
+        lib.bdl_file_prefetcher_create.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+            ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.c_uint64, ctypes.c_int, ctypes.c_int,
+            ctypes.c_int, f32p, f32p, i64p,
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+            ctypes.POINTER(ctypes.c_int)]
+        lib.bdl_file_prefetcher_create.restype = ctypes.c_void_p
+        lib.bdl_prefetcher_next_u8.argtypes = [ctypes.c_void_p, u8p,
+                                               i32p]
         _lib = lib
         return _lib
 
@@ -166,7 +173,7 @@ def resize_bilinear(img: np.ndarray, out_h: int, out_w: int,
     when the native plane is unavailable (caller falls back to numpy —
     measured 12x slower per core for 256→224, PROFILE_r04)."""
     lib = _load()
-    if lib is None or not getattr(lib, "_has_resize", False):
+    if lib is None:
         return None
     img = np.ascontiguousarray(img, np.float32)
     if img.ndim == 2:
@@ -381,8 +388,7 @@ class FilePrefetcher:
         assert out_dtype in ("f32", "u8"), out_dtype
         self.out_dtype = out_dtype
         self._lib = _load()
-        self.native = (self._lib is not None and
-                       getattr(self._lib, "_has_file_prefetcher", False))
+        self.native = self._lib is not None
         if self.native:
             arr = (ctypes.c_char_p * len(self.paths))(
                 *[p.encode() for p in self.paths])

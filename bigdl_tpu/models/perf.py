@@ -166,6 +166,9 @@ def main(argv=None):
                     choices=[None, "bf16", "mixed", "fp32"],
                     help="bf16 → mixed precision (fp32 master weights)")
     args = ap.parse_args(argv)
+    from bigdl_tpu.utils.engine import setup_compile_cache
+
+    setup_compile_cache()
     result = run_perf(args.model, args.batch_size, args.iterations,
                       args.mesh, args.optimizer, args.class_num,
                       args.precision)

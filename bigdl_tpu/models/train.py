@@ -71,7 +71,10 @@ def main(argv=None):
     from bigdl_tpu.optim import (
         Adam, Optimizer, SGD, Top1Accuracy, Trigger,
     )
+    from bigdl_tpu.utils.engine import setup_compile_cache
     from bigdl_tpu.visualization import TrainSummary, ValidationSummary
+
+    setup_compile_cache()
 
     # ---- data + model
     if args.model == "lenet":

@@ -329,7 +329,7 @@ class TransformerLM(Module):
             y = tp_identity(y, self.tp_axis)
         # NOTE: a fused qkv matmul (concat weights → one (E, 3HD) gemm →
         # split) was MEASURED SLOWER at 186M — 53.2k vs 55.3k tok/s
-        # (PROFILE_r04/ANALYSIS.md): the per-scan-step weight concat and
+        # (July records, another stack): the per-scan-step weight concat and
         # qkv split cost more than the gemm fusion saves. Three gemms
         # at M=B·S are already MXU-efficient; don't re-fuse.
         q = (y @ bp["wq"] + bp["bq"]).reshape(b, s, h_local, d).transpose(0, 2, 1, 3)
@@ -398,8 +398,7 @@ class TransformerLM(Module):
                 raise ValueError(
                     f"zigzag sp_mode needs an even local sequence "
                     f"length, got {s}")
-            from bigdl_tpu.parallel.shard_map_compat import axis_size
-            n = axis_size(self.sp_axis)
+            n = lax.axis_size(self.sp_axis)
             my = lax.axis_index(self.sp_axis)
             # positions(i) for traced i: both half starts are affine
             # in the device index, so index the stacked table

@@ -58,14 +58,6 @@ _LOG2E = 1.4426950408889634  # MUST match between _bwd_recompute (s2) and _bwd_p
 # jnp oracle / CPU fallback
 # --------------------------------------------------------------------------
 
-def _tpu_compiler_params(pltpu, **kw):
-    """pltpu.CompilerParams was TPUCompilerParams before jax 0.5 —
-    same fields, renamed class."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
 def attention_reference(
     q: jax.Array,
     k: jax.Array,
@@ -250,7 +242,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k,
         # overlap/reorder grid cells (the library kernel's convention).
         # vmem cap raised like the fused backward's so 2048-row tiles
         # compile (default 16 MiB rejects them).
-        compiler_params=_tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         in_specs=[
@@ -561,7 +553,7 @@ def _flash_bwd_pallas_fused(q, k, v, o, lse, do, causal, sm_scale,
         # scoped-vmem budget at long context (18.1 MiB at S=16384 with
         # native-dtype dots); v5e has 128 MiB — raise the kernel's cap.
         # Only bh is parallel: the dq plane persists across kv AND q.
-        compiler_params=_tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         in_specs=col_specs,
@@ -634,7 +626,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
         # kernels'. Default tie-break shrinks the Q tile first: the
         # round-5 sweep with native-dtype dots re-confirmed 512x1024 as
         # the optimum at the 186M shape (13.39 ms vs 13.58 at 1024x512,
-        # 15.94 at 512x512 — PROFILE_r05/bwd_tile_sweep.log); the
+        # 15.94 at 512x512 — July records, another stack); the
         # serial kv loop amortizes better with a WIDE kv tile.
         # `bwd_tiles` overrides for experimentation.
         if bwd_tiles is None:
@@ -694,7 +686,7 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
             block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
             num_kv=num_kv),
         grid=(bh, num_q, num_kv),
-        compiler_params=_tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         in_specs=row_specs,
         out_specs=pl.BlockSpec((1, block_q, dp_), lambda b, i, j: (b, i, 0)),
@@ -709,7 +701,7 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
             block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
             num_q=num_q),
         grid=(bh, num_kv, num_q),
-        compiler_params=_tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         in_specs=col_specs,
         out_specs=[
@@ -740,8 +732,8 @@ def _flash_fwd_xla(q, k, v, causal, sm_scale, block_k):
     as jnp ops so XLA fuses the elementwise chain into the two matmuls
     per block. Memory O(S·block_k). This was the round-2 TPU default;
     since the Mosaic kernels were retuned (512x512 tiles) and gained a
-    Mosaic backward it loses at every measured shape
-    (PROFILE_r03/ANALYSIS.md) and remains as impl='xla' for comparison
+    Mosaic backward it loses at every measured shape (July records,
+    another stack) and remains as impl='xla' for comparison
     and as a fallback.
     """
     bh, seq_q, dim = q.shape
@@ -927,11 +919,9 @@ def _resolve_impl_and_blocks(q, k, block_q, block_k, impl):
 
 
 def _default_impl() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover - backend init failure
-        platform = "cpu"
-    if platform != "tpu":
+    # a backend that fails to initialise RAISES here: answering "cpu"
+    # would send a broken TPU run down the reference path unnoticed
+    if jax.devices()[0].platform != "tpu":
         return "reference"
     # Round-3 full-step measurements on the real chip (S=2048, D=64,
     # remat, fused loss): with both the forward kernel (512x512 tiles)

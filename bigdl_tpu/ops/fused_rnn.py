@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from bigdl_tpu.ops.flash_attention import _tpu_compiler_params
 from bigdl_tpu.utils import envknobs
 
 # Above this hidden size the backward's VMEM residents no longer fit
@@ -71,10 +70,9 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _default_platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # pragma: no cover - backend init failure
-        return "cpu"
+    # a backend that fails to initialise RAISES here: answering "cpu"
+    # would send a broken TPU run down the lax.scan path unnoticed
+    return jax.devices()[0].platform
 
 
 def resolve_impl(hidden: int, impl: Optional[str] = None) -> str:
@@ -244,8 +242,8 @@ def _lstm_fwd_pallas(zx, w, block_n, interpret, save_residuals=True):
     out = pl.pallas_call(
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             blk4,
@@ -277,8 +275,8 @@ def _lstm_bwd_pallas(w, ys, c_seq, gates, dy, block_n, interpret):
     dzx, dw = pl.pallas_call(
         functools.partial(_lstm_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             pl.BlockSpec((hidden, h4), lambda b, s: (0, 0)),       # w
@@ -468,8 +466,8 @@ def _bilstm_fwd_pallas(zxf, zxb, wf, wb, block_n, interpret,
     out = pl.pallas_call(
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             pl.BlockSpec(blk(h4), at_t),        # zx fwd
@@ -510,8 +508,8 @@ def _bilstm_bwd_pallas(wf, wb, res_f, res_b, dyf, dyb, block_n,
     dzxf, dzxb, dwf, dwb = pl.pallas_call(
         functools.partial(_bilstm_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             w_spec, w_spec,
@@ -703,8 +701,8 @@ def _gru_fwd_pallas(zg, zc, wg, wc, block_n, interpret,
     out = pl.pallas_call(
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             blk2,
@@ -733,8 +731,8 @@ def _gru_bwd_pallas(wg, wc, ys, zr_seq, cand_seq, dy, block_n,
     return pl.pallas_call(
         functools.partial(_gru_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         in_specs=[
             pl.BlockSpec((hidden, h2), lambda b, s: (0, 0)),

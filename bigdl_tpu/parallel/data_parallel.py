@@ -49,13 +49,12 @@ from typing import Any, Callable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.nn.module import Criterion, Module
 from bigdl_tpu.utils.anomaly import health_ok, select_update as _select_update
 
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 # the flatten/pad/slice algebra lives in the param-layout spine
 # (ISSUE 18) — re-exported here because this module IS its historical
 # home and every training consumer imports it from parallel/

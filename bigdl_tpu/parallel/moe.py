@@ -31,7 +31,6 @@ from jax import lax
 
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module
-from bigdl_tpu.parallel.shard_map_compat import axis_size
 
 
 class MoE(Module):
@@ -193,7 +192,7 @@ class MoE(Module):
         # expert-parallel: params arrive expert-sharded; route globally,
         # exchange tokens so each device runs only its local experts
         axis = self.expert_axis
-        n = axis_size(axis)
+        n = lax.axis_size(axis)
         e_local = p["w1"].shape[0]                 # num_experts / n
         if e_local * n != self.num_experts:
             raise ValueError(
@@ -262,10 +261,8 @@ def make_moe_lm_train_step(model, method, mesh, ep_axis: str = "expert"):
     leaves (router, attention, embeddings) psum their per-shard
     contributions. The model must be built with ep_axis=<axis>.
     """
-    import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from bigdl_tpu.parallel.shard_map_compat import shard_map
 
     if getattr(model, "ep_axis", None) != ep_axis:
         raise ValueError(

@@ -27,12 +27,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from bigdl_tpu.models.transformer import TransformerLM, tp_reduce
 
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 
 
 def pipeline_specs(pipe_axis: str = "pipe", tie_embeddings: bool = True):

@@ -39,10 +39,9 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bigdl_tpu.parallel.shard_map_compat import axis_size, shard_map
 
 _NEG_INF = -1e30
 
@@ -98,7 +97,7 @@ def ring_attention(
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     s_local = q.shape[-2]
     q_off = my * s_local
@@ -188,7 +187,7 @@ def zigzag_ring_attention(
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     s_local = q.shape[-2]
     if s_local % 2:
@@ -271,7 +270,7 @@ def ulysses_attention(
     """
     from bigdl_tpu.ops.flash_attention import flash_attention
 
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     h = q.shape[1]
     if h % n:
         raise ValueError(f"num_heads {h} not divisible by axis size {n}")

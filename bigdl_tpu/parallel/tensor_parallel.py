@@ -31,12 +31,11 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.models.transformer import TransformerLM
 
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 
 # stacked-block leaves: which dim (after the layer axis) carries the shard
 _COL = {"wq", "wk", "wv", "w1"}          # shard last dim
@@ -156,31 +155,12 @@ def make_transformer_train_step(
     from bigdl_tpu.parallel.ring_attention import zigzag_order
 
     n_sp = mesh.shape[sp_axis]
-    tok_sharding = NamedSharding(mesh, tok_spec)
     orders = {}  # seq len → device-resident permutation (stable shapes)
 
     def _order(s):
         if s not in orders:
             orders[s] = jnp.asarray(zigzag_order(n_sp, s))
         return orders[s]
-
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        # jax 0.4.x GSPMD partitions a TRACED cross-shard gather that
-        # feeds a shard_map in_spec shard-locally — silently wrong
-        # values, no error, and with_sharding_constraint does not help.
-        # Run the permutation eagerly with an explicit reshard instead:
-        # correct on 0.4.x, and int32 tokens make the extra dispatch
-        # noise next to the train step. Single-process only (the eager
-        # fancy-index needs fully-addressable arrays); multi-host
-        # zigzag needs the traced path of jax >= 0.5.
-        def zig_step(params, slots, tokens, targets, lr, stepno, rng):
-            order = _order(tokens.shape[1])
-            return step(params, slots,
-                        jax.device_put(tokens[:, order], tok_sharding),
-                        jax.device_put(targets[:, order], tok_sharding),
-                        lr, stepno, rng)
-
-        return zig_step
 
     def zig_step(params, slots, tokens, targets, lr, stepno, rng):
         order = _order(tokens.shape[1])

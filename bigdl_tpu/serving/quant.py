@@ -14,12 +14,12 @@ table stay fp32 — they are O(E) a layer, quantizing them saves nothing
 and costs accuracy.
 
 What this buys: the decode step is weight-STREAMING-bound
-(~172 MB/token fp32 at 43M, PROFILE_r07), so int8 weights cut the
+(~172 MB/token fp32 at 43M, counted from shapes), so int8 weights cut the
 bytes the roofline charges per token ~4x on the gemm weights — the
 `lmdecode_quant` bench row reports the measured bytes/token next to
 ms/token. On CPU XLA the dequant multiply materializes fp32 tiles
-(parity/correctness harness); the fused int8 MXU gemm is on-chip
-measurement debt (PROFILE_r06 protocol).
+(parity/correctness harness); the fused int8 MXU gemm is not measured
+on the chip (ROADMAP A1).
 
 Numerics contract: quantization is LOSSY — a quantized engine is NOT
 bit-identical to fp32 and never claims to be. The repo's load-bearing

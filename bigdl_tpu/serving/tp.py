@@ -77,6 +77,7 @@ import weakref
 from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from bigdl_tpu.models.transformer import TransformerLM
@@ -86,7 +87,6 @@ from bigdl_tpu.models.transformer import TransformerLM
 from bigdl_tpu.parallel.param_layout import (gather_tree,
                                              tp_serving_block_specs,
                                              tp_serving_specs)
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 from bigdl_tpu.parallel.tensor_parallel import shard_params
 
 

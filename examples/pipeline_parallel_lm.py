@@ -11,10 +11,6 @@ microbatches. Run with real chips, or simulate:
 import os, sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from bigdl_tpu.utils.engine import ensure_cpu_platform
-
-ensure_cpu_platform()  # honor JAX_PLATFORMS=cpu despite the PJRT plugin
-
 import numpy as np
 
 import jax

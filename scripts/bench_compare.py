@@ -1,8 +1,11 @@
 """Perf-regression sentinel over the bench trajectory (ISSUE 11
 tentpole, layer 3).
 
-Five rounds of `BENCH_r0*.json` history sit in the repo and nothing
-ever compares them: a silent 2× decode slowdown would ship. This
+Rounds of `BENCH_r*.json` history accumulate and nothing else ever
+compares them: a silent 2× decode slowdown would ship. (The repo
+currently holds ONE round, `BENCH_r05.json` — July numbers from
+another stack — so `--fresh-latest` has nothing to gate against until
+a second round lands; `--fresh` against it still works.) This
 script loads the committed trajectory plus a candidate run and flags
 regressions with NOISE-AWARE thresholds, so the documented ~25% host
 variance (CLAUDE.md; the round-4 BiLSTM row ranged 7.8–23.3k

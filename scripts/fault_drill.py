@@ -176,11 +176,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    from bigdl_tpu.utils.engine import ensure_cpu_platform
-
-    ensure_cpu_platform()
-
 
 def _flat(model):
     return np.concatenate([np.ravel(np.asarray(a, np.float32))

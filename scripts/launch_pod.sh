@@ -21,10 +21,9 @@ set -euo pipefail
 # --- performance env (counterpart of bigdl.sh's OMP_NUM_THREADS etc.) ---
 # Donated-buffer reuse + async dispatch are defaults; these keep the host
 # input pipeline from fighting XLA's compilation threads.
+# (The persistent compile cache is placed by the program itself —
+# bigdl_tpu.utils.engine.setup_compile_cache — not from here.)
 export TPU_MEGACORE="${TPU_MEGACORE:-}"
-export JAX_ENABLE_COMPILATION_CACHE="${JAX_ENABLE_COMPILATION_CACHE:-1}"
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/jax_comp}"
-mkdir -p "$JAX_COMPILATION_CACHE_DIR"
 
 # --- distributed bring-up flags consumed by Engine.init_distributed ---
 if [[ -n "${BIGDL_COORDINATOR:-}" ]]; then

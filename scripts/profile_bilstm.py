@@ -28,13 +28,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    from bigdl_tpu.utils.engine import ensure_cpu_platform
-
-    ensure_cpu_platform()
-
-PEAK_BF16 = 197e12
-
 
 def run_config(tag, batch, seq, unroll, hoist, iters, fused=False,
                block_n=None, bidir_fused=True):
@@ -112,6 +105,9 @@ def run_config(tag, batch, seq, unroll, hoist, iters, fused=False,
         dt = (time.perf_counter() - t0) / iters
         e = h = 128
         flops = 3 * batch * 2 * seq * 8 * h * (e + h)
+        from bigdl_tpu.utils.engine import bf16_utilization
+
+        mfu = bf16_utilization(flops / dt)
         print(json.dumps({
             "config": tag, "batch": batch, "seq": seq, "unroll": unroll,
             "hoist": hoist, "fused": fused, "rnn_impl": rnn_impl,
@@ -119,7 +115,7 @@ def run_config(tag, batch, seq, unroll, hoist, iters, fused=False,
             "bidir_fused": bidir_fused if fused else None,
             "step_ms": round(dt * 1e3, 2),
             "samples_per_sec": round(batch / dt, 1),
-            "mfu": round(flops / dt / PEAK_BF16, 4),
+            "mfu": None if mfu is None else round(mfu, 4),
         }), flush=True)
     except Exception as exc:
         print(json.dumps({"config": tag, "FAILED": str(exc)[:160]}),
@@ -133,6 +129,10 @@ def main():
                     help="persistent-kernel tile/residency sweep "
                          "instead of the classic lever sweep")
     args = ap.parse_args()
+
+    from bigdl_tpu.utils.engine import setup_compile_cache
+
+    setup_compile_cache()
 
     if args.fused_sweep:
         # A/B anchor: the shipped lax.scan path at the bench shape

@@ -42,6 +42,10 @@ def chain(fn, x0, n=8, reps=3):
 
 
 def main():
+    from bigdl_tpu.utils.engine import setup_compile_cache
+
+    setup_compile_cache()
+
     import jax
     import jax.numpy as jnp
 

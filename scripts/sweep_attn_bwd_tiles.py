@@ -87,6 +87,9 @@ def sweep_shape(tag, bh, s, d, combos):
 
 
 def main():
+    from bigdl_tpu.utils.engine import setup_compile_cache
+
+    setup_compile_cache()
     combos = [(512, 512), (512, 1024), (1024, 512), (256, 512),
               (512, 256), (256, 1024)]
     sweep_shape("186m_B8H16_S2048_D64", 128, 2048, 64, combos)

@@ -237,7 +237,7 @@ class TestStateReduction:
         from bigdl_tpu.parallel.data_parallel import _reduce_state
         from jax.sharding import PartitionSpec as P
 
-        from bigdl_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         def body():
             i = jax.lax.axis_index("data").astype(jnp.float32)
@@ -267,7 +267,7 @@ class TestStateReduction:
         from bigdl_tpu.parallel.data_parallel import _reduce_state
         from jax.sharding import PartitionSpec as P
 
-        from bigdl_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         def body():
             i = jax.lax.axis_index("data").astype(jnp.float32)
@@ -347,7 +347,7 @@ class TestSyncBatchNorm:
         one device. Round 4 made this exact: averaging E[x] and E[x^2]
         across replicas yields the true global variance (the old
         averaged-local-variance form only approximated it)."""
-        from bigdl_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         bn_sync = nn.SpatialBatchNormalization(3, sync=True,

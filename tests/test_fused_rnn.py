@@ -5,7 +5,7 @@ CPU tier-1 coverage for the Mosaic kernels via Pallas interpret mode
 the `lax.scan` fallback (the exact math the kernel replaces) and the
 torch oracle, in fp32 and bf16. The kernels' grid/index-map machinery
 runs unchanged under interpret — only the Mosaic lowering itself needs
-the real chip (scripts/validate_tpu.py)."""
+the real chip (chip_smoke.py, kernel leg)."""
 
 import jax
 import jax.numpy as jnp
@@ -124,7 +124,7 @@ class TestBiLSTMScan:
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(yb), np.asarray(rb),
                                    rtol=1e-5, atol=1e-6)
-        # the xla fallback branch (what validate_tpu oracles the chip
+        # the xla fallback branch (what chip_smoke.py oracles the chip
         # against) must itself match this independent flip-scan oracle
         ff, fb = fused_rnn.bilstm_scan(zxf, zxb, wf, wb, impl="xla")
         np.testing.assert_allclose(np.asarray(ff), np.asarray(rf),
@@ -212,7 +212,7 @@ class TestGRUScan:
 def test_bench_shape_sweep_interpret():
     """The bench.py BiLSTM hidden size (H=128) through the kernel at
     several batch tiles (~2 s interpreted — cheap enough for tier-1);
-    the on-chip counterpart lives in scripts/validate_tpu.py."""
+    the on-chip counterpart is chip_smoke.py's kernel leg."""
     rng = np.random.RandomState(0)
     h = 128
     zxf, zxb = (_rand(rng, 8, 16, 4 * h, scale=0.1) for _ in range(2))

@@ -5,12 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.parallel import make_mesh, shard_params
 from bigdl_tpu.parallel.moe import MoE, moe_specs
 
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 
 DIM, HID, EXPERTS = 16, 32, 8
 

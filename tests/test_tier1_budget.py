@@ -5,6 +5,8 @@ real run is a standalone invocation; see CLAUDE.md)."""
 import importlib.util
 import os
 
+import pytest
+
 _SYNTHETIC = """\
 ============================= slowest durations ==============================
 120.50s call     tests/test_models.py::test_resnet
@@ -30,8 +32,10 @@ def test_parse_and_projection():
     entries = m.parse_durations(_SYNTHETIC)
     assert len(entries) == 4
     assert entries[0] == (120.5, "call", "tests/test_models.py::test_resnet")
+    # approx: the script's sum() is compensated on Python >= 3.12, a
+    # left-to-right + chain is not — they differ in the last bit
     assert m.projected_runtime_s(entries, overhead_s=40.0) == \
-        40.0 + 120.5 + 0.3 + 45.25 + 0.05
+        pytest.approx(40.0 + 120.5 + 0.3 + 45.25 + 0.05)
     top = m.slowest_tests(entries, top=1)
     assert top == [(120.8, "tests/test_models.py::test_resnet")]
 

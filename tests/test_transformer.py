@@ -5,12 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.models.transformer import TransformerConfig, TransformerLM, build_lm
 from bigdl_tpu.parallel import make_mesh
 
-from bigdl_tpu.parallel.shard_map_compat import shard_map
 
 
 def test_forward_shape():
