@@ -8,7 +8,11 @@ training loop and the serving engine report into:
 * `events`   — schema-versioned JSONL event log (ring buffer +
   optional file sink); the machine-readable record of what a run did
 * `spans`    — host-side span tracer emitting Chrome-trace/Perfetto
-  JSON, aligned with `utils/profiler` device traces
+  JSON: one tree per scheduling round and per train step (ids and
+  parents), each context-manager span mirrored as a
+  `jax.profiler.TraceAnnotation` onto a device trace's clock
+* `compiles` — the process's one `jax.monitoring` listener:
+  `xla_compiles_total{cache}` and a `compile` span per backend compile
 
 ISSUE 14 adds the LIVE layer on top: `timeseries` (bounded ring of
 registry samples, windowed rate/delta/quantile queries — the
@@ -21,7 +25,12 @@ Hard contracts (tests/test_obs.py):
 * telemetry NEVER touches jitted code: zero new compiles with it on
   (the serving #buckets+1 guard passes with telemetry enabled);
 * zero new device→host syncs on hot paths — emission consumes only
-  values the loop already fetched;
+  values the loop already fetched. ONE stated exception, and only
+  while the span tracer is enabled: the serving engine's `prefill`
+  span waits for the prefill program before it closes
+  (`args.fenced`; obs/spans.py says why). Tracer off — the default,
+  and how every untraced benchmark run goes — the contract holds
+  unchanged: no span site touches a device array;
 * everything is bit-reproducible under an injected clock (the fault
   drills assert on telemetry, scripts/fault_drill.py);
 * <1% step overhead on the lmdecode_batched bench row (bench.py
@@ -38,6 +47,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+from bigdl_tpu.obs.compiles import install_compile_listener
 from bigdl_tpu.obs.events import (EventLog, get_event_log, read_jsonl,
                                   set_event_log, stream_jsonl)
 from bigdl_tpu.obs.exposition import ScrapeServer
@@ -63,12 +73,16 @@ __all__ = [
     "to_perfetto",
     "MetricsSampler", "HistogramWindow",
     "SLOObjective", "AlertRule", "AlertEngine", "ScrapeServer",
+    "install_compile_listener",
     "enabled", "set_enabled", "emit_event", "log_metrics_snapshot",
     "provenance", "reset_all",
 ]
 
 _enabled = os.environ.get("BIGDL_OBS", "on").lower() not in (
     "off", "0", "false", "no")
+
+
+install_compile_listener()      # one per process: obs/compiles.py
 
 
 def enabled() -> bool:

@@ -202,7 +202,7 @@ class FlightRecorder:
 
         obs = _obs()
         out: Dict[str, float] = {}
-        snap = obs.get_registry().snapshot()
+        snap = obs.get_registry().snapshot(process_state=False)
         for name, fam in snap["metrics"].items():
             if fam["kind"] != "counter":
                 continue
@@ -247,7 +247,8 @@ class FlightRecorder:
                  for k, v in sorted(now_counters.items())
                  if v != self._counter_base.get(k, 0.0)}
         self._write(bundle, "registry.json",
-                    {"snapshot": obs.get_registry().snapshot(),
+                    {"snapshot": obs.get_registry().snapshot(
+                        process_state=False),
                      "counters_delta_since_install": delta})
         self._write(bundle, "journeys.json", build_journeys(tail))
         manifest = {

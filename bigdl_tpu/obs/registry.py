@@ -102,6 +102,11 @@ class _Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
+        # True on a family whose values are a property of the PROCESS
+        # (its caches), not of the run's inputs: a flight-recorder
+        # bundle, which must be byte-identical across runs, leaves it
+        # out (`snapshot(process_state=False)`); a scrape shows it
+        self.process_state = False
         self._children: Dict[Tuple[str, ...], object] = {}
         self._lock = threading.Lock()
 
@@ -293,14 +298,17 @@ class MetricsRegistry:
             self._metrics.clear()
 
     # ------------------------------------------------------------- export
-    def snapshot(self) -> dict:
+    def snapshot(self, process_state: bool = True) -> dict:
         """Deterministic dict: metric names sorted, label tuples
         sorted; identical metric activity → byte-identical JSON (the
         clock stamp is the only time-dependent field, and it is
-        injectable)."""
+        injectable). `process_state=False` leaves out the families
+        marked as the process's own state (`_Metric.process_state`)."""
         out: Dict[str, dict] = {}
         for name in sorted(self._metrics):
             m = self._metrics[name]
+            if m.process_state and not process_state:
+                continue
             fam: dict = {"kind": m.kind, "help": m.help,
                          "labelnames": list(m.labelnames), "series": []}
             for key, child in m._sorted_children():

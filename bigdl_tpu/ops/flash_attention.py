@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from bigdl_tpu.ops.pallas_names import named_pallas_call
 from bigdl_tpu.utils import envknobs
 
 _NEG_INF = -1e30
@@ -234,7 +235,8 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k,
         _fa_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, seq_q=seq_q, seq_k=seq_k, num_kv=num_kv)
 
-    out_p, lse_p = pl.pallas_call(
+    out_p, lse_p = named_pallas_call(
+        "flash_fwd",
         kernel,
         grid=(bh, num_q, num_kv),
         # bh and q rows are independent; only the kv sweep carries the
@@ -543,7 +545,8 @@ def _flash_bwd_pallas_fused(q, k, v, o, lse, do, causal, sm_scale,
                             sm_scale)
     num_q, num_kv = sq // block_q, sk // block_k
 
-    dq_p, dk_p, dv_p = pl.pallas_call(
+    dq_p, dk_p, dv_p = named_pallas_call(
+        "flash_bwd_fused",
         functools.partial(
             _fa_bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
@@ -680,7 +683,8 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
         pl.BlockSpec((1, 1, sq), lambda b, i, j: (b, 0, 0)),          # lse
         pl.BlockSpec((1, 1, sq), lambda b, i, j: (b, 0, 0)),          # delta
     ]
-    dq_p = pl.pallas_call(
+    dq_p = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(
             _fa_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
@@ -695,7 +699,8 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
         interpret=interpret,
     )(qp, kp, vp, dop, lse_p, delta_p)
 
-    dk_p, dv_p = pl.pallas_call(
+    dk_p, dv_p = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(
             _fa_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,

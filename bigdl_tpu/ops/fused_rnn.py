@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from bigdl_tpu.ops.pallas_names import named_pallas_call
 from bigdl_tpu.utils import envknobs
 
 # Above this hidden size the backward's VMEM residents no longer fit
@@ -239,7 +240,8 @@ def _lstm_fwd_pallas(zx, w, block_n, interpret, save_residuals=True):
     blk = pl.BlockSpec((1, block_n, hidden), lambda b, t: (t, b, 0))
     blk4 = pl.BlockSpec((1, block_n, h4), lambda b, t: (t, b, 0))
     kernel = _lstm_fwd_kernel if save_residuals else _lstm_fwd_infer_kernel
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "fused_lstm_fwd",
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(
@@ -272,7 +274,8 @@ def _lstm_bwd_pallas(w, ys, c_seq, gates, dy, block_n, interpret):
     hidden = h4 // 4
     at_t = lambda b, s: (n_t - 1 - s, b, 0)
     at_prev = lambda b, s: (jnp.maximum(n_t - 2 - s, 0), b, 0)
-    dzx, dw = pl.pallas_call(
+    dzx, dw = named_pallas_call(
+        "fused_lstm_bwd",
         functools.partial(_lstm_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(
@@ -463,7 +466,8 @@ def _bilstm_fwd_pallas(zxf, zxb, wf, wb, block_n, interpret,
         out_specs = [pl.BlockSpec(blk(hidden), at_t),
                      pl.BlockSpec(blk(hidden), at_rev)]
         out_shape = [ys_shape, ys_shape]
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "fused_lstm_bi_fwd",
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(
@@ -505,7 +509,8 @@ def _bilstm_bwd_pallas(wf, wb, res_f, res_b, dyf, dyb, block_n,
     dw_spec = pl.BlockSpec((1, hidden, h4), lambda b, s: (b, 0, 0))
     dw_shape = jax.ShapeDtypeStruct((n // block_n, hidden, h4),
                                     jnp.float32)
-    dzxf, dzxb, dwf, dwb = pl.pallas_call(
+    dzxf, dzxb, dwf, dwb = named_pallas_call(
+        "fused_lstm_bi_bwd",
         functools.partial(_bilstm_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(
@@ -698,7 +703,8 @@ def _gru_fwd_pallas(zg, zc, wg, wc, block_n, interpret,
     blk2 = pl.BlockSpec((1, block_n, h2), at_t)
     ys_shape = jax.ShapeDtypeStruct((n_t, n, hidden), zg.dtype)
     kernel = _gru_fwd_kernel if save_residuals else _gru_fwd_infer_kernel
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "fused_gru_fwd",
         functools.partial(kernel, hidden=hidden),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(
@@ -728,7 +734,8 @@ def _gru_bwd_pallas(wg, wc, ys, zr_seq, cand_seq, dy, block_n,
     hidden = h2 // 2
     at_t = lambda b, s: (n_t - 1 - s, b, 0)
     at_prev = lambda b, s: (jnp.maximum(n_t - 2 - s, 0), b, 0)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "fused_gru_bwd",
         functools.partial(_gru_bwd_kernel, hidden=hidden, n_t=n_t),
         grid=(n // block_n, n_t),
         compiler_params=pltpu.CompilerParams(

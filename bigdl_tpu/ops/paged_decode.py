@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from bigdl_tpu.ops.pallas_names import named_pallas_call
 from bigdl_tpu.utils import envknobs
 
 _NEG_INF = -1e30
@@ -198,7 +199,8 @@ def _paged_decode_pallas(q, k_pool, v_pool, table, pos, sm_scale,
             pltpu.VMEM((head_tile, seq, d), jnp.float32),
             pltpu.VMEM((head_tile, seq, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    return named_pallas_call(
+        "paged_decode",
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),

@@ -617,9 +617,12 @@ class LocalOptimizer:
                 else {**train_state, "neval": eff_step}
             lr = o.optim_method.current_rate(lr_state)
             with Timer(self.metrics, "dispatch_s"):
+                # dispatch = h2d_place + the step's call (PERF.md §3)
+                with Timer(self.metrics, "h2d_place_s"):
+                    x, y = _to_device(mb.input), _to_device(mb.target)
                 step_args = (
                     variables["params"], variables["state"], slots,
-                    _to_device(mb.input), _to_device(mb.target),
+                    x, y,
                     jnp.asarray(lr, jnp.float32),
                     jnp.asarray(eff_step, jnp.int32),
                     step_rng)

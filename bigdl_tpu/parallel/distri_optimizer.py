@@ -360,10 +360,14 @@ class DistriOptimizer(LocalOptimizer):
                 thr = None if guard is None else jnp.asarray(
                     guard.threshold(), jnp.float32)
                 with Timer(self.metrics, "dispatch_s"):
+                    # dispatch = h2d_place (the host batch placed on
+                    # the mesh) + the step's call (PERF.md §3)
+                    with Timer(self.metrics, "h2d_place_s"):
+                        x = self._global(mb.input)
+                        y = self._global(mb.target)
                     if accum == 1:
                         step_args = (
-                            flat_w, slots, mod_state,
-                            self._global(mb.input), self._global(mb.target),
+                            flat_w, slots, mod_state, x, y,
                             jnp.asarray(lr, jnp.float32),
                             jnp.asarray(eff_step, jnp.int32),
                             step_rng)
@@ -375,9 +379,7 @@ class DistriOptimizer(LocalOptimizer):
                              gnorm_d) = step_fn(*step_args, thr)
                     else:
                         micro_args = (
-                            flat_w, g_acc, mod_state,
-                            self._global(mb.input), self._global(mb.target),
-                            step_rng)
+                            flat_w, g_acc, mod_state, x, y, step_rng)
                         if guard is None:
                             g_acc, mod_state, loss = micro_fn(*micro_args)
                             micro_n += 1

@@ -614,6 +614,11 @@ class InferenceEngine:
         self.step_retries = step_retries
         self.retry_backoff_s = retry_backoff_s
         self._clock = clock
+        # in-round spans run on the tracer's clock unless a drill
+        # injected one here: one timeline, one clock (obs/spans.py)
+        self._span_clock = None if clock is time.monotonic else clock
+        # a traced round's admitted / emitted request ids (step())
+        self._round_log: Optional[Dict[str, list]] = None
         self._stats: Dict[str, int] = {
             "prefill_calls": 0, "decode_steps": 0, "requests_done": 0,
             "shed": 0, "rejected": 0, "deadline_misses": 0,
@@ -1116,6 +1121,15 @@ class InferenceEngine:
             out["tenant"] = tenant
         return out
 
+    def _span(self, name: str, parent: Optional[int] = None):
+        """One span of the scheduling round's tree (obs/spans.py): the
+        shared no-op unless the tracer is enabled. A site that has
+        counts for it tests `span.id is not None` first and hands them
+        to `span.set`, so that an untraced round builds nothing."""
+        return obs.get_tracer().span(name, "serving",
+                                     clock=self._span_clock,
+                                     parent=parent)
+
     def _bump(self, key: str, n: int = 1) -> None:
         """One increment path: the engine-local stats dict (always,
         core bookkeeping) plus the registry mirror (when telemetry is
@@ -1358,43 +1372,44 @@ class InferenceEngine:
         return True
 
     def _admit(self):
-        self._expire_queued(self._clock())
-        # quota-exceeded requests are set ASIDE and restored to the
-        # queue front afterwards (order preserved) — a blocked tenant
-        # must never head-of-line-block the other tenants' admissions
-        quota_skipped: List[Request] = []
-        try:
-            for slot in self._free_slots():
-                while self._queue:
-                    req = self._pop_next()
-                    if self._quota_blocked(req):
-                        quota_skipped.append(req)
-                        continue
-                    if self._admit_into(slot, req):
-                        self._admit_fails.pop(req.id, None)
-                        self._quota_noted.discard(req.id)
-                        break
-                    # pool pressure: every evictable/spillable prefix
-                    # block is gone and the free list still cannot
-                    # cover the suffix. Requeue at the FRONT of the
-                    # line (its precedence is preserved) — BOUNDED
-                    # (ISSUE 16 bugfix): a pool that never frees
-                    # (nothing in flight to release blocks) would
-                    # otherwise spin the request through the queue
-                    # forever with no terminal and no counter
-                    fails = self._admit_fails.pop(req.id, 0) + 1
-                    if fails > self.admit_requeue_budget:
-                        self._bump("admit_requeue_exhausted")
-                        self._terminal(req, "pool_exhausted", "done")
-                        continue          # try the next queued request
-                    self._admit_fails[req.id] = fails
-                    self._queue.appendleft(req)
-                    return
-                if not self._queue:
-                    return
-        finally:
-            for r in reversed(quota_skipped):
-                self._queue.appendleft(r)
+        with self._span("admit"):
+            self._expire_queued(self._clock())
+            # quota-exceeded requests are set ASIDE and restored to the
+            # queue front afterwards (order preserved) — a blocked tenant
+            # must never head-of-line-block the other tenants' admissions
+            quota_skipped: List[Request] = []
+            try:
+                for slot in self._free_slots():
+                    while self._queue:
+                        req = self._pop_next()
+                        if self._quota_blocked(req):
+                            quota_skipped.append(req)
+                            continue
+                        if self._admit_into(slot, req):
+                            self._admit_fails.pop(req.id, None)
+                            self._quota_noted.discard(req.id)
+                            break
+                        # pool pressure: every evictable/spillable prefix
+                        # block is gone and the free list still cannot
+                        # cover the suffix. Requeue at the FRONT of the
+                        # line (its precedence is preserved) — BOUNDED
+                        # (ISSUE 16 bugfix): a pool that never frees
+                        # (nothing in flight to release blocks) would
+                        # otherwise spin the request through the queue
+                        # forever with no terminal and no counter
+                        fails = self._admit_fails.pop(req.id, 0) + 1
+                        if fails > self.admit_requeue_budget:
+                            self._bump("admit_requeue_exhausted")
+                            self._terminal(req, "pool_exhausted", "done")
+                            continue          # try the next queued request
+                        self._admit_fails[req.id] = fails
+                        self._queue.appendleft(req)
+                        return
+                    if not self._queue:
+                        return
+            finally:
+                for r in reversed(quota_skipped):
+                    self._queue.appendleft(r)
 
     def _point_table_row(self, slot: int, hit: List[int],
                          new: List[int]) -> np.ndarray:
@@ -1486,22 +1501,26 @@ class InferenceEngine:
             t_sub = self._meta.get(req.id, {}).get("t", t_admit)
             tracer.complete("queued", "serving", t_sub, t_admit,
                             args={"request": req.id, "slot": slot})
-        with warnings.catch_warnings():
-            # donation is a per-call no-op warning on CPU backends;
-            # on TPU it aliases the pool update in place
-            warnings.filterwarnings(
-                "ignore", message=".*[Dd]onat", category=UserWarning)
-            self.pool = _prefill_step(
-                self.model, self._params, self.pool,
-                jnp.asarray(toks), np.int32(start),
-                jnp.asarray(new, dtype=jnp.int32),
-                jnp.asarray(row[None, :]))
-        if tracer.enabled:
-            tracer.complete("prefill", "serving", t_admit,
-                            self._clock(),
-                            args={"request": req.id, "slot": slot,
-                                  "bucket": int(b),
-                                  "prefix_tokens": int(start)})
+        with self._span("prefill") as span:
+            with warnings.catch_warnings():
+                # donation is a per-call no-op warning on CPU
+                # backends; on TPU it aliases the pool update in place
+                warnings.filterwarnings(
+                    "ignore", message=".*[Dd]onat",
+                    category=UserWarning)
+                self.pool = _prefill_step(
+                    self.model, self._params, self.pool,
+                    jnp.asarray(toks), np.int32(start),
+                    jnp.asarray(new, dtype=jnp.int32),
+                    jnp.asarray(row[None, :]))
+            if span.id is not None:
+                # THE one span that waits for the device, and only
+                # while it is being recorded (obs/spans.py): unfenced
+                # it times the dispatch and the prefill program lands
+                # in the next decode_step. Tracer off: never reached
+                jax.block_until_ready(self.pool)  # graftlint: disable=hidden-device-sync
+                span.set(request=req.id, slot=slot, bucket=int(b),
+                         prefix_tokens=int(start), fenced=True)
         self._bump("prefill_calls")
         if start:
             self._bump("prefix_hits")
@@ -1515,6 +1534,8 @@ class InferenceEngine:
                            prompt_len=n, **self._trace_fields(req))
         self._update_pool_gauge()
         self._seat_slot(slot, req, hit, new)
+        if self._round_log is not None:
+            self._round_log["admitted"].append(req.id)
         return True
 
     def _finish(self, slot: int, reason: str,
@@ -1646,8 +1667,18 @@ class InferenceEngine:
                 done.append(self._finish(slot, "stop_id"))
                 return done
             self._gen[slot].append(tok)
+            if self._round_log is not None:
+                # the per-token stamp of a traced round: the request's
+                # id once per token, under the round's one `now`
+                self._round_log["emitted"].append(req.id)
             if len(self._gen[slot]) == 1 and req.id in self._meta:
-                self._meta[req.id]["t_first"] = now   # TTFT stamp
+                meta = self._meta[req.id]
+                meta["t_first"] = now                 # TTFT stamp
+                if self._round_log is not None:
+                    obs.get_tracer().instant(
+                        "first_token", "serving", ts=now,
+                        args={"request": req.id,
+                              "ttft_s": now - meta["t"]})
             if len(self._gen[slot]) >= req.max_new_tokens:
                 done.append(self._finish(slot, "max_tokens"))
                 return done
@@ -1688,6 +1719,10 @@ class InferenceEngine:
         a typed StepTimeout. A daemon thread suffices here because
         steady-state PJRT dispatch/fetch releases the GIL while it
         waits (see _watchdog_call)."""
+        # the closure may run on the watchdog's thread, where no span
+        # is open: hand the enclosing span (decode_step) over
+        parent = obs.get_tracer().current()
+
         def work():
             if slow_s:
                 time.sleep(slow_s)    # injected straggler/hang model
@@ -1701,20 +1736,25 @@ class InferenceEngine:
                 # dispatch is beyond this guard — that is the failure
                 # mode the watchdog exists to convert.
                 return None
-            with warnings.catch_warnings():
+            host = (self._tok, self._pos, self._seed, self._nout,
+                    self._temp, self._topk, self._topp, poison,
+                    self._table)
+            with self._span("upload", parent) as span:
+                args = [jnp.asarray(a) for a in host]
+                if span.id is not None:
+                    span.set(bytes=sum(a.nbytes for a in host))
+            with self._span("dispatch", parent), \
+                    warnings.catch_warnings():
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
                 nxt, finite, pools = _decode_step(
-                    self.model, self._params, self.pool,
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._seed), jnp.asarray(self._nout),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), jnp.asarray(poison),
-                    jnp.asarray(self._table), self.attn_impl)
+                    self.model, self._params, self.pool, *args,
+                    self.attn_impl)
             # THE one deliberate per-step device→host fetch: the host
             # needs the token, so the fetch doubles as the fence for
             # the decode dispatch, inside the watchdog budget above
-            return np.asarray(nxt), np.asarray(finite), pools  # graftlint: disable=hidden-device-sync
+            with self._span("fetch", parent):
+                return np.asarray(nxt), np.asarray(finite), pools  # graftlint: disable=hidden-device-sync
 
         nxt, finite, pools = _watchdog_call(
             work, self.step_timeout_s if watchdog else None)
@@ -1743,25 +1783,26 @@ class InferenceEngine:
         shadow mirror must never emit a request_terminal (the quiesce
         contract); blocks already granted stay registered on their
         slots and release with them."""
-        done: List[GenerationResult] = []
-        for i, req in enumerate(self._req):
-            if req is None:
-                continue
-            h = 0 if horizons is None else int(horizons[i])
-            lo = int(self._pos[i]) // self.block_size
-            hi = (int(self._pos[i]) + h) // self.block_size
-            for bi in range(lo, hi + 1):
-                if self._table[i, bi] != 0:
+        with self._span("ensure_blocks"):
+            done: List[GenerationResult] = []
+            for i, req in enumerate(self._req):
+                if req is None:
                     continue
-                new = self._alloc_blocks(1)
-                if new is None:
-                    if exhaust == "abort":
-                        return None
-                    done.append(self._finish(i, "pool_exhausted"))
-                    break
-                self._table[i, bi] = new[0]
-                self._slot_blocks[i][1].append(new[0])
-        return done
+                h = 0 if horizons is None else int(horizons[i])
+                lo = int(self._pos[i]) // self.block_size
+                hi = (int(self._pos[i]) + h) // self.block_size
+                for bi in range(lo, hi + 1):
+                    if self._table[i, bi] != 0:
+                        continue
+                    new = self._alloc_blocks(1)
+                    if new is None:
+                        if exhaust == "abort":
+                            return None
+                        done.append(self._finish(i, "pool_exhausted"))
+                        break
+                    self._table[i, bi] = new[0]
+                    self._slot_blocks[i][1].append(new[0])
+            return done
 
     def rollback_slot(self, slot: int) -> int:
         """Cache rollback hook (ISSUE 15): detach and free the slot's
@@ -2072,9 +2113,24 @@ class InferenceEngine:
             return []
         if self.role == "prefill":
             return self._step_prefill()
+        # one span tree per scheduling round (obs/spans.py; PERF.md §3
+        # has the table): round > admit > prefill, ensure_blocks,
+        # decode_step > upload / dispatch / fetch, emit
+        with self._span("round") as span:
+            if span.id is None:
+                return self._round()
+            log = self._round_log = {"admitted": [], "emitted": []}
+            try:
+                return self._round()
+            finally:
+                self._round_log = None
+                span.set(attn_impl=self.attn_impl, **log)
+
+    def _round(self) -> List[GenerationResult]:
         self._admit()
         done = self._ensure_blocks()
-        if all(r is None for r in self._req):
+        n_active = sum(r is not None for r in self._req)
+        if not n_active:
             return done
         plan = faults.get_plan()
         stepno = self._stats["decode_steps"]
@@ -2089,7 +2145,14 @@ class InferenceEngine:
                 if plan.fires("serve_slow", stepno):
                     slow_s = (self.step_timeout_s or 0.05) * 5
                 tc0 = self._clock()
-                nxt, finite = self._dispatch_and_fetch(poison, slow_s)
+                # one span per ATTEMPT: a retried round holds one
+                # `decode_step` for each, in order, the failed ones
+                # ending at their exception
+                with self._span("decode_step") as span_d:
+                    if span_d.id is not None:
+                        span_d.set(step=stepno, active=n_active)
+                    nxt, finite = self._dispatch_and_fetch(poison,
+                                                           slow_s)
                 # dispatch+fetch wall time into the fixed-bucket
                 # histogram UNCONDITIONALLY: health() percentiles are
                 # core engine bookkeeping (this store replaced the
@@ -2099,15 +2162,6 @@ class InferenceEngine:
                 # nondeterministic-drill): drills with a fake clock get
                 # bit-deterministic latency records too
                 self._m_lat.observe(self._clock() - tc0)
-                if obs.enabled():
-                    tracer = obs.get_tracer()
-                    if tracer.enabled:
-                        tracer.complete(
-                            "decode_step", "serving", tc0,
-                            self._clock(),
-                            args={"step": stepno,
-                                  "active": sum(r is not None
-                                                for r in self._req)})
                 break
             except StepTimeout as e:
                 self._bump("watchdog_trips")
@@ -2135,11 +2189,12 @@ class InferenceEngine:
                     time.sleep(self.retry_backoff_s * (2 ** attempt))
         self._bump("decode_steps")
         now = self._clock()
-        for i, req in enumerate(self._req):
-            if req is None:
-                continue
-            done.extend(self._emit_multi(i, [int(nxt[i])],
-                                         [bool(finite[i])], now))
+        with self._span("emit"):
+            for i, req in enumerate(self._req):
+                if req is None:
+                    continue
+                done.extend(self._emit_multi(i, [int(nxt[i])],
+                                             [bool(finite[i])], now))
         return done
 
     def run(self, requests: Optional[Sequence[Request]] = None

@@ -217,6 +217,8 @@ class EngineRouter:
         self._migrated: set = set()
         self.engine_factory = engine_factory
         self._clock = clock
+        # spans run on the tracer's clock unless one was injected here
+        self._span_clock = None if clock is time.monotonic else clock
         self.completed: Dict[int, GenerationResult] = {}
         self._pending: Dict[int, _Assignment] = {}
         # terminals settled OUTSIDE a step() call (submit-time shed
@@ -301,6 +303,10 @@ class EngineRouter:
             buckets=ROUTER_LATENCY_BUCKETS)
 
     # ------------------------------------------------------------- helpers
+    def _span(self, name: str):
+        return obs.get_tracer().span(name, "serving",
+                                     clock=self._span_clock)
+
     def _bump(self, key: str, n: int = 1) -> None:
         self._stats[key] += n
         if obs.enabled() and key in self._m_ops:
@@ -394,27 +400,29 @@ class EngineRouter:
         policies the admitting engine may shed a victim (or the
         request itself) — the result surfaces through the router like
         any other terminal, never a KeyError."""
-        if request.id is None:
-            rid = next(self._ids)
-            while rid in self._pending or rid in self.completed:
+        with self._span("submit") as span:
+            if request.id is None:
                 rid = next(self._ids)
-            request.id = rid
-        elif request.id in self._pending \
-                or request.id in self.completed \
-                or (self.tenancy is not None
-                    and self.tenancy.has(request.id)):
-            raise ValueError(f"request id {request.id} already in "
-                             "flight or completed-unclaimed")
-        if getattr(request, "trace_id", None) is None:
-            # journey tracing (ISSUE 11): the trace context opens at
-            # ROUTER admission — deterministic (router label + request
-            # id, no clock/RNG), and every move below (failover,
-            # rebalance, handoff import) increments the hop counter
-            request.trace_id = f"{self._obs_name}/{request.id}"
-            request.hop = 0
-        if self.tenancy is not None:
-            return self._submit_tenancy(request)
-        return self._dispatch(request)
+                while rid in self._pending or rid in self.completed:
+                    rid = next(self._ids)
+                request.id = rid
+            elif request.id in self._pending \
+                    or request.id in self.completed \
+                    or (self.tenancy is not None
+                        and self.tenancy.has(request.id)):
+                raise ValueError(f"request id {request.id} already in "
+                                 "flight or completed-unclaimed")
+            if getattr(request, "trace_id", None) is None:
+                # journey tracing (ISSUE 11): the trace context opens at
+                # ROUTER admission — deterministic (router label + request
+                # id, no clock/RNG), and every move below (failover,
+                # rebalance, handoff import) increments the hop counter
+                request.trace_id = f"{self._obs_name}/{request.id}"
+                request.hop = 0
+            span.set(request=request.id)
+            if self.tenancy is not None:
+                return self._submit_tenancy(request)
+            return self._dispatch(request)
 
     def _submit_tenancy(self, request: Request) -> int:
         """Tenancy-armed admission (ISSUE 19): the request parks in
@@ -770,6 +778,12 @@ class EngineRouter:
         surfaced); terminals settled between steps — submit-time shed
         victims — ride the next return, so a driver loop sees every
         request it submitted exactly once."""
+        # root of the round's span tree: the engines' `round`s are its
+        # children, its self time the rebalance, tenancy and settling
+        with self._span("router_step"):
+            return self._step()
+
+    def _step(self) -> List[GenerationResult]:
         self._rebalance()
         if self.tenancy is not None:
             # release BEFORE draining the backlog: expiry terminals

@@ -175,9 +175,10 @@ def test_span_tracer_chrome_trace_parses(tmp_path):
     x = [e for e in evs if e["name"] == "prefill"][0]
     assert x["ts"] == pytest.approx(1.5e6)        # seconds → µs
     assert x["dur"] == pytest.approx(0.5e6)
-    assert x["args"] == {"slot": 0}
+    assert x["args"] == {"slot": 0, "id": 1}      # a root: no parent
     q = [e for e in evs if e["name"] == "queued"][0]
     assert q["dur"] == pytest.approx(1.25e6)
+    assert q["args"] == {"request": 7, "id": 2}
 
 
 def test_span_tracer_disabled_is_noop():
