@@ -626,9 +626,11 @@ class TransformerLM(Module):
     def init_block_pool(self, num_blocks: int, block_size: int,
                         dtype=jnp.float32):
         """Per-layer paged KV pools: a TUPLE of L dicts {'k','v'},
-        each (num_blocks, H, block_size, D). Per-layer (not stacked)
-        for the same reason as init_cache; block 0 is the reserved
-        scratch block (ops/kv_cache.py)."""
+        each (num_blocks, block_size, H*D): blocks are axis 0, a
+        block's rows are tokens, a row holds the heads side by side
+        (ops/kv_cache.init_block_pool says why). Per-layer (not
+        stacked) for the same reason as init_cache; block 0 is the
+        reserved scratch block (ops/kv_cache.py)."""
         from bigdl_tpu.ops.kv_cache import init_block_pool
 
         self._serving_guard(tp_ok=True)
@@ -695,8 +697,8 @@ class TransformerLM(Module):
             kp, vp = write_prompt_blocks(pl["k"], pl["v"], k, v,
                                          block_ids)
             new_pools.append({"k": kp, "v": vp})
-            kc = gather_block_cache(kp, table)      # (1, H, S_tab, D)
-            vc = gather_block_cache(vp, table)
+            kc = gather_block_cache(kp, table, h)   # (1, H, S_tab, D)
+            vc = gather_block_cache(vp, table, h)
             if visible is None:                     # same every layer
                 jpos = jnp.arange(kc.shape[-2])
                 ipos = start + jnp.arange(s)
@@ -766,7 +768,7 @@ class TransformerLM(Module):
         p = variables["params"] if "params" in variables else variables
         bsz = tokens.shape[0]
         d = self.head_dim
-        bs = pools[0]["k"].shape[2]
+        bs = pools[0]["k"].shape[1]
         rows = jnp.arange(bsz)
         block_ids = table[rows, pos // bs]          # (B,)
         offsets = pos % bs

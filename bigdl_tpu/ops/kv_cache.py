@@ -22,7 +22,8 @@ creation) — scores still accumulate in fp32.
 
 Paged layout (ISSUE 8): the second cache family here pages the
 per-layer cache into fixed-size blocks held in ONE preallocated
-`(num_blocks, H, block_size, D)` pool per layer. A sequence's cache is
+`(num_blocks, block_size, H*D)` pool per layer (block-major storage,
+`init_block_pool`). A sequence's cache is
 then a BLOCK TABLE — a static `(max_blocks,)` int32 row of pool
 indices — instead of a contiguous `(S, ...)` buffer: eviction, slot
 elasticity and prefix sharing become integer surgery on the table plus
@@ -154,11 +155,32 @@ def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 def init_block_pool(num_blocks: int, num_heads: int, block_size: int,
                     head_dim: int, dtype=jnp.float32
                     ) -> Tuple[jax.Array, jax.Array]:
-    """One layer's paged (k, v) pool, each (num_blocks, H, block_size,
-    D), zero-filled. Block 0 is the scratch block by convention (see
-    module docstring); the host allocator (serving/kv_pool.py) never
-    hands it out."""
-    shape = (num_blocks, num_heads, block_size, head_dim)
+    """One layer's paged (k, v) pool, each (num_blocks, block_size,
+    H*D), zero-filled: a block is `block_size` token rows, a row holds
+    the heads side by side (head h at lanes [h*D, (h+1)*D)). Block 0
+    is the scratch block by convention (see module docstring); the
+    host allocator (serving/kv_pool.py) never hands it out.
+
+    THE CONTRACT every holder of a pool relies on: blocks are axis 0.
+    Spill, re-admission, scrub, handoff and migration index axis 0
+    only and never look inside a block; what is inside is this
+    module's business (`write_*_blocks`, `gather_block_cache`,
+    ops/paged_decode.py) and serving/tp.py's, which splits the last
+    axis by head.
+
+    Why this shape: a TPU lays an array out in (8, 128) tiles over
+    two dimensions of the compiler's choosing. Given a minor dimension
+    of D = 64 it would rather make the BLOCK dimension minor-most than
+    pad 64 lanes to 128, and every program that indexes the pool by
+    block then transposes all of it on the way in and again for the
+    donated output. With (block_size, H*D) minor, whole tiles when H*D
+    is a multiple of 128 and block_size of 8, the default layout is
+    row-major: block-major, unpadded, and a scatter by block id updates
+    the donated leaf in place (tests/test_pool_layout.py compiles both
+    serving programs for a v5e and holds them to that). Other widths
+    get the same shape and a correct pool; how the device tiles them
+    is the compiler's choice."""
+    shape = (num_blocks, block_size, num_heads * head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -178,17 +200,18 @@ def write_prompt_blocks(k_pool: jax.Array, v_pool: jax.Array,
                          f"(batch 1), got batch {k_new.shape[0]}")
     nb = block_ids.shape[0]
     _, h, s, d = k_new.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[1]
     pad = nb * bs - s
     if pad < 0:
         raise ValueError(f"{nb} blocks of {bs} cannot hold {s} tokens")
 
     def blocked(x, pool):
-        x = x[0].astype(pool.dtype)                 # (H, S, D)
+        # (H, S, D) → (S, H*D): a token's heads side by side
+        x = x[0].astype(pool.dtype).transpose(1, 0, 2).reshape(s, h * d)
         if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        # (H, nb*bs, D) → (nb, H, bs, D): one row per destination block
-        return x.reshape(h, nb, bs, d).transpose(1, 0, 2, 3)
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        # (nb*bs, H*D) → (nb, bs, H*D): one row per destination block
+        return x.reshape(nb, bs, h * d)
 
     return (k_pool.at[block_ids].set(blocked(k_new, k_pool)),
             v_pool.at[block_ids].set(blocked(v_new, v_pool)))
@@ -205,21 +228,25 @@ def write_decode_blocks(k_pool: jax.Array, v_pool: jax.Array,
     read-only, the engine never routes a write at one); inactive rows
     all target the scratch block, whose content no reader ever sees
     unmasked, so colliding garbage writes there are harmless."""
-    kv = k_new[:, :, 0, :].astype(k_pool.dtype)     # (B, H, D)
-    vv = v_new[:, :, 0, :].astype(v_pool.dtype)
-    return (k_pool.at[block_ids, :, offsets, :].set(kv),
-            v_pool.at[block_ids, :, offsets, :].set(vv))
+    b = k_new.shape[0]
+    kv = k_new.astype(k_pool.dtype).reshape(b, -1)      # (B, H*D)
+    vv = v_new.astype(v_pool.dtype).reshape(b, -1)
+    return (k_pool.at[block_ids, offsets].set(kv),
+            v_pool.at[block_ids, offsets].set(vv))
 
 
-def gather_block_cache(pool: jax.Array, table: jax.Array) -> jax.Array:
+def gather_block_cache(pool: jax.Array, table: jax.Array,
+                       num_heads: int) -> jax.Array:
     """Materialize each row's logical cache through its block table:
-    pool (N, H, bs, D) gathered by table (B, nb) → (B, H, nb*bs, D).
+    pool (N, bs, H*D) gathered by table (B, nb) → (B, H, nb*bs, D),
+    H = `num_heads` (the pool does not say where one head ends).
     A pure gather — values pass through bitwise, so attention over the
     gathered array equals attention over an equivalent contiguous
     cache bit-for-bit (tests/test_kv_pool.py pins it)."""
-    g = pool[table]                                 # (B, nb, H, bs, D)
-    b, nb, h, bs, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, nb * bs, d)
+    g = pool[table]                                 # (B, nb, bs, H*D)
+    b, nb, bs, hd = g.shape
+    return g.reshape(b, nb * bs, num_heads, hd // num_heads) \
+        .transpose(0, 2, 1, 3)
 
 
 def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -254,7 +281,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     table: jax.Array, pos: jax.Array,
                     sm_scale: Optional[float] = None) -> jax.Array:
     """One query row per sequence against the paged pool: q
-    (B, H, 1, D), pools (N, H, bs, D), table (B, nb), pos (B,) — the
+    (B, H, 1, D), pools (N, bs, H*D), table (B, nb), pos (B,) — the
     row clock, exactly as cached_attention. Gathers each row's blocks
     and attends positions <= pos over the FULL table extent (nb*bs),
     so the math is the dense cached_attention bit-for-bit when the
@@ -262,8 +289,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if q.shape[-2] != 1:
         raise ValueError(f"paged_attention decodes one row, got q "
                          f"length {q.shape[-2]}")
-    kc = gather_block_cache(k_pool, table)
-    vc = gather_block_cache(v_pool, table)
+    kc = gather_block_cache(k_pool, table, q.shape[1])
+    vc = gather_block_cache(v_pool, table, q.shape[1])
     seq = kc.shape[-2]
     visible = (jnp.arange(seq)[None, :] <= pos[:, None])    # (B, S)
     return block_attention(q, kc, vc, visible[:, None, :], visible,

@@ -66,11 +66,24 @@ def _default_impl() -> str:
         else "interpret"
 
 
+def _lane_aligned_head_tile(num_heads: int, head_dim: int) -> int:
+    """The fewest heads whose lanes a compiled launch may stream: a
+    pool row holds the heads side by side (ops/kv_cache
+    .init_block_pool), and Mosaic takes a block of its last axis only
+    in multiples of 128 lanes or whole. 64-wide heads go in pairs."""
+    for ht in range(1, num_heads):
+        if num_heads % ht == 0 and (ht * head_dim) % 128 == 0:
+            return ht
+    return num_heads
+
+
 def resolve_tiles(num_blocks: int, num_heads: int,
                   block_tile: Optional[int] = None,
-                  head_tile: Optional[int] = None) -> Tuple[int, int]:
+                  head_tile: Optional[int] = None,
+                  head_dim: Optional[int] = None) -> Tuple[int, int]:
     """(block_tile, head_tile) for a launch: explicit args win, then
-    the `BIGDL_PAGED_DECODE_TILES` import-time snapshot, then (1, 1).
+    the `BIGDL_PAGED_DECODE_TILES` import-time snapshot, then (1, the
+    lane-aligned head tile for `head_dim`, 1 where none is given).
     Both must DIVIDE the launch's table width / head count — the
     index-map routing streams whole pool blocks, so a ragged tile
     would either read past the table or silently widen the reduction
@@ -78,8 +91,11 @@ def resolve_tiles(num_blocks: int, num_heads: int,
     env = envknobs.PAGED_DECODE_TILES
     if block_tile is None:
         block_tile = env[0] if env is not None else 1
-    if head_tile is None:
-        head_tile = env[1] if env is not None else 1
+    if head_tile is None and env is not None:
+        head_tile = env[1]
+    elif head_tile is None:
+        head_tile = 1 if head_dim is None \
+            else _lane_aligned_head_tile(num_heads, head_dim)
     if block_tile < 1 or num_blocks % block_tile:
         raise ValueError(
             f"block_tile {block_tile} must divide the table width "
@@ -93,9 +109,11 @@ def resolve_tiles(num_blocks: int, num_heads: int,
 
 def _pd_kernel(tbl_ref, pos_ref, q_ref, *refs, block_tile, head_tile,
                num_j, block_size, seq, sm_scale, dup_batch):
-    """One grid cell: stream `block_tile` table-routed pool blocks
-    into the (head_tile, seq, D) VMEM scratch; on the final KV sweep
-    run the oracle's full-extent masked softmax per head."""
+    """One grid cell: stream `block_tile` table-routed pool blocks —
+    each (block_size, head_tile*D), the cell's heads side by side —
+    into the (head_tile, seq, D) VMEM scratch, one head's lanes at a
+    time; on the final KV sweep run the oracle's full-extent masked
+    softmax per head."""
     k_refs = refs[:block_tile]
     v_refs = refs[block_tile:2 * block_tile]
     o_ref = refs[2 * block_tile]
@@ -105,6 +123,7 @@ def _pd_kernel(tbl_ref, pos_ref, q_ref, *refs, block_tile, head_tile,
     b = pl.program_id(0)
     j = pl.program_id(2)
     row_pos = pos_ref[b]
+    d = k_scr.shape[-1]
 
     for i in range(block_tile):
         # whole blocks only, so the sublane offset of every scratch
@@ -112,16 +131,18 @@ def _pd_kernel(tbl_ref, pos_ref, q_ref, *refs, block_tile, head_tile,
         # through program_id arithmetic
         base = pl.multiple_of((j * block_tile + i) * block_size,
                               block_size)
-        kblk = k_refs[i][0].astype(jnp.float32)      # (ht, bs, D)
+        kblk = k_refs[i][0].astype(jnp.float32)      # (bs, ht*D)
         vblk = v_refs[i][0].astype(jnp.float32)
         off = lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
         valid = (base + off) <= row_pos              # (bs, 1)
-        k_scr[:, pl.ds(base, block_size), :] = kblk
         # zero value rows beyond the clock at load: 0-probability rows
         # must contribute exactly 0.0, never 0.0 * NaN (the oracle's
         # `valid` hygiene — block_attention)
-        v_scr[:, pl.ds(base, block_size), :] = jnp.where(
-            valid[None], vblk, 0.0)
+        vblk = jnp.where(valid, vblk, 0.0)
+        for hh in range(head_tile):
+            lanes = slice(hh * d, (hh + 1) * d)
+            k_scr[hh, pl.ds(base, block_size), :] = kblk[:, lanes]
+            v_scr[hh, pl.ds(base, block_size), :] = vblk[:, lanes]
 
     @pl.when(j == num_j - 1)
     def _finalize():
@@ -167,7 +188,10 @@ def _paged_decode_pallas(q, k_pool, v_pool, table, pos, sm_scale,
 
     b, h, _, d = q.shape
     nb = table.shape[1]
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[1]
+    if k_pool.shape[2] != h * d:
+        raise ValueError(f"pool rows of {k_pool.shape[2]} do not hold "
+                         f"{h} heads of {d}")
     seq = nb * bs
     num_j = nb // block_tile
 
@@ -183,12 +207,14 @@ def _paged_decode_pallas(q, k_pool, v_pool, table, pos, sm_scale,
         (1, head_tile, 1, d), lambda bb, hh, jj, tbl, ps: (bb, hh, 0, 0))
     # one spec per streamed block: the index map routes pool block
     # tbl[b, j*bt + i] through VMEM — the table read happens at grid
-    # scheduling time (scalar prefetch), never inside the kernel body
+    # scheduling time (scalar prefetch), never inside the kernel body.
+    # A pool row holds the heads side by side, so a head tile is a
+    # window of head_tile*D lanes of the last axis
     kv_specs = [
         pl.BlockSpec(
-            (1, head_tile, bs, d),
+            (1, bs, head_tile * d),
             (lambda bb, hh, jj, tbl, ps, _i=i:
-             (tbl[bb, jj * block_tile + _i], hh, 0, 0)))
+             (tbl[bb, jj * block_tile + _i], 0, hh)))
         for i in range(block_tile)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -222,7 +248,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            block_tile: Optional[int] = None,
                            head_tile: Optional[int] = None) -> jax.Array:
     """Drop-in for `ops/kv_cache.paged_attention`: q (B, H, 1, D),
-    pools (N, H, bs, D), table (B, nb) int32, pos (B,) row clocks →
+    pools (N, bs, H*D), table (B, nb) int32, pos (B,) row clocks →
     (B, H, 1, D).
 
     impl: None → auto ('pallas' on TPU, 'interpret' elsewhere);
@@ -230,7 +256,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     verbatim — the engine's default off-TPU); 'pallas' | 'interpret'
     → the one-launch kernel. fp32 kernel output is BITWISE the oracle
     in interpret mode (module docstring); tiles via `block_tile` /
-    `head_tile` or the `BIGDL_PAGED_DECODE_TILES` snapshot."""
+    `head_tile` or the `BIGDL_PAGED_DECODE_TILES` snapshot, else one
+    block and the fewest heads whose lanes Mosaic can stream (a
+    compiled launch needs head_tile*D to be a multiple of 128, or all
+    heads; the interpreter takes any)."""
     if q.shape[-2] != 1:
         raise ValueError(f"paged_decode_attention decodes one row, "
                          f"got q length {q.shape[-2]}")
@@ -244,7 +273,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         raise ValueError(f"impl {impl!r}: expected 'xla', 'pallas' or "
                          "'interpret'")
     bt, ht = resolve_tiles(table.shape[1], q.shape[1], block_tile,
-                           head_tile)
+                           head_tile, head_dim=q.shape[-1])
     return _paged_decode_pallas(q, k_pool, v_pool, table, pos,
                                 float(sm_scale), bt, ht,
                                 interpret=(impl == "interpret"))

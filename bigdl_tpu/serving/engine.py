@@ -6,7 +6,7 @@ prefill/decode_step, ops/kv_cache.py).
 Design
 ------
 * **Paged KV pool + block tables (ISSUE 8).** The engine owns one
-  PAGED KV cache: per-layer `(num_blocks, H, block_size, D)` pools
+  PAGED KV cache: per-layer `(num_blocks, block_size, H*D)` pools
   (ops/kv_cache.py) plus a host `(slots, max_blocks)` int32 block
   TABLE — a slot is a row of pool indices, not a contiguous buffer.
   A request occupies one slot from prefill to finish; eviction and
@@ -106,13 +106,14 @@ The engine is model-agnostic over anything exposing
 `init_block_pool(num_blocks, block_size, dtype)` /
 `prefill_paged(variables, tokens, pools, table, block_ids, start)` /
 `decode_step_paged(variables, tokens, pos, pools, table)` whose pools
-are a pytree of block-leading leaves (and, optionally,
+are a pytree of block-leading leaves — `(num_blocks, block_size, ...)`,
+blocks on axis 0 and nothing here looks past it (and, optionally,
 `serving_params(variables)` for a fast weight layout) — the paged
 trio models/transformer.py implements.
 
 Tensor-parallel sharding (ISSUE 10): `tp_mesh=` swaps the model for
 the memoized `serving/tp.py` wrapper — weights and the per-layer KV
-pool shard over the mesh (pool on the HEAD axis, so the block table
+pool shard over the mesh (pool BY HEAD, so the block table
 and every host-side invariant here stay byte-identical), the jitted
 steps trace shard_map'd bodies, and the emitted tokens are BITWISE
 identical to the unsharded engine (the tp_shard_gather construction).
@@ -334,7 +335,7 @@ class HandoffPackage:
     """One prefilled request, detached from its prefill engine
     (disaggregated prefill, ISSUE 10): the original Request, the
     host-side KV block contents its prefill wrote (per-layer
-    {'k','v'} arrays of shape (nb, H, block_size, D) — GLOBAL arrays,
+    {'k','v'} arrays of shape (nb, block_size, H*D) — GLOBAL arrays,
     so the package moves between sharding layouts), and the original
     submit stamp (the importer re-stamps its meta with it, so
     TTFT/latency tell the whole truth across the handoff)."""
@@ -593,8 +594,9 @@ class InferenceEngine:
         # bytes-saved counter's unit), from the pool leaves themselves
         # — model-agnostic
         self._kv_bytes_per_token = int(sum(
-            leaf.dtype.itemsize * leaf.shape[1] * leaf.shape[3]
-            for leaf in jax.tree_util.tree_leaves(self.pool)))
+            leaf.nbytes // leaf.shape[0]
+            for leaf in jax.tree_util.tree_leaves(self.pool))
+            // block_size)
         self.buckets = tuple(sorted(
             prefill_buckets if prefill_buckets is not None
             else default_buckets(self.cache_len)))
@@ -1905,7 +1907,7 @@ class InferenceEngine:
         warm-state migration (ISSUE 16): one entry per tree node —
         the full prefix tokens from the root plus the block's bytes
         in the HandoffPackage per-layer {'k','v'} layout (one
-        (H, block_size, D) row per array; fp32 reference layout).
+        (block_size, H*D) row per array; fp32 reference layout).
         Device-resident blocks are fetched in ONE batched transfer;
         host-tier blocks are already bytes. Parents precede children,
         so a survivor can import_tree() the list in order. Safe on a
@@ -2015,7 +2017,7 @@ class InferenceEngine:
         if req.id in in_flight:
             raise ValueError(f"request id {req.id} already in flight "
                              "or completed-unclaimed")
-        pkg_bs = int(pkg.kv[0]["k"].shape[2])
+        pkg_bs = int(pkg.kv[0]["k"].shape[1])
         if len(pkg.kv) != len(self.pool) \
                 or pkg_bs != self.block_size \
                 or pkg.kv[0]["k"].shape[1:] != self.pool[0]["k"].shape[1:] \

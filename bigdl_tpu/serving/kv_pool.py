@@ -2,7 +2,7 @@
 
 No reference counterpart: the reference's serving surface is batch
 Predictor.scala. This is the allocator half of the paged-cache spine —
-the DEVICE half (the per-layer `(num_blocks, H, block_size, D)` pools
+the DEVICE half (the per-layer `(num_blocks, block_size, H*D)` pools
 and the block-table gather/scatter ops) lives in ops/kv_cache.py; the
 content-addressed reuse half (the radix tree that decides WHICH blocks
 a new prompt can share) lives in serving/prefix_cache.py. This module

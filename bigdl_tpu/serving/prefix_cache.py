@@ -31,7 +31,7 @@ Host-RAM spill tier (ISSUE 16): with `host_blocks > 0` the tree spans
 TWO tiers. A node either owns a device pool block (`block` set,
 registered in `_by_block`) or parks its block's BYTES in host numpy
 arrays (`host` set, `block` None — the HandoffPackage per-layer
-{'k','v'} layout, one (H, block_size, D) row per array). Spilled
+{'k','v'} layout, one (block_size, H*D) row per array). Spilled
 blocks are bytes, never recomputation, so the warm==cold bit-identity
 contract extends verbatim across a spill/re-admit round trip. The LRU
 ordering is ONE logical clock spanning both tiers: under pool

@@ -15,8 +15,10 @@ The split (per stacked serving layer, Megatron-shaped but bit-exact):
 
     wq/wk/wv, bq/bk/bv   column-sharded by HEAD (each shard owns
                          H/tp heads end to end)
-    KV block pools       sharded on the head axis — (N, H/tp, bs, D)
-                         per shard, 1/tp cache residency; the block
+    KV block pools       sharded by head — (N, bs, (H/tp)*D) per
+                         shard (a row holds the heads side by side, so
+                         a split of the last axis is a split by whole
+                         heads), 1/tp cache residency; the block
                          TABLE stays host-side int32, REPLICATED and
                          identical on every shard, so every host-side
                          invariant (allocator, radix prefix tree,
@@ -150,9 +152,10 @@ class TPServingLM:
         self._tp_model = TransformerLM(
             cfg, tp_axis=axis, name=f"{model.name}_tp{tp}")
         self._block_specs = tp_serving_block_specs(axis)
+        # the last axis is H*D, heads contiguous: tp | H makes this a
+        # split by whole heads
         self._pool_specs = tuple(
-            {"k": P(None, axis, None, None),
-             "v": P(None, axis, None, None)}
+            {"k": P(None, None, axis), "v": P(None, None, axis)}
             for _ in range(cfg.num_layers))
 
     @property
@@ -175,7 +178,7 @@ class TPServingLM:
     def init_block_pool(self, num_blocks: int, block_size: int,
                         dtype=jnp.float32):
         """The per-layer paged pools, head-sharded on the mesh: each
-        shard holds (num_blocks, H/tp, block_size, D) per layer —
+        shard holds (num_blocks, block_size, (H/tp)*D) per layer —
         1/tp KV residency, the serving memory win. Block ids/tables
         are untouched host integers, identical across shards."""
         pools = self.model.init_block_pool(num_blocks, block_size,

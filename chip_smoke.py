@@ -398,7 +398,7 @@ def kernel_leg(clock, model, xla_results, *, impl, slots, buckets,
     from bigdl_tpu.ops import fused_rnn
     from bigdl_tpu.ops.flash_attention import (attention_reference,
                                                flash_attention)
-    from bigdl_tpu.ops.kv_cache import paged_attention
+    from bigdl_tpu.ops.kv_cache import init_block_pool, paged_attention
     from bigdl_tpu.ops.paged_decode import paged_decode_attention
 
     leg = Leg(name, clock)
@@ -442,8 +442,10 @@ def kernel_leg(clock, model, xla_results, *, impl, slots, buckets,
     # --- paged decode: the serve leg's launch (slots rows, full table)
     nb = cfg.max_len // block_size
     pool_n = slots * nb + 1                     # block 0 = reserved scratch
-    kp = jnp.asarray(rng.randn(pool_n, h, block_size, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(pool_n, h, block_size, d), jnp.float32)
+    shape = jax.eval_shape(
+        lambda: init_block_pool(pool_n, h, block_size, d))[0].shape
+    kp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    vp = jnp.asarray(rng.randn(*shape), jnp.float32)
     table = jnp.asarray(rng.permutation(np.arange(1, pool_n))
                         .reshape(slots, nb), jnp.int32)
     pos = jnp.asarray(rng.randint(0, nb * block_size, size=slots), jnp.int32)

@@ -39,12 +39,16 @@ def _setup(args):
     import jax
     import jax.numpy as jnp
 
+    from bigdl_tpu.ops.kv_cache import init_block_pool
+
     rng = np.random.RandomState(0)
     b, h, d = args.batch, args.heads, args.head_dim
     nb, bs = args.blocks, args.block_size
     pool_n = b * nb + 1                       # block 0 = reserved scratch
-    k_pool = jnp.asarray(rng.randn(pool_n, h, bs, d), jnp.float32)
-    v_pool = jnp.asarray(rng.randn(pool_n, h, bs, d), jnp.float32)
+    shape = jax.eval_shape(
+        lambda: init_block_pool(pool_n, h, bs, d))[0].shape
+    k_pool = jnp.asarray(rng.randn(*shape), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(*shape), jnp.float32)
     # each row owns a disjoint, shuffled block chain (never block 0):
     # the routing the index maps must reproduce
     ids = rng.permutation(np.arange(1, pool_n))[:b * nb]

@@ -94,9 +94,11 @@ def test_mesh_leg_tiny(smoke, clock, trained, capsys):
                    ["shard_devices"])) == 4
     assert serve["leg"] == "mesh_serve[model=4]" and serve["tp"] == 4
     assert len(serve["placement"]["pool_devices"]) == 4
-    # heads shard over the mesh: each device holds H/4 of the pool
-    assert serve["placement"]["pool_k0_shard"][1] * 4 \
-        == serve["placement"]["pool_k0_global"][1]
+    # heads shard over the mesh: each device holds H/4 of a pool row
+    assert serve["placement"]["pool_k0_shard"][-1] * 4 \
+        == serve["placement"]["pool_k0_global"][-1]
+    assert serve["placement"]["pool_k0_shard"][:-1] \
+        == serve["placement"]["pool_k0_global"][:-1]
 
 
 def test_main_refuses_a_cpu_backend(smoke, capsys, cache_config):

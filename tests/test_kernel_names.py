@@ -80,7 +80,7 @@ def test_paged_decode():
     names = _kernel_names(
         lambda q, kp, vp, tbl, pos: paged_decode_attention(
             q, kp, vp, tbl, pos, impl="pallas"),
-        _f32(b, h, 1, d), _f32(pool, h, bs, d), _f32(pool, h, bs, d),
+        _f32(b, h, 1, d), _f32(pool, bs, h * d), _f32(pool, bs, h * d),
         jax.ShapeDtypeStruct((b, nb), jnp.int32),
         jax.ShapeDtypeStruct((b,), jnp.int32))
     assert names == ["paged_decode"]

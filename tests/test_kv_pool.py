@@ -89,8 +89,8 @@ class TestPagedPrimitives:
             kp, vp, kn, vn,
             jnp.asarray(table[np.arange(B), pos // bs]),
             jnp.asarray(pos % bs, np.int32))
-        gk = np.asarray(gather_block_cache(kp, jnp.asarray(table)))
-        gv = np.asarray(gather_block_cache(vp, jnp.asarray(table)))
+        gk = np.asarray(gather_block_cache(kp, jnp.asarray(table), H))
+        gv = np.asarray(gather_block_cache(vp, jnp.asarray(table), H))
         np.testing.assert_array_equal(np.asarray(kd)[0, :, 5],
                                       gk[0, :, 5])
         np.testing.assert_array_equal(np.asarray(vd)[1, :, 14],
@@ -105,9 +105,10 @@ class TestPagedPrimitives:
         kp, vp = init_block_pool(3, H, bs, D)
         kp, _ = write_prompt_blocks(kp, vp, k, k, jnp.asarray([2]))
         got = np.asarray(kp)
-        np.testing.assert_array_equal(got[2, :, :8], np.asarray(k)[0])
-        assert (got[2, :, 8:] == 0).all()
         assert (got[1] == 0).all()           # untouched block
+        got = np.asarray(gather_block_cache(kp, jnp.asarray([[2]]), H))
+        np.testing.assert_array_equal(got[0, :, :8], np.asarray(k)[0])
+        assert (got[0, :, 8:] == 0).all()
 
 
 class TestModelPagedParity:

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.ops.kv_cache import paged_attention
+from bigdl_tpu.ops.kv_cache import init_block_pool, paged_attention
 from bigdl_tpu.ops.paged_decode import paged_decode_attention, resolve_tiles
 from bigdl_tpu.utils import envknobs
 
@@ -28,8 +28,10 @@ def _case(b, h, nb, bs, d, dtype=jnp.float32, seed=0, pos=None,
     kernel never reads it and masked keys launder correctly."""
     rng = np.random.RandomState(seed)
     pool_n = b * nb + 1
-    k_pool = rng.randn(pool_n, h, bs, d).astype(np.float32)
-    v_pool = rng.randn(pool_n, h, bs, d).astype(np.float32)
+    shape = jax.eval_shape(
+        lambda: init_block_pool(pool_n, h, bs, d))[0].shape
+    k_pool = rng.randn(*shape).astype(np.float32)
+    v_pool = rng.randn(*shape).astype(np.float32)
     if poison:
         k_pool[0] = np.nan
         v_pool[0] = np.nan

@@ -1,0 +1,216 @@
+"""The paged KV pool's storage shape (ISSUE 25): what the TPU compiler
+makes of it, and that any widths round-trip through it.
+
+The first half compiles the engine's two serving programs ahead of time
+for a described `v5e:2x2` (no chip attached; the TPU compiler is
+installed), on a 2-layer model at gpt2-medium's widths with the chat
+cell's pool geometry, and holds them to what the shape was chosen for:
+the device lays a leaf out block-major, no instruction relayouts a whole
+leaf on the way in or out, and every leaf is updated in place. A compile
+that passes is not a chip run.
+
+libtpu is touched only inside the `topo` fixture (one process at a time
+may load it; a module that touches it while being imported breaks the
+collection under several workers), and the tests skip where no topology
+can be described.
+
+The second half runs on the CPU: pools whose rows are not whole
+128-lane tiles still hold exactly what was written.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bigdl_tpu.ops.kv_cache import (gather_block_cache, init_block_pool,
+                                    write_decode_blocks,
+                                    write_prompt_blocks)
+
+# gpt2-medium's widths, the chat cell's engine (benchmarks/traffic/
+# chat-open.json): 64 slots, 3,073 blocks of 16 tokens
+DIM, HEADS, VOCAB, MAX_LEN = 1024, 16, 50257, 1024
+SLOTS, BLOCK, POOL_BLOCKS, BUCKET = 64, 16, 3073, 256
+LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - any failure means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """An AOT compile for an absent chip can be written to the persistent
+    cache but not read back; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def programs(topo, no_cache):
+    """{'decode' | 'prefill': (entry instructions, module header)} of
+    the compiled programs, and the leaf's shape."""
+    from bigdl_tpu.models.transformer import TransformerConfig, \
+        TransformerLM
+    from bigdl_tpu.serving import engine as eng
+
+    one = SingleDeviceSharding(topo.devices[0])
+    model = TransformerLM(TransformerConfig(
+        vocab_size=VOCAB, max_len=MAX_LEN, dim=DIM, num_heads=HEADS,
+        num_layers=LAYERS, mlp_ratio=4))
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    params = jax.eval_shape(lambda: model.serving_params(
+        model.init(jax.random.PRNGKey(0))))
+    pools = jax.eval_shape(lambda: model.init_block_pool(
+        POOL_BLOCKS, BLOCK, jnp.float32))
+    per_slot = MAX_LEN // BLOCK
+    i32, f32 = jnp.int32, jnp.float32
+    dec = on((params, pools, vec(i32, SLOTS), vec(i32, SLOTS),
+              vec(i32, SLOTS), vec(i32, SLOTS), vec(f32, SLOTS),
+              vec(i32, SLOTS), vec(f32, SLOTS), vec(jnp.bool_, SLOTS),
+              vec(i32, SLOTS, per_slot)))
+    pre = on((params, pools, vec(i32, 1, BUCKET), vec(i32),
+              vec(i32, BUCKET // BLOCK), vec(i32, 1, per_slot)))
+    return {
+        "leaf": pools[0]["k"].shape,
+        "decode": _entry(eng._decode_step.lower(model, *dec, "xla")
+                         .compile().as_text()),
+        "prefill": _entry(eng._prefill_step.lower(model, *pre)
+                          .compile().as_text()),
+    }
+
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>\S+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"(?:\{(?P<layout>[\d,]*)[^}]*\})? (?P<op>[\w-]+)\(")
+
+
+def _entry(text):
+    """(The entry computation's instructions: name, dims, minor-to-major
+    layout, opcode, and the line; the module's header line)."""
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    out = []
+    for line in body.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            dims = tuple(int(d) for d in m["dims"].split(",") if d)
+            layout = tuple(int(d) for d in (m["layout"] or "").split(",")
+                           if d)
+            out.append((m["name"], dims, layout, m["op"], line))
+    return out, text[:text.index("\n")]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_pool_parameters_are_block_major(programs, program):
+    leaf = programs["leaf"]
+    params = [(name, layout) for name, dims, layout, op, _
+              in programs[program][0]
+              if op == "parameter" and dims == leaf]
+    assert len(params) == 2 * LAYERS, params
+    for name, layout in params:
+        # minor-to-major: the block index (dimension 0) comes last
+        assert layout[-1] == 0, (
+            f"{name}: device layout {layout} of a {leaf} leaf does not "
+            "keep the block dimension major-most")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_nothing_relayouts_a_whole_leaf(programs, program):
+    """Nothing in the entry computation produces a leaf-sized result
+    except the scatter that updates the donated leaf in place: no copy,
+    no transpose, no fusion that rewrites the pool."""
+    leaf = programs["leaf"]
+    n = int(np.prod(leaf))
+    entry = programs[program][0]
+    whole = [(name, dims, op, line) for name, dims, _, op, line in entry
+             if op != "parameter" and sorted(dims) == sorted(leaf)]
+    assert len(whole) == 2 * LAYERS, [w[:3] for w in whole]
+    for name, dims, op, line in whole:
+        assert dims == leaf and op == "fusion" and "/scatter" in line, (
+            f"{name} = {op} -> {dims}: a whole pool leaf ({n} elements) "
+            f"is produced by something else than the scatter: {line[:200]}")
+    # nor a leaf under other dimensions
+    for name, dims, _, op, line in entry:
+        assert not (op in ("copy", "transpose", "copy-start")
+                    and int(np.prod(dims or (1,))) == n), line[:200]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_every_pool_leaf_is_donated_in_place(programs, program):
+    entry, header = programs[program]
+    leaf = programs["leaf"]
+    pool_params = {
+        int(re.search(r"parameter\((\d+)\)", line).group(1))
+        for _, dims, _, op, line in entry
+        if op == "parameter" and dims == leaf}
+    aliased = {int(p) for p in re.findall(
+        r"\{[\d,\s]*\}:\s*\((\d+),\s*\{[\d,\s]*\},\s*(?:may|must)-alias\)",
+        header)}
+    assert len(pool_params) == 2 * LAYERS
+    assert pool_params <= aliased, (
+        f"pool parameters {sorted(pool_params - aliased)} are not in "
+        f"input_output_alias ({sorted(aliased)})")
+
+
+# ------------------------------------------------------------- CPU cases
+
+@pytest.mark.parametrize("heads,head_dim,block", [
+    (2, 4, 4),          # H*D = 8: the tiny test models
+    (3, 20, 4),         # 60: not a multiple of 8 either
+    (5, 24, 8),         # 120: just short of a tile
+    (2, 96, 16),        # 192: one tile and a half
+    (16, 64, 16),       # 1,024: gpt2-medium's row, whole tiles
+], ids=lambda v: str(v))
+def test_any_widths_round_trip_bitwise(heads, head_dim, block):
+    """write -> gather is the identity on the bits, prompt and decode
+    writes alike, whatever the row's width."""
+    rng = np.random.RandomState(heads * 1000 + head_dim)
+    nb, slots = 3, 2
+    s = nb * block - 1                      # a ragged last block
+    kp, vp = init_block_pool(1 + slots * nb, heads, block, head_dim)
+    assert kp.shape[0] == 1 + slots * nb    # blocks are axis 0
+    table = np.arange(1, 1 + slots * nb, dtype=np.int32).reshape(slots, nb)
+    want_k = rng.randn(slots, heads, nb * block, head_dim).astype(np.float32)
+    want_v = rng.randn(slots, heads, nb * block, head_dim).astype(np.float32)
+    for r in range(slots):
+        kp, vp = write_prompt_blocks(
+            kp, vp, jnp.asarray(want_k[r:r + 1, :, :s]),
+            jnp.asarray(want_v[r:r + 1, :, :s]), jnp.asarray(table[r]))
+    # the last position of every row by a decode write
+    pos = np.full((slots,), s, np.int32)
+    kp, vp = write_decode_blocks(
+        kp, vp, jnp.asarray(want_k[:, :, s:s + 1]),
+        jnp.asarray(want_v[:, :, s:s + 1]),
+        jnp.asarray(table[np.arange(slots), pos // block]),
+        jnp.asarray(pos % block))
+    got_k = np.asarray(gather_block_cache(kp, jnp.asarray(table), heads))
+    got_v = np.asarray(gather_block_cache(vp, jnp.asarray(table), heads))
+    np.testing.assert_array_equal(got_k, want_k)
+    np.testing.assert_array_equal(got_v, want_v)
+    assert not np.asarray(kp[0]).any()      # the scratch block untouched
