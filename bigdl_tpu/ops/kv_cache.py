@@ -180,8 +180,57 @@ def init_block_pool(num_blocks: int, num_heads: int, block_size: int,
     serving programs for a v5e and holds them to that). Other widths
     get the same shape and a correct pool; how the device tiles them
     is the compiler's choice."""
-    shape = (num_blocks, block_size, num_heads * head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    width = num_heads * head_dim
+    return (init_row_pool(num_blocks, block_size, width, dtype),
+            init_row_pool(num_blocks, block_size, width, dtype))
+
+
+def init_row_pool(num_blocks: int, block_size: int, width: int,
+                  dtype=jnp.float32) -> jax.Array:
+    """One paged pool leaf, (num_blocks, block_size, width), zeros: a
+    block is `block_size` token rows of `width` numbers, whatever a
+    model keeps of a token there (a layer's keys or values with the
+    heads side by side, or a latent row, models/latent_moe.py). The
+    same contract as `init_block_pool`: blocks are axis 0, block 0 is
+    scratch."""
+    return jnp.zeros((num_blocks, block_size, width), dtype)
+
+
+def write_prompt_rows(pool: jax.Array, rows: jax.Array,
+                      block_ids: jax.Array) -> jax.Array:
+    """Bulk-write one request's prefill rows (S, W) into the blocks
+    `block_ids` (nb,) of a (N, bs, W) pool leaf; S pads up to nb*bs
+    with zeros (beyond the row's clock, masked like any garbage).
+    `block_ids` must be distinct (the allocator guarantees it)."""
+    nb = block_ids.shape[0]
+    s, w = rows.shape
+    bs = pool.shape[1]
+    pad = nb * bs - s
+    if pad < 0:
+        raise ValueError(f"{nb} blocks of {bs} cannot hold {s} tokens")
+    rows = rows.astype(pool.dtype)
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    # (nb*bs, W) → (nb, bs, W): one row per destination block
+    return pool.at[block_ids].set(rows.reshape(nb, bs, w))
+
+
+def write_decode_rows(pool: jax.Array, rows: jax.Array,
+                      block_ids: jax.Array, offsets: jax.Array
+                      ) -> jax.Array:
+    """Write one decode step's rows (B, W) at per-row (block, offset)
+    destinations of a (N, bs, W) pool leaf (see write_decode_blocks
+    for which rows may collide, and why that is harmless)."""
+    return pool.at[block_ids, offsets].set(rows.astype(pool.dtype))
+
+
+def gather_block_rows(pool: jax.Array, table: jax.Array) -> jax.Array:
+    """Each row's logical cache through its block table: pool
+    (N, bs, W) gathered by table (B, nb) → (B, nb*bs, W). A pure
+    gather: values pass through bitwise."""
+    g = pool[table]                                 # (B, nb, bs, W)
+    b, nb, bs, w = g.shape
+    return g.reshape(b, nb * bs, w)
 
 
 def write_prompt_blocks(k_pool: jax.Array, v_pool: jax.Array,
@@ -198,23 +247,14 @@ def write_prompt_blocks(k_pool: jax.Array, v_pool: jax.Array,
     if k_new.shape[0] != 1:
         raise ValueError("write_prompt_blocks writes one request "
                          f"(batch 1), got batch {k_new.shape[0]}")
-    nb = block_ids.shape[0]
     _, h, s, d = k_new.shape
-    bs = k_pool.shape[1]
-    pad = nb * bs - s
-    if pad < 0:
-        raise ValueError(f"{nb} blocks of {bs} cannot hold {s} tokens")
 
-    def blocked(x, pool):
+    def rows(x, pool):
         # (H, S, D) → (S, H*D): a token's heads side by side
-        x = x[0].astype(pool.dtype).transpose(1, 0, 2).reshape(s, h * d)
-        if pad:
-            x = jnp.pad(x, ((0, pad), (0, 0)))
-        # (nb*bs, H*D) → (nb, bs, H*D): one row per destination block
-        return x.reshape(nb, bs, h * d)
+        return x[0].astype(pool.dtype).transpose(1, 0, 2).reshape(s, h * d)
 
-    return (k_pool.at[block_ids].set(blocked(k_new, k_pool)),
-            v_pool.at[block_ids].set(blocked(v_new, v_pool)))
+    return (write_prompt_rows(k_pool, rows(k_new, k_pool), block_ids),
+            write_prompt_rows(v_pool, rows(v_new, v_pool), block_ids))
 
 
 def write_decode_blocks(k_pool: jax.Array, v_pool: jax.Array,
@@ -231,8 +271,8 @@ def write_decode_blocks(k_pool: jax.Array, v_pool: jax.Array,
     b = k_new.shape[0]
     kv = k_new.astype(k_pool.dtype).reshape(b, -1)      # (B, H*D)
     vv = v_new.astype(v_pool.dtype).reshape(b, -1)
-    return (k_pool.at[block_ids, offsets].set(kv),
-            v_pool.at[block_ids, offsets].set(vv))
+    return (write_decode_rows(k_pool, kv, block_ids, offsets),
+            write_decode_rows(v_pool, vv, block_ids, offsets))
 
 
 def gather_block_cache(pool: jax.Array, table: jax.Array,
@@ -243,9 +283,9 @@ def gather_block_cache(pool: jax.Array, table: jax.Array,
     A pure gather — values pass through bitwise, so attention over the
     gathered array equals attention over an equivalent contiguous
     cache bit-for-bit (tests/test_kv_pool.py pins it)."""
-    g = pool[table]                                 # (B, nb, bs, H*D)
-    b, nb, bs, hd = g.shape
-    return g.reshape(b, nb * bs, num_heads, hd // num_heads) \
+    g = gather_block_rows(pool, table)              # (B, nb*bs, H*D)
+    b, s, hd = g.shape
+    return g.reshape(b, s, num_heads, hd // num_heads) \
         .transpose(0, 2, 1, 3)
 
 
@@ -295,3 +335,38 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     visible = (jnp.arange(seq)[None, :] <= pos[:, None])    # (B, S)
     return block_attention(q, kc, vc, visible[:, None, :], visible,
                            sm_scale)
+
+
+def latent_paged_attention(q_lat: jax.Array, q_rope: jax.Array,
+                           pool: jax.Array, table: jax.Array,
+                           pos: jax.Array, rank: int,
+                           sm_scale: float) -> jax.Array:
+    """One query row per sequence against a pool of LATENT rows
+    (multi-head latent attention, decode in the absorbed form). A
+    token's row is `[c_kv ; k_rope]`: the `rank`-wide compressed
+    key/value, shared by all heads, then the one rotary key. With the
+    key up-projection absorbed into the query, `q_lat = q_nope W_UK^T`
+    (B, H, rank), a head's score is one contraction over the row,
+    `[q_lat ; q_rope] . [c_kv ; k_rope]`, and its output is
+    `sum_j p_j c_kv_j` (B, H, rank) in float32, which the caller takes
+    through W_UV: the same mathematics as expanding every row to
+    per-head keys and values, without ever holding them. pool
+    (N, bs, W >= rank + rope, the rest of a row zeros), table (B, nb),
+    pos (B,) as paged_attention: full table extent, positions <= pos
+    visible, invisible rows zeroed before the weighted sum (the
+    0 * NaN hygiene of block_attention)."""
+    rows = gather_block_rows(pool, table)           # (B, S, W)
+    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], axis=-1)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1]))
+                ).astype(rows.dtype)
+    s = jnp.einsum("bhc,bsc->bhs", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    visible = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+    s = jnp.where(visible[:, None, :], s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    probs = p / jnp.sum(p, axis=-1, keepdims=True)
+    rows = jnp.where(visible[:, :, None], rows, jnp.zeros((), rows.dtype))
+    out = jnp.einsum("bhs,bsc->bhc", probs.astype(rows.dtype), rows,
+                     preferred_element_type=jnp.float32)
+    return out[..., :rank]

@@ -19,6 +19,11 @@ are the only cross-device traffic, riding ICI.
 
 A load-balancing auxiliary loss (mean gate fraction × mean dispatch
 fraction × E, per Switch/GShard) is returned alongside the output.
+
+`DroplessMoE`, further down, is the other family of expert layer:
+top-k of many experts by a sigmoid router with a selection bias, a
+shared expert, no capacity and no dropped token (the DeepSeek-V3
+line of models). It is the serving FFN of models/latent_moe.py.
 """
 
 from __future__ import annotations
@@ -213,6 +218,117 @@ class MoE(Module):
         # aux is computed from THIS shard's tokens only — callers must
         # pmean it over the expert axis before using it as a loss term
         return (y.reshape(shape), aux), variables["state"]
+
+
+class DroplessMoE(Module):
+    """Dropless top-k expert layer with a sigmoid router, SiLU-gated
+    experts and a shared expert (DeepSeek-V3's `MoE` with
+    `topk_method: noaux_tc`, `scoring_func: sigmoid`, one group):
+
+        s      = sigmoid(x W_r)                      (T, E), float32
+        chosen = top_k(s + b)          b selects and does not weigh
+        w_i    = scale * s_i / sum_chosen s_j        (norm_topk_prob)
+        y      = sum_i w_i E_i(x) + E_shared(x)
+        E(x)   = (silu(x W_g) * x W_u) W_d
+
+    No capacity, no (T, E, C) tensor, no auxiliary loss: the T * k
+    assignments are sorted by expert and each of the three expert
+    matmuls is ONE `lax.ragged_dot` over the sorted rows with the
+    per-expert counts as group sizes, which the TPU compiler turns
+    into a grouped matmul that reads only the experts with rows. The
+    same program text serves 64 decode rows and a 2,048-token
+    prefill; every shape is static. `forward` also returns the
+    tokens each expert got (int32[E]), which is free here and what
+    the serving engine's `aux` carries (serving/engine.py).
+
+    Expert weights may be bfloat16; products accumulate in float32.
+    The router runs in float32 at the highest matmul precision: a
+    top-k over 256 near-equal scores is decided in the fourth digit.
+    Single-mesh: an expert layer that is told which experts it holds,
+    and its exchange, are ROADMAP B-I 3.
+    """
+
+    def __init__(self, dim: int, hidden: int, num_experts: int,
+                 top_k: int, shared_hidden: int = 0,
+                 scale: float = 1.0, normalize: bool = True,
+                 name: Optional[str] = None):
+        super().__init__(name=name)
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.dim, self.hidden, self.num_experts = dim, hidden, num_experts
+        self.top_k, self.shared_hidden = top_k, shared_hidden
+        self.scale, self.normalize = scale, normalize
+
+    def init_params(self, rng):
+        e, d, f, fs = (self.num_experts, self.dim, self.hidden,
+                       self.shared_hidden)
+        ks = jax.random.split(rng, 7)
+        init = Xavier()
+        p = {
+            "router": init(ks[0], (d, e), fan_in=d, fan_out=e),
+            "router_bias": jnp.zeros((e,), jnp.float32),
+            "w_gate": init(ks[1], (e, d, f), fan_in=d, fan_out=f),
+            "w_up": init(ks[2], (e, d, f), fan_in=d, fan_out=f),
+            "w_down": init(ks[3], (e, f, d), fan_in=f, fan_out=d),
+        }
+        if fs:
+            p.update(ws_gate=init(ks[4], (d, fs), fan_in=d, fan_out=fs),
+                     ws_up=init(ks[5], (d, fs), fan_in=d, fan_out=fs),
+                     ws_down=init(ks[6], (fs, d), fan_in=fs, fan_out=d))
+        return p
+
+    def route(self, p, x32):
+        """x32 (T, D) float32 → chosen experts (T, k) int32 and their
+        weights (T, k) float32."""
+        logits = jnp.dot(x32, p["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(s + p["router_bias"], self.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.normalize:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx, w * self.scale
+
+    def forward(self, p, x, x32=None):
+        """x (T, D) in the experts' compute dtype (x32: the same rows
+        in float32 for the router, where the caller has them) →
+        (y (T, D) float32, tokens per expert int32[E])."""
+        t, k, e = x.shape[0], self.top_k, self.num_experts
+        idx, w = self.route(p, x.astype(jnp.float32) if x32 is None
+                            else x32)
+        flat = idx.reshape(t * k)
+        order = jnp.argsort(flat)             # stable: ties by token
+        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        xs = x[order // k]                    # (T*k, D), expert-sorted
+
+        def grouped(a, wgt):
+            return lax.ragged_dot(a, wgt, counts,
+                                  preferred_element_type=jnp.float32)
+
+        h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
+        ys = grouped(h.astype(x.dtype), p["w_down"])      # (T*k, D)
+        # back to (token, choice) order, then the weighted sum over a
+        # token's k experts in a fixed order (no scatter-add)
+        y = ys[jnp.argsort(order)].reshape(t, k, self.dim)
+        y = jnp.sum(y * w[:, :, None], axis=1)
+        if self.shared_hidden:
+            y = y + gated_ffn(x, p["ws_gate"], p["ws_up"], p["ws_down"])
+        return y, counts
+
+    def apply(self, variables, x, training=False, rng=None):
+        p = variables["params"]
+        y, _ = self.forward(p, x.reshape(-1, self.dim))
+        return y.reshape(x.shape).astype(x.dtype), variables["state"]
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """(silu(x W_g) * x W_u) W_d with float32 accumulation; the hidden
+    activation goes back to x's dtype between the matmuls."""
+    def mm(a, b):
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(mm(x, w_gate)) * mm(x, w_up)
+    return mm(h.astype(x.dtype), w_down)
 
 
 def moe_specs(expert_axis: str = "expert"):

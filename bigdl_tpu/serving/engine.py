@@ -243,6 +243,13 @@ class EngineDraining(RuntimeError):
     scale-down path rely on exactly this contract."""
 
 
+def _first_leaf(pools):
+    """The first leaf of a pool-shaped tree (the engine's pools, a
+    handoff package's or a migrated entry's blocks), by position:
+    what its leaves are called is the model's business."""
+    return jax.tree_util.tree_leaves(pools)[0]
+
+
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def _prefill_step(model, params, pools, tokens, start, block_ids,
                   table_row):
@@ -272,17 +279,20 @@ def _decode_step(model, params, pools, tok, pos, seed, nout, temp,
     arming it never retraces. `attn_impl` (ISSUE 17) is STATIC like
     the model: engines sharing (model, attn_impl) share the one
     executable; flipping the impl is a distinct executable by
-    construction, never a silent retrace."""
+    construction, never a silent retrace. `aux` is a small pytree
+    the MODEL defines, a third result of its `decode_step_paged`
+    (models/latent_moe.py: the tokens each expert got); a model that
+    returns two has none, and its program is what it was. The engine
+    fetches it only while the tracer records."""
     _TRACES["decode"] += 1                # runs at trace time only
-    logits, pools = model.decode_step_paged({"params": params}, tok,
-                                            pos, pools, table,
-                                            attn_impl)
+    logits, pools, *aux = model.decode_step_paged(
+        {"params": params}, tok, pos, pools, table, attn_impl)
     logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
     finite = rows_finite(logits)
     keys = jax.vmap(lambda s, t: jax.random.fold_in(
         jax.random.PRNGKey(s), t))(seed, nout)
     nxt = sample_logits(logits, keys, temp, topk, topp)
-    return nxt, finite, pools
+    return nxt, finite, pools, tuple(aux)
 
 
 @dataclass
@@ -440,6 +450,12 @@ class InferenceEngine:
                  weight_dtype: str = "fp32",
                  model_tag: Optional[str] = None,
                  tenant_kv_quotas: Optional[Dict[str, int]] = None):
+        check = getattr(model, "check_serving_options", None)
+        if check is not None:
+            # a model that does not serve under every option says so
+            # here, before anything is built (models/latent_moe.py)
+            check(attn_impl=attn_impl, weight_dtype=weight_dtype,
+                  tp=tp_mesh is not None)
         if tp_mesh is not None:
             # memoized: engines over the same (model, mesh, axis)
             # share one wrapper and therefore every jitted executable
@@ -632,7 +648,7 @@ class InferenceEngine:
             "kv_spill_blocks": 0, "kv_readmit_blocks": 0,
             "kv_host_evictions": 0, "admit_requeue_exhausted": 0,
             "handoffs_out": 0, "handoffs_in": 0,
-            "weight_swaps": 0,
+            "weight_swaps": 0, "moe_tokens_routed": 0,
         }
         # ---- telemetry plane (ISSUE 5): every _stats increment also
         # mirrors into the process-wide registry under this engine's
@@ -687,6 +703,9 @@ class InferenceEngine:
                            "prefill tier",
             "weight_swaps": "weight hot-swaps re-placed into the live "
                             "serving layout (ISSUE 18)",
+            "moe_tokens_routed": "expert assignments of recorded decode "
+                                 "steps (a model's decode aux, fetched "
+                                 "only while the tracer is enabled)",
         }
         self._m_ops = {
             key: reg.counter(f"serving_{key}_total", help_,
@@ -758,6 +777,7 @@ class InferenceEngine:
         self._draining = False
         # prefill-role export queue, drained by take_handoffs()
         self._handoffs: List[HandoffPackage] = []
+        self._aux = None    # a recorded step's fetched model aux
         if step_timeout_s is not None:
             # arming the watchdog opts into a warmup decode at
             # construction: the FIRST decode call traces+compiles
@@ -1513,7 +1533,10 @@ class InferenceEngine:
                 self.pool = _prefill_step(
                     self.model, self._params, self.pool,
                     jnp.asarray(toks), np.int32(start),
-                    jnp.asarray(new, dtype=jnp.int32),
+                    # int32 on the host: jnp.asarray(list, dtype=)
+                    # dispatches a jit(convert_element_type) program
+                    # of its own, one more launch an admission
+                    jnp.asarray(np.asarray(new, np.int32)),
                     jnp.asarray(row[None, :]))
             if span.id is not None:
                 # THE one span that waits for the device, and only
@@ -1522,7 +1545,8 @@ class InferenceEngine:
                 # in the next decode_step. Tracer off: never reached
                 jax.block_until_ready(self.pool)  # graftlint: disable=hidden-device-sync
                 span.set(request=req.id, slot=slot, bucket=int(b),
-                         prefix_tokens=int(start), fenced=True)
+                         prefix_tokens=int(start), fenced=True,
+                         **self._prefill_span_args(int(b)))
         self._bump("prefill_calls")
         if start:
             self._bump("prefix_hits")
@@ -1539,6 +1563,11 @@ class InferenceEngine:
         if self._round_log is not None:
             self._round_log["admitted"].append(req.id)
         return True
+
+    def _prefill_span_args(self, bucket: int) -> dict:
+        """What the model adds to a recorded `prefill` span."""
+        extra = getattr(self.model, "prefill_span_args", None)
+        return extra(bucket) if extra is not None else {}
 
     def _finish(self, slot: int, reason: str,
                 status: str = "done") -> GenerationResult:
@@ -1749,19 +1778,39 @@ class InferenceEngine:
                     warnings.catch_warnings():
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
-                nxt, finite, pools = _decode_step(
+                nxt, finite, pools, aux = _decode_step(
                     self.model, self._params, self.pool, *args,
                     self.attn_impl)
             # THE one deliberate per-step device→host fetch: the host
             # needs the token, so the fetch doubles as the fence for
-            # the decode dispatch, inside the watchdog budget above
-            with self._span("fetch", parent):
-                return np.asarray(nxt), np.asarray(finite), pools  # graftlint: disable=hidden-device-sync
+            # the decode dispatch, inside the watchdog budget above.
+            # The model's `aux` comes after it, and only while the
+            # step is being recorded (the step has ended: no wait)
+            with self._span("fetch", parent) as span:
+                nxt, finite = np.asarray(nxt), np.asarray(finite)  # graftlint: disable=hidden-device-sync
+                if aux and span.id is not None:
+                    aux = jax.device_get(aux[0])  # graftlint: disable=hidden-device-sync
+                else:
+                    aux = None
+                return nxt, finite, pools, aux
 
-        nxt, finite, pools = _watchdog_call(
+        nxt, finite, pools, aux = _watchdog_call(
             work, self.step_timeout_s if watchdog else None)
         self.pool = pools
+        self._aux = aux
         return nxt, finite
+
+    def _report_aux(self, span) -> None:
+        """What a recorded step's `aux` says, in the model's words
+        (`decode_aux_report`), onto the `decode_step` span and into the
+        engine's counters."""
+        aux, self._aux = self._aux, None
+        if aux is None:
+            return
+        span_args, counters = self.model.decode_aux_report(aux)
+        span.set(**span_args)
+        for key, n in counters.items():
+            self._bump(key, n)
 
     def _ensure_blocks(self, horizons=None, exhaust: str = "finish"
                        ) -> Optional[List[GenerationResult]]:
@@ -1967,15 +2016,16 @@ class InferenceEngine:
         number of blocks grafted."""
         if not self.spill_enabled or not entries:
             return 0
-        ref = self.pool[0]["k"]
+        ref = _first_leaf(self.pool)
         for e in entries:
             kv = e["kv"]
+            got = _first_leaf(kv)
             if len(kv) != len(self.pool) \
-                    or tuple(kv[0]["k"].shape) != tuple(ref.shape[1:]) \
-                    or kv[0]["k"].dtype != ref.dtype:
+                    or tuple(got.shape) != tuple(ref.shape[1:]) \
+                    or got.dtype != ref.dtype:
                 raise ValueError(
                     f"migrated tree entry layout {len(kv)} layers x "
-                    f"{tuple(kv[0]['k'].shape)} ({kv[0]['k'].dtype}) "
+                    f"{tuple(got.shape)} ({got.dtype}) "
                     f"does not match this engine's {len(self.pool)} "
                     f"layers x {tuple(ref.shape[1:])} ({ref.dtype}) — "
                     "migration requires a same-layout fleet")
@@ -2017,22 +2067,23 @@ class InferenceEngine:
         if req.id in in_flight:
             raise ValueError(f"request id {req.id} already in flight "
                              "or completed-unclaimed")
-        pkg_bs = int(pkg.kv[0]["k"].shape[1])
+        got, ref = _first_leaf(pkg.kv), _first_leaf(self.pool)
+        pkg_bs = int(got.shape[1])
         if len(pkg.kv) != len(self.pool) \
                 or pkg_bs != self.block_size \
-                or pkg.kv[0]["k"].shape[1:] != self.pool[0]["k"].shape[1:] \
-                or pkg.kv[0]["k"].dtype != self.pool[0]["k"].dtype:
+                or got.shape[1:] != ref.shape[1:] \
+                or got.dtype != ref.dtype:
             # config error, not transient pressure: a mismatched fleet
             # (different block_size/model/cache dtype) can never seat
             # this package — a silent dtype cast in particular would
             # break the handoff bit-identity contract, not just crash
             raise ValueError(
                 f"handoff package layout {len(pkg.kv)} layers x "
-                f"{tuple(pkg.kv[0]['k'].shape[1:])} (block_size "
-                f"{pkg_bs}, {pkg.kv[0]['k'].dtype}) does not match "
+                f"{tuple(got.shape[1:])} (block_size "
+                f"{pkg_bs}, {got.dtype}) does not match "
                 f"this engine's {len(self.pool)} layers x "
-                f"{tuple(self.pool[0]['k'].shape[1:])} (block_size "
-                f"{self.block_size}, {self.pool[0]['k'].dtype}) — "
+                f"{tuple(ref.shape[1:])} (block_size "
+                f"{self.block_size}, {ref.dtype}) — "
                 "prefill and decode tiers must share model, "
                 "block_size and cache_dtype")
         free = self._free_slots()
@@ -2040,7 +2091,7 @@ class InferenceEngine:
             return False
         prompt = list(req.prompt)
         n = len(prompt)
-        nb = int(pkg.kv[0]["k"].shape[0])
+        nb = int(got.shape[0])
         if nb > self._table.shape[1]:
             # prompt spans more blocks than one slot's table row can
             # hold here (importer has a shorter max_len) — the backlog
@@ -2152,9 +2203,16 @@ class InferenceEngine:
                 # ending at their exception
                 with self._span("decode_step") as span_d:
                     if span_d.id is not None:
-                        span_d.set(step=stepno, active=n_active)
+                        # cache rows the step's attention has to read:
+                        # each seated slot's clock, and the row it writes
+                        span_d.set(step=stepno, active=n_active,
+                                   cached_tokens=int(sum(
+                                       self._pos[i] + 1 for i, r
+                                       in enumerate(self._req)
+                                       if r is not None)))
                     nxt, finite = self._dispatch_and_fetch(poison,
                                                            slow_s)
+                    self._report_aux(span_d)
                 # dispatch+fetch wall time into the fixed-bucket
                 # histogram UNCONDITIONALLY: health() percentiles are
                 # core engine bookkeeping (this store replaced the

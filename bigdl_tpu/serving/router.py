@@ -877,7 +877,7 @@ class EngineRouter:
                            router=self._obs_name,
                            request=pkg.request.id,
                            source=pkg.source, target=eng.obs_name,
-                           blocks=len(pkg.kv[0]["k"]),
+                           blocks=len(next(iter(pkg.kv[0].values()))),
                            trace=pkg.request.trace_id,
                            hop=pkg.request.hop)
             return eng

@@ -172,6 +172,9 @@ class SpeculativeEngine:
             if eng.degraded:
                 raise ValueError(f"{name} engine is already degraded "
                                  f"({eng.degraded})")
+            check = getattr(eng.model, "check_serving_options", None)
+            if check is not None:
+                check(speculative=True)
         if draft is target:
             raise ValueError("draft and target must be distinct "
                              "engines (self-speculation would pay the "
@@ -641,7 +644,7 @@ class SpeculativeEngine:
             with warnings.catch_warnings():
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
-                nxt, _, pools = _decode_step(
+                nxt, _, pools, _ = _decode_step(
                     d.model, d._params, d.pool,
                     jnp.asarray(tok), jnp.asarray(pos),
                     jnp.asarray(d._seed), jnp.asarray(nout),
@@ -675,7 +678,7 @@ class SpeculativeEngine:
             with warnings.catch_warnings():
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
-                nxt, finite, pools = _decode_step(
+                nxt, finite, pools, _ = _decode_step(
                     t.model, t._params, t.pool,
                     jnp.asarray(tok), jnp.asarray(pos),
                     jnp.asarray(seed), jnp.asarray(nout),
