@@ -312,6 +312,12 @@ class LatentMoELM(Module):
                 raise NotImplementedError(
                     f"LatentMoELM does not serve with {what}: {why}")
 
+    def decode_attn_form(self, attn_impl: str = "xla") -> str:
+        """`InferenceEngine`'s `attn_form` label: latent rows are
+        shared by all heads and attended as they are stored
+        (ops/kv_cache.latent_paged_attention)."""
+        return "rows"
+
     def init_block_pool(self, num_blocks: int, block_size: int,
                         dtype=jnp.float32):
         """Per-layer latent pools: a TUPLE of L dicts {'kv'}, each

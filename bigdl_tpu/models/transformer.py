@@ -714,6 +714,16 @@ class TransformerLM(Module):
                 self._ln(x, bp["ln2_g"], bp["ln2_b"]), bp)
         return tuple(new_pools)
 
+    def decode_attn_form(self, attn_impl: str = "xla", tp: int = 1) -> str:
+        """The label `InferenceEngine` reports as `attn_form`: what
+        `decode_step_paged` does with the cache under `attn_impl`, at
+        the heads one of `tp` shards holds
+        (ops/paged_decode.decode_attention_form)."""
+        from bigdl_tpu.ops.paged_decode import decode_attention_form
+
+        return decode_attention_form(
+            attn_impl, self.cfg.num_heads // tp, self.head_dim)
+
     def decode_step_paged(self, variables, tokens, pos, pools, table,
                           attn_impl: str = "xla"):
         """One incremental step over the paged pools: tokens/pos (B,)

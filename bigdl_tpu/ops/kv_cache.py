@@ -48,6 +48,31 @@ insertion at `(len(prompt) - 1) // block_size` full blocks, keeping
 the re-decoded last prompt token (and everything generated) out of
 shared blocks.
 
+Two operand layouts, one algorithm (ISSUE 29): the decode read,
+`paged_attention`, contracts either per head over a head-split copy of
+the gathered table (`paged_attention_heads`, the form all of the above
+was written about) or over the gathered rows as the pool stores them,
+with a block-diagonal query (`paged_attention_rows`): the second where
+a TPU would pad the split head, chosen by `paged_attention_form` from
+the shape and nothing else. Extent, mask, softmax and hygiene are the
+same; the rows form's two contractions are matmuls at the backend's
+default matmul precision, as the prefill's are. What that does to the
+pins:
+- PATH AGAINST THE SAME PATH hold bitwise in either form, because both
+  sides run the one compiled program over bitwise-equal cache rows:
+  warm == cold (the prefill programs are untouched, decode-written
+  positions are never shared), the spill / re-admit round trip, the
+  speculative verify against sequential decode (every row of a call
+  takes the same form), tp against tp=1 at equal local form.
+- FORM AGAINST FORM are bitwise only where the head-split form runs
+  (every toy width of the CPU suites): paged against the dense
+  `cached_attention`, the Pallas kernel against this oracle
+  (ops/paged_decode.py splits heads in VMEM). Where the rows form
+  runs they agree to a tolerance: 1e-5 relative in float32 on the CPU
+  (tests/test_rows_attention.py, tests/test_paged_decode.py), one
+  bfloat16 pass on a TPU (chip_smoke.py's kernel leg; the benchmark's
+  `correct` judges the served tokens).
+
 Host spill tier (ISSUE 16): the bit-identity contract is what makes a
 host-RAM block tier possible at all — a tree block's content is
 immutable after its prefill (COW discipline) and position-invariant in
@@ -317,24 +342,115 @@ def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    table: jax.Array, pos: jax.Array,
-                    sm_scale: Optional[float] = None) -> jax.Array:
-    """One query row per sequence against the paged pool: q
-    (B, H, 1, D), pools (N, bs, H*D), table (B, nb), pos (B,) — the
-    row clock, exactly as cached_attention. Gathers each row's blocks
-    and attends positions <= pos over the FULL table extent (nb*bs),
-    so the math is the dense cached_attention bit-for-bit when the
-    visible content matches. Returns (B, H, 1, D)."""
-    if q.shape[-2] != 1:
-        raise ValueError(f"paged_attention decodes one row, got q "
-                         f"length {q.shape[-2]}")
+def paged_attention_form(num_heads: int, head_dim: int) -> str:
+    """Which operand layout `paged_attention` contracts in, from the
+    shape alone: "rows" where a row is whole 128-lane tiles and a head
+    is not (H*D % 128 == 0, D % 128 != 0: gpt2-medium's 16 x 64), i.e.
+    exactly where a TPU would pad the split head (below); "heads"
+    otherwise. A 128-wide head splits without padding and the
+    block-diagonal query would only waste matmul work; rows that are
+    not whole tiles are the compiler's business already
+    (`init_block_pool`). Static per compiled program: the engine
+    reports it as a label (`health()["attn_form"]`, the `round`
+    span)."""
+    rows = (num_heads * head_dim) % 128 == 0 and head_dim % 128 != 0
+    return "rows" if rows else "heads"
+
+
+def paged_attention_heads(q: jax.Array, k_pool: jax.Array,
+                          v_pool: jax.Array, table: jax.Array,
+                          pos: jax.Array,
+                          sm_scale: Optional[float] = None) -> jax.Array:
+    """`paged_attention` in the head-split form: the gathered rows are
+    relaid (B, H, S, D) and attended per head by `block_attention`,
+    the prefill's core: the dense `cached_attention` bit for bit when
+    the visible content matches."""
     kc = gather_block_cache(k_pool, table, q.shape[1])
     vc = gather_block_cache(v_pool, table, q.shape[1])
     seq = kc.shape[-2]
     visible = (jnp.arange(seq)[None, :] <= pos[:, None])    # (B, S)
     return block_attention(q, kc, vc, visible[:, None, :], visible,
                            sm_scale)
+
+
+def paged_attention_rows(q: jax.Array, k_pool: jax.Array,
+                         v_pool: jax.Array, table: jax.Array,
+                         pos: jax.Array,
+                         sm_scale: Optional[float] = None) -> jax.Array:
+    """`paged_attention` over the gathered rows AS THEY ARE STORED,
+    heads side by side in the lanes: the head never becomes the minor
+    dimension of anything cache-sized. Splitting 1,024 lanes into 16
+    heads of 64 makes a TPU pad every 64 to a 128-lane tile, so the
+    head-split form rewrites each gathered table at twice its size and
+    reads that (48 reshapes, 58-60 of gpt2-medium's 134 ms decode
+    step: PERF.md, PR 29). Here the QUERY is laid out instead, block-
+    diagonally: row h of `qbd` (B, H, H*D) holds head h's D numbers at
+    lanes [h*D, (h+1)*D) and zeros elsewhere, so `qbd . row` is head
+    h's score, and head h's output is its own D lanes of
+    `probs[b, h] @ v_rows` (the diagonal of (B, H, H, D)). Same
+    mathematics, extent, mask (-1e30 AFTER the score contraction),
+    full-extent float32 softmax and zeroed value rows beyond the clock
+    as `block_attention`; H times the multiply-adds, on a matrix unit
+    that is otherwise idle.
+
+    Precision: the two contractions are matmuls and run at the
+    backend's default matmul precision like every other matmul of the
+    engine (the prefill's attention over the same cache included): on
+    a TPU one bfloat16 pass with float32 accumulation. Rows stay in
+    the pool's dtype into the dot (a bf16 pool is never widened).
+
+    Poison: with a block-diagonal query a non-finite number in ANY
+    head's lanes of a VISIBLE key row makes the whole SLOT's scores
+    non-finite (0 * NaN), where the head-split form loses that head;
+    the slot's logits are non-finite either way and the engine evicts
+    by slot. Rows beyond the clock are laundered as ever; other slots
+    never see it (the contraction is per slot)."""
+    b, h, _, d = q.shape
+    if sm_scale is None:
+        sm_scale = 1.0 / (d ** 0.5)
+    k = gather_block_rows(k_pool, table)            # (B, S, H*D)
+    v = gather_block_rows(v_pool, table)
+    visible = jnp.arange(k.shape[1])[None, :] <= pos[:, None]  # (B, S)
+    diag = jnp.eye(h, dtype=bool)[None, :, :, None]
+    qbd = jnp.where(diag, q[:, :, 0, None, :], 0.0) \
+        .reshape(b, h, h * d).astype(k.dtype)
+    s = jnp.einsum("bhl,bsl->bhs", qbd, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    # the where AFTER the matmul launders NaN scores a non-finite
+    # masked KEY row would produce
+    s = jnp.where(visible[:, None, :], s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    probs = p / jnp.sum(p, axis=-1, keepdims=True)
+    # 0.0 * NaN = NaN: value rows beyond the clock are zeroed exactly
+    # (block_attention's `valid` hygiene)
+    v = jnp.where(visible[:, :, None], v, jnp.zeros((), v.dtype))
+    o = jnp.einsum("bhs,bsl->bhl", probs.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    out = jnp.einsum("bhhd->bhd", o.reshape(b, h, h, d))
+    return out[:, :, None, :].astype(q.dtype)
+
+
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                    table: jax.Array, pos: jax.Array,
+                    sm_scale: Optional[float] = None) -> jax.Array:
+    """One query row per sequence against the paged pool: q
+    (B, H, 1, D), pools (N, bs, H*D), table (B, nb), pos (B,) — the
+    row clock, exactly as cached_attention. Gathers each row's blocks
+    and attends positions <= pos over the FULL table extent (nb*bs).
+    Returns (B, H, 1, D). One algorithm in two operand layouts, chosen
+    by `paged_attention_form` from the shape and nothing else: the
+    head-split form is the dense cached_attention bit for bit when the
+    visible content matches; the rows form is the same mathematics
+    within the rounding of a matmul (module docstring, "Two operand
+    layouts")."""
+    if q.shape[-2] != 1:
+        raise ValueError(f"paged_attention decodes one row, got q "
+                         f"length {q.shape[-2]}")
+    if paged_attention_form(q.shape[1], q.shape[-1]) == "rows":
+        return paged_attention_rows(q, k_pool, v_pool, table, pos,
+                                    sm_scale)
+    return paged_attention_heads(q, k_pool, v_pool, table, pos, sm_scale)
 
 
 def latent_paged_attention(q_lat: jax.Array, q_rope: jax.Array,

@@ -34,7 +34,12 @@ VMEM load here vs post-gather there — same values, so fp32 stays
 bitwise; bf16 is bitwise too but pinned only to tolerance). Compiled
 by Mosaic on the chip the kernel is held to a TOLERANCE against the
 oracle, not to bits (chip_smoke.py, kernel leg; what the bitwise pins
-cost there is ROADMAP C4).
+cost there is ROADMAP C4). This is a FORM AGAINST FORM pin
+(ops/kv_cache.py, "Two operand layouts"): the kernel splits heads in
+VMEM, so its interpret-mode bits are `paged_attention_heads`'s, at
+every width; against `paged_attention` itself they are bitwise where
+that takes the head-split form and within 1e-5 where it attends the
+rows as stored (a row of whole 128-lane tiles, a narrower head).
 
 Masking matches the oracle exactly: scores masked to -1e30 AFTER the
 q·K^T dot (NaN laundering of poisoned masked keys), value rows beyond
@@ -240,6 +245,18 @@ def _paged_decode_pallas(q, k_pool, v_pool, table, pos, sm_scale,
       *([k_pool] * block_tile), *([v_pool] * block_tile))
 
 
+def decode_attention_form(impl: str, num_heads: int, head_dim: int) -> str:
+    """What `paged_decode_attention` does with the cache at these
+    (local) widths, as a label: "kernel" for the Pallas launch, else
+    the operand layout of the XLA arm, "rows" or "heads"
+    (ops/kv_cache.paged_attention_form). Static per compiled
+    program."""
+    if impl != "xla":
+        return "kernel"
+    from bigdl_tpu.ops.kv_cache import paged_attention_form
+    return paged_attention_form(num_heads, head_dim)
+
+
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
                            pos: jax.Array,
@@ -253,9 +270,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     impl: None → auto ('pallas' on TPU, 'interpret' elsewhere);
     'xla' → the gather-then-attend oracle path (paged_attention
-    verbatim — the engine's default off-TPU); 'pallas' | 'interpret'
-    → the one-launch kernel. fp32 kernel output is BITWISE the oracle
-    in interpret mode (module docstring); tiles via `block_tile` /
+    verbatim, in the form its shape selects — the engine's default);
+    'pallas' | 'interpret' → the one-launch kernel. fp32 kernel output
+    is BITWISE the head-split oracle in interpret mode (module
+    docstring); tiles via `block_tile` /
     `head_tile` or the `BIGDL_PAGED_DECODE_TILES` snapshot, else one
     block and the fewest heads whose lanes Mosaic can stream (a
     compiled launch needs head_tile*D to be a multiple of 128, or all

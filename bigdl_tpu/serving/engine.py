@@ -508,6 +508,11 @@ class InferenceEngine:
                 "debt, ops/paged_decode.py) — serve sharded engines "
                 "with attn_impl='xla'")
         self.attn_impl = attn_impl
+        # what the decode program does with the cache at this model's
+        # (local) widths: "rows" / "heads" (ops/kv_cache
+        # .paged_attention_form, chosen from the shape) or "kernel".
+        # Static per compiled program, so a label and not a rate
+        self.attn_form = model.decode_attn_form(attn_impl)
         # weight layout (ISSUE 17; constructor arg, never env):
         # "fp32" is THE bit-identity reference layout every bitwise
         # pin runs on; "int8" repacks the serving gemm weights via
@@ -936,6 +941,7 @@ class InferenceEngine:
             # serving-layout provenance (ISSUE 17): which attention
             # impl decodes and which numerics family tokens carry
             "attn_impl": self.attn_impl,
+            "attn_form": self.attn_form,
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
             "model_tag": self.model_tag,
@@ -2177,7 +2183,8 @@ class InferenceEngine:
                 return self._round()
             finally:
                 self._round_log = None
-                span.set(attn_impl=self.attn_impl, **log)
+                span.set(attn_impl=self.attn_impl,
+                         attn_form=self.attn_form, **log)
 
     def _round(self) -> List[GenerationResult]:
         self._admit()

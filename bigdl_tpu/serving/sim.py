@@ -460,6 +460,7 @@ class SimulatedEngine:
             "tp": self.tp,
             "role": self.role,
             "attn_impl": "simulated",
+            "attn_form": "simulated",
             "weight_dtype": self._layout.split("/")[0],
             "cache_dtype": self._layout.split("/")[-1],
             "model_tag": self.model_tag,

@@ -212,6 +212,11 @@ class TPServingLM:
         return fn(p, pools, tokens, table, block_ids,
                   jnp.asarray(start, jnp.int32))
 
+    def decode_attn_form(self, attn_impl: str = "xla") -> str:
+        """The form is chosen from the LOCAL shape: a shard attends
+        its own heads (models/transformer.decode_attn_form)."""
+        return self.model.decode_attn_form(attn_impl, tp=self.tp)
+
     def decode_step_paged(self, variables, tokens, pos, pools, table,
                           attn_impl: str = "xla"):
         """Sharded decode step: per-head attention against the local

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.ops.kv_cache import init_block_pool, paged_attention
+from bigdl_tpu.ops.kv_cache import (init_block_pool, paged_attention,
+                                    paged_attention_form,
+                                    paged_attention_heads)
 from bigdl_tpu.ops.paged_decode import paged_decode_attention, resolve_tiles
 from bigdl_tpu.utils import envknobs
 
@@ -61,12 +63,22 @@ CONFIGS = [
 class TestInterpretParity:
     @pytest.mark.parametrize("b,h,nb,bs,d,bt,ht", CONFIGS)
     def test_fp32_bitwise(self, b, h, nb, bs, d, bt, ht):
+        # form against form (ops/kv_cache.py, "Two operand layouts"):
+        # the kernel splits heads in VMEM, so its bits are the
+        # head-split form's; where the XLA arm attends the rows as
+        # stored (the 43M shape: 8 x 64 lanes) they agree to rounding
         args = _case(b, h, nb, bs, d)
-        ref = paged_attention(*args)
+        ref = paged_attention_heads(*args)
         out = paged_decode_attention(*args, impl="interpret",
                                      block_tile=bt, head_tile=ht)
         assert out.dtype == ref.dtype
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        xla = np.asarray(paged_attention(*args))
+        if paged_attention_form(h, d) == "heads":
+            np.testing.assert_array_equal(np.asarray(out), xla)
+        else:
+            np.testing.assert_allclose(np.asarray(out), xla,
+                                       rtol=1e-5, atol=1e-5)
 
     def test_fp32_bitwise_ragged_clocks(self):
         # clocks mid-block, at a block boundary, and at 0: the

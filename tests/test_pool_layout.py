@@ -7,7 +7,10 @@ installed), on a 2-layer model at gpt2-medium's widths with the chat
 cell's pool geometry, and holds them to what the shape was chosen for:
 the device lays a leaf out block-major, no instruction relayouts a whole
 leaf on the way in or out, and every leaf is updated in place. A compile
-that passes is not a chip run.
+that passes is not a chip run. The decode program attends the gathered
+rows as they are stored (ISSUE 29): nothing cache-sized in it has a head
+for its minor dimension, and one layer's attention holds less in
+temporaries than the head-split form compiled beside it.
 
 libtpu is touched only inside the `topo` fixture (one process at a time
 may load it; a module that touches it while being imported breaks the
@@ -28,6 +31,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops.kv_cache import (gather_block_cache, init_block_pool,
+                                    paged_attention_form,
+                                    paged_attention_heads,
+                                    paged_attention_rows,
                                     write_decode_blocks,
                                     write_prompt_blocks)
 
@@ -67,7 +73,8 @@ def no_cache():
 @pytest.fixture(scope="module")
 def programs(topo, no_cache):
     """{'decode' | 'prefill': (entry instructions, module header)} of
-    the compiled programs, and the leaf's shape."""
+    the compiled programs, the decode program's whole text, and the
+    leaf's shape."""
     from bigdl_tpu.models.transformer import TransformerConfig, \
         TransformerLM
     from bigdl_tpu.serving import engine as eng
@@ -97,10 +104,12 @@ def programs(topo, no_cache):
               vec(i32, SLOTS, per_slot)))
     pre = on((params, pools, vec(i32, 1, BUCKET), vec(i32),
               vec(i32, BUCKET // BLOCK), vec(i32, 1, per_slot)))
+    decode_text = eng._decode_step.lower(model, *dec, "xla") \
+        .compile().as_text()
     return {
         "leaf": pools[0]["k"].shape,
-        "decode": _entry(eng._decode_step.lower(model, *dec, "xla")
-                         .compile().as_text()),
+        "decode": _entry(decode_text),
+        "decode_text": decode_text,
         "prefill": _entry(eng._prefill_step.lower(model, *pre)
                           .compile().as_text()),
     }
@@ -176,6 +185,44 @@ def test_every_pool_leaf_is_donated_in_place(programs, program):
     assert pool_params <= aliased, (
         f"pool parameters {sorted(pool_params - aliased)} are not in "
         f"input_output_alias ({sorted(aliased)})")
+
+
+def test_decode_never_makes_the_head_the_minor_dimension(programs):
+    """gpt2-medium's 16 x 64 lanes take the rows form: no instruction
+    of the decode program, inside a fusion or out, has a result over
+    the gathered extent (64 slots x 1,024 positions x 1,024 lanes)
+    whose minor dimension is one head's 64 (`f32[64,1024,16,64]`, which
+    the device pads to 128 lanes: the head-split form's two reshapes a
+    layer)."""
+    assert paged_attention_form(HEADS, DIM // HEADS) == "rows"
+    gathered = SLOTS * MAX_LEN * DIM
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= \w+\[([\d,]+)\]",
+                                     programs["decode_text"])}
+    assert any(int(np.prod(s)) == gathered for s in shapes)  # it gathers
+    split = sorted(s for s in shapes if s[-1] == DIM // HEADS
+                   and int(np.prod(s)) >= gathered)
+    assert not split, split
+
+
+def test_rows_form_holds_less_than_the_head_split_form(topo, no_cache):
+    """One layer's decode attention at the cells' shapes, both forms
+    compiled side by side: the rows form's temporaries are the smaller
+    (ISSUE 29's sizing: 0.27 against 0.84 GB)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    per_slot = MAX_LEN // BLOCK
+
+    def on(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    f32, i32 = jnp.float32, jnp.int32
+    args = (on(f32, SLOTS, HEADS, 1, DIM // HEADS),
+            on(f32, POOL_BLOCKS, BLOCK, DIM), on(f32, POOL_BLOCKS, BLOCK, DIM),
+            on(i32, SLOTS, per_slot), on(i32, SLOTS))
+    temp = {f.__name__: jax.jit(f).lower(*args).compile()
+            .memory_analysis().temp_size_in_bytes
+            for f in (paged_attention_rows, paged_attention_heads)}
+    assert temp["paged_attention_rows"] < 0.5 * temp["paged_attention_heads"], temp
 
 
 # ------------------------------------------------------------- CPU cases

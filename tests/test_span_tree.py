@@ -148,7 +148,8 @@ def test_every_round_has_one_decode_step_and_ordered_children(served):
         assert r["ts"] <= kids[0]["ts"] and _end(kids[-1]) <= _end(r)
         assert r["args"]["attn_impl"] == "xla"
         # the counts a reader uses, and no second copy of any of them
-        assert set(r["args"]) == {"id", "parent", "attn_impl",
+        assert r["args"]["attn_form"] == "heads"    # dim 32: toy widths
+        assert set(r["args"]) == {"id", "parent", "attn_impl", "attn_form",
                                   "admitted", "emitted"}
 
 
