@@ -1970,7 +1970,7 @@ def bench_lm_decode_quant(on_tpu, context=None, new_tokens=None,
         "fp32_ms_per_token": round(fp32_mspt, 3),
         "weight_dtype": q_eng.weight_dtype,
         "cache_dtype": q_eng.health()["cache_dtype"],
-        "attn_impl": q_eng.attn_impl,
+        "attn_form": q_eng.attn_form,
         "layout_family": q_eng.layout_family,
         "weight_bytes": q_eng._weight_bytes,
         "fp32_weight_bytes": fp32_eng._weight_bytes,

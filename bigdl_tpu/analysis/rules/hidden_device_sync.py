@@ -58,12 +58,9 @@ placement; everything else on those paths is host bookkeeping over
 block ids and numpy arrays, so any other fetch is a stealth sync per
 eviction or per admission.
 
-ISSUE 17 adds `ops/paged_decode.py` to the scope and quant/repack to
-the hot-name set: the one-launch paged-attention kernel runs INSIDE
-the jitted decode step (its launch wrapper and BlockSpec index maps
-are trace roots — a fetch there would sync once per decode step), and
-`serving/quant.py`'s repack (already inside the `serving/` prefix)
-must stay device-side jnp ops: quantization happens once at engine
+ISSUE 17 adds quant/repack to the hot-name set: `serving/quant.py`'s
+repack (already inside the `serving/` prefix) must stay device-side
+jnp ops: quantization happens once at engine
 construction, but a fetch hiding in `quantize_serving_params` would
 pull the whole fp32 tree to the host.
 
@@ -112,7 +109,6 @@ class HiddenDeviceSync(Rule):
              "bigdl_tpu/obs/flightrecorder.py",
              "bigdl_tpu/serving/",
              "bigdl_tpu/ops/kv_cache.py",
-             "bigdl_tpu/ops/paged_decode.py",
              "bigdl_tpu/models/transformer.py",
              "bigdl_tpu/parallel/param_layout.py")
 

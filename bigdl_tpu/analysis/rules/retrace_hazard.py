@@ -26,7 +26,7 @@ module is therefore checked with all parameters traced.
 ISSUE 17: Pallas KERNEL BODIES are trace roots too — a function
 handed to `pl.pallas_call` (directly, wrapped in
 `functools.partial(...)`, or via a variable holding such a partial —
-the `ops/paged_decode.py` / `ops/flash_attention.py` launch idiom) is
+the `ops/flash_attention.py` launch idiom) is
 traced with its Ref parameters as traced operands. The partial's
 bound arguments are the kernel's static escape hatch (grid constants
 like tile sizes and `dup_batch` are Python values by construction);

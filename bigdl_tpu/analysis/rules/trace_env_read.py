@@ -14,13 +14,10 @@ the snapshot.
 Module-top-level reads (import time, by construction before any trace)
 are allowed.
 
-ISSUE 17 additions ride the existing prefixes: `ops/paged_decode.py`
-(the one-launch decode kernel's tile knob `BIGDL_PAGED_DECODE_TILES`
-is an envknobs import snapshot — its launch wrapper runs inside the
-jitted decode step, the canonical place this bug class bites) and
-`serving/quant.py` (layout choices are CONSTRUCTOR args on the
-engine, never env — a quantization knob read here would freeze the
-first engine's layout into every later one).
+ISSUE 17's addition rides the existing prefixes: `serving/quant.py`
+(layout choices are CONSTRUCTOR args on the engine, never env — a
+quantization knob read here would freeze the first engine's layout
+into every later one).
 
 ISSUE 18 likewise: the speculation flywheel's knobs — adaptive
 lookahead (`adapt_k`, `k_min`, `adapt_window`, `raise_at`,

@@ -292,14 +292,11 @@ class LatentMoELM(Module):
 
     # ------------------------------------------------------ the paged trio
 
-    def check_serving_options(self, attn_impl="xla", weight_dtype="fp32",
-                              tp=False, speculative=False):
+    def check_serving_options(self, weight_dtype="fp32", tp=False,
+                              speculative=False):
         """What `InferenceEngine` and `SpeculativeEngine` ask a model
         that has limits; raises for what this one does not do."""
         for bad, what, why in (
-                (attn_impl != "xla", f"attn_impl={attn_impl!r}",
-                 "the paged-decode kernel reads per-head K and V pools; "
-                 "latent rows have no kernel yet"),
                 (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
                  "serving/quant.py repacks TransformerLM's block leaves"),
                 (tp, "tp_mesh",
@@ -312,7 +309,7 @@ class LatentMoELM(Module):
                 raise NotImplementedError(
                     f"LatentMoELM does not serve with {what}: {why}")
 
-    def decode_attn_form(self, attn_impl: str = "xla") -> str:
+    def decode_attn_form(self) -> str:
         """`InferenceEngine`'s `attn_form` label: latent rows are
         shared by all heads and attended as they are stored
         (ops/kv_cache.latent_paged_attention)."""
@@ -373,14 +370,12 @@ class LatentMoELM(Module):
             x = x + self._ffn(lp, kind, x)[0]
         return tuple(new_pools)
 
-    def decode_step_paged(self, variables, tokens, pos, pools, table,
-                          attn_impl: str = "xla"):
+    def decode_step_paged(self, variables, tokens, pos, pools, table):
         """As `TransformerLM.decode_step_paged`: tokens/pos (B,), table
         (B, max_blocks); writes each row's latent at (table[pos // bs],
         pos % bs), attends in the absorbed form. Returns (logits
         (B, V) float32, pools, aux): `aux` is int32 (MoE layers, E),
         the tokens each expert got in this step."""
-        self.check_serving_options(attn_impl=attn_impl)
         p = variables["params"] if "params" in variables else variables
         c = self.cfg
         b = tokens.shape[0]

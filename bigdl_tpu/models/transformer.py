@@ -714,18 +714,17 @@ class TransformerLM(Module):
                 self._ln(x, bp["ln2_g"], bp["ln2_b"]), bp)
         return tuple(new_pools)
 
-    def decode_attn_form(self, attn_impl: str = "xla", tp: int = 1) -> str:
-        """The label `InferenceEngine` reports as `attn_form`: what
-        `decode_step_paged` does with the cache under `attn_impl`, at
+    def decode_attn_form(self, tp: int = 1) -> str:
+        """The label `InferenceEngine` reports as `attn_form`: the
+        operand layout `decode_step_paged` attends the cache in, at
         the heads one of `tp` shards holds
-        (ops/paged_decode.decode_attention_form)."""
-        from bigdl_tpu.ops.paged_decode import decode_attention_form
+        (ops/kv_cache.paged_attention_form)."""
+        from bigdl_tpu.ops.kv_cache import paged_attention_form
 
-        return decode_attention_form(
-            attn_impl, self.cfg.num_heads // tp, self.head_dim)
+        return paged_attention_form(self.cfg.num_heads // tp,
+                                    self.head_dim)
 
-    def decode_step_paged(self, variables, tokens, pos, pools, table,
-                          attn_impl: str = "xla"):
+    def decode_step_paged(self, variables, tokens, pos, pools, table):
         """One incremental step over the paged pools: tokens/pos (B,)
         as decode_step, `table` (B, max_blocks) int32 block tables.
         Writes each row's k/v at (table[pos // bs], pos % bs) — always
@@ -762,17 +761,11 @@ class TransformerLM(Module):
         and the spec-vs-target-only token identity would be luck, not
         construction.
 
-        `attn_impl` (ISSUE 17, STATIC under jit — the engine threads
-        it as a static argnum): "xla" = the gather-then-attend oracle
-        (ops/kv_cache.paged_attention, the default and the bitwise
-        reference everywhere off-TPU); "pallas"/"interpret" = the
-        one-launch table-routed kernel (ops/paged_decode.py), fp32
-        interpret output bitwise == "xla". Because this step is also
-        the speculative verify entry, one knob covers plain decode,
-        draft decode, and the k+1-row verify with the same
-        executable-per-impl."""
-        from bigdl_tpu.ops.kv_cache import write_decode_blocks
-        from bigdl_tpu.ops.paged_decode import paged_decode_attention
+        How the cache is attended is `ops/kv_cache.paged_attention`'s
+        decision alone, made from the (local) head shape: plain
+        decode, draft decode and the k+1-row verify all take it."""
+        from bigdl_tpu.ops.kv_cache import (paged_attention,
+                                            write_decode_blocks)
 
         self._serving_guard(tp_ok=True)
         p = variables["params"] if "params" in variables else variables
@@ -797,8 +790,7 @@ class TransformerLM(Module):
             kp, vp = write_decode_blocks(pl["k"], pl["v"], k, v,
                                          block_ids, offsets)
             new_pools.append({"k": kp, "v": vp})
-            a = paged_decode_attention(q, kp, vp, table, pos,
-                                       impl=attn_impl)  # (B, h, 1, D)
+            a = paged_attention(q, kp, vp, table, pos)  # (B, h, 1, D)
             a = a.transpose(0, 2, 1, 3).reshape(bsz, h * d)
             if self.tp_axis is not None:
                 a = tp_shard_gather(a, self.tp_axis)

@@ -65,13 +65,13 @@ pins:
   speculative verify against sequential decode (every row of a call
   takes the same form), tp against tp=1 at equal local form.
 - FORM AGAINST FORM are bitwise only where the head-split form runs
-  (every toy width of the CPU suites): paged against the dense
-  `cached_attention`, the Pallas kernel against this oracle
-  (ops/paged_decode.py splits heads in VMEM). Where the rows form
-  runs they agree to a tolerance: 1e-5 relative in float32 on the CPU
-  (tests/test_rows_attention.py, tests/test_paged_decode.py), one
-  bfloat16 pass on a TPU (chip_smoke.py's kernel leg; the benchmark's
-  `correct` judges the served tokens).
+  (every toy width of the CPU suites; it is the prefill core's layout
+  and the form for 128-wide heads): paged against the dense
+  `cached_attention`. Where the rows form runs they agree to a
+  tolerance: 1e-5 relative in float32 on the CPU
+  (tests/test_rows_attention.py, tests/test_paged_attention.py), one
+  bfloat16 pass on a TPU (the benchmark's `correct` judges the served
+  tokens).
 
 Host spill tier (ISSUE 16): the bit-identity contract is what makes a
 host-RAM block tier possible at all — a tree block's content is
@@ -189,9 +189,8 @@ def init_block_pool(num_blocks: int, num_heads: int, block_size: int,
     THE CONTRACT every holder of a pool relies on: blocks are axis 0.
     Spill, re-admission, scrub, handoff and migration index axis 0
     only and never look inside a block; what is inside is this
-    module's business (`write_*_blocks`, `gather_block_cache`,
-    ops/paged_decode.py) and serving/tp.py's, which splits the last
-    axis by head.
+    module's business (`write_*_blocks`, `gather_block_cache`) and
+    serving/tp.py's, which splits the last axis by head.
 
     Why this shape: a TPU lays an array out in (8, 128) tiles over
     two dimensions of the compiler's choosing. Given a minor dimension

@@ -276,17 +276,18 @@ def _decode_step(model, params, pools, tok, pos, seed, nout, temp,
     a True row's logits are forced to NaN INSIDE the jitted step, so
     the drill exercises the same health reduction and eviction path a
     genuinely non-finite request would — and, being a (B,) operand,
-    arming it never retraces. `attn_impl` (ISSUE 17) is STATIC like
-    the model: engines sharing (model, attn_impl) share the one
-    executable; flipping the impl is a distinct executable by
-    construction, never a silent retrace. `aux` is a small pytree
+    arming it never retraces. `attn_impl` is a constant nothing reads:
+    the benchmark's AOT tests (tests/bench/test_aot.py:134,
+    test_aot_mla_moe.py:170) pass the literal as a thirteenth
+    argument, and only a `benchmark` PR may edit them (ROADMAP A0);
+    no caller in the program passes it. `aux` is a small pytree
     the MODEL defines, a third result of its `decode_step_paged`
     (models/latent_moe.py: the tokens each expert got); a model that
     returns two has none, and its program is what it was. The engine
     fetches it only while the tracer records."""
     _TRACES["decode"] += 1                # runs at trace time only
     logits, pools, *aux = model.decode_step_paged(
-        {"params": params}, tok, pos, pools, table, attn_impl)
+        {"params": params}, tok, pos, pools, table)
     logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
     finite = rows_finite(logits)
     keys = jax.vmap(lambda s, t: jax.random.fold_in(
@@ -446,7 +447,6 @@ class InferenceEngine:
                  obs_label: Optional[str] = None,
                  tp_mesh=None, tp_axis: str = "model",
                  role: str = "both",
-                 attn_impl: str = "xla",
                  weight_dtype: str = "fp32",
                  model_tag: Optional[str] = None,
                  tenant_kv_quotas: Optional[Dict[str, int]] = None):
@@ -454,8 +454,7 @@ class InferenceEngine:
         if check is not None:
             # a model that does not serve under every option says so
             # here, before anything is built (models/latent_moe.py)
-            check(attn_impl=attn_impl, weight_dtype=weight_dtype,
-                  tp=tp_mesh is not None)
+            check(weight_dtype=weight_dtype, tp=tp_mesh is not None)
         if tp_mesh is not None:
             # memoized: engines over the same (model, mesh, axis)
             # share one wrapper and therefore every jitted executable
@@ -491,28 +490,11 @@ class InferenceEngine:
         # 'both' (handoff imports AND direct admissions); 'prefill'
         # changes step() into the export path
         self.role = role
-        # decode-attention impl (ISSUE 17; constructor arg, never
-        # env): "xla" = gather-then-attend (ops/kv_cache, the bitwise
-        # reference and the off-TPU default), "pallas" = the
-        # one-launch table-routed kernel (ops/paged_decode.py, TPU
-        # only), "interpret" = the same kernel through the Pallas
-        # interpreter (CPU parity tests). Static in _decode_step, so
-        # each impl is its own executable — never a silent retrace.
-        if attn_impl not in ("xla", "pallas", "interpret"):
-            raise ValueError(f"attn_impl {attn_impl!r}: expected "
-                             "'xla', 'pallas' or 'interpret'")
-        if attn_impl != "xla" and tp_mesh is not None:
-            raise ValueError(
-                "attn_impl='pallas' under tp_mesh is not validated "
-                "(the kernel inside shard_map is on-chip measurement "
-                "debt, ops/paged_decode.py) — serve sharded engines "
-                "with attn_impl='xla'")
-        self.attn_impl = attn_impl
         # what the decode program does with the cache at this model's
         # (local) widths: "rows" / "heads" (ops/kv_cache
-        # .paged_attention_form, chosen from the shape) or "kernel".
-        # Static per compiled program, so a label and not a rate
-        self.attn_form = model.decode_attn_form(attn_impl)
+        # .paged_attention_form, chosen from the shape). Static per
+        # compiled program, so a label and not a rate
+        self.attn_form = model.decode_attn_form()
         # weight layout (ISSUE 17; constructor arg, never env):
         # "fp32" is THE bit-identity reference layout every bitwise
         # pin runs on; "int8" repacks the serving gemm weights via
@@ -815,9 +797,9 @@ class InferenceEngine:
     def swap_params(self, variables) -> None:
         """Hot-swap model weights (ISSUE 18): rebuild the serving
         layout from `variables` and re-point the jitted steps' params
-        OPERAND. The model (+ attn_impl) is the static jit argument
-        and the new tree arrives with identical structure/shapes/
-        dtypes, so the swap is pure re-placement — zero new
+        OPERAND. The model is the static jit argument and the new
+        tree arrives with identical structure/shapes/dtypes, so the
+        swap is pure re-placement — zero new
         executables (the `_TRACES` census pins it) and no quiesce:
         in-flight slots keep their KV bytes and decode their next
         token under the new weights. Swapping a speculative DRAFT is
@@ -939,8 +921,7 @@ class InferenceEngine:
             "tp": self.tp,
             "role": self.role,
             # serving-layout provenance (ISSUE 17): which attention
-            # impl decodes and which numerics family tokens carry
-            "attn_impl": self.attn_impl,
+            # form decodes and which numerics family tokens carry
             "attn_form": self.attn_form,
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
@@ -1785,8 +1766,7 @@ class InferenceEngine:
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
                 nxt, finite, pools, aux = _decode_step(
-                    self.model, self._params, self.pool, *args,
-                    self.attn_impl)
+                    self.model, self._params, self.pool, *args)
             # THE one deliberate per-step device→host fetch: the host
             # needs the token, so the fetch doubles as the fence for
             # the decode dispatch, inside the watchdog budget above.
@@ -2183,8 +2163,10 @@ class InferenceEngine:
                 return self._round()
             finally:
                 self._round_log = None
-                span.set(attn_impl=self.attn_impl,
-                         attn_form=self.attn_form, **log)
+                # attn_impl: a constant, read by benchmarks/harness/
+                # span_tree.py's `span tree:` print (ROADMAP A0)
+                span.set(attn_impl="xla", attn_form=self.attn_form,
+                         **log)
 
     def _round(self) -> List[GenerationResult]:
         self._admit()

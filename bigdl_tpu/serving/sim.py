@@ -459,7 +459,6 @@ class SimulatedEngine:
             "degraded_reason": self._degraded,
             "tp": self.tp,
             "role": self.role,
-            "attn_impl": "simulated",
             "attn_form": "simulated",
             "weight_dtype": self._layout.split("/")[0],
             "cache_dtype": self._layout.split("/")[-1],

@@ -651,7 +651,7 @@ class SpeculativeEngine:
                     jnp.asarray(d._temp), jnp.asarray(d._topk),
                     jnp.asarray(d._topp),
                     jnp.asarray(np.zeros(d.slots, bool)),
-                    jnp.asarray(table), d.attn_impl)
+                    jnp.asarray(table))
             # the draft half of the round's deliberate fetches: the
             # chain is sequential by nature (step j+1's input token IS
             # step j's sample), so one bounded host fetch per draft
@@ -684,7 +684,7 @@ class SpeculativeEngine:
                     jnp.asarray(seed), jnp.asarray(nout),
                     jnp.asarray(temp), jnp.asarray(topk),
                     jnp.asarray(topp), jnp.asarray(poison),
-                    jnp.asarray(table), t.attn_impl)
+                    jnp.asarray(table))
             # THE one deliberate per-round target fetch: the host
             # needs the tokens, so the fetch doubles as the fence for
             # the verify dispatch, inside the watchdog budget above

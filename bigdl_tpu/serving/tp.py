@@ -212,27 +212,16 @@ class TPServingLM:
         return fn(p, pools, tokens, table, block_ids,
                   jnp.asarray(start, jnp.int32))
 
-    def decode_attn_form(self, attn_impl: str = "xla") -> str:
+    def decode_attn_form(self) -> str:
         """The form is chosen from the LOCAL shape: a shard attends
         its own heads (models/transformer.decode_attn_form)."""
-        return self.model.decode_attn_form(attn_impl, tp=self.tp)
+        return self.model.decode_attn_form(tp=self.tp)
 
-    def decode_step_paged(self, variables, tokens, pos, pools, table,
-                          attn_impl: str = "xla"):
+    def decode_step_paged(self, variables, tokens, pos, pools, table):
         """Sharded decode step: per-head attention against the local
         pool shard, activation gathers keeping every contraction
         full-extent, logits replicated and bitwise == tp=1 — the
-        engine samples from them exactly as it would unsharded.
-
-        Only attn_impl='xla' is accepted: the Pallas kernel inside a
-        shard_map body is on-chip measurement debt (ISSUE 17), and the
-        engine constructor already refuses the combination — this
-        guard keeps the invariant local."""
-        if attn_impl != "xla":
-            raise ValueError(
-                f"tp decode is xla-only (got attn_impl={attn_impl!r}); "
-                "the paged-decode kernel under shard_map is ISSUE 17 "
-                "on-chip measurement debt")
+        engine samples from them exactly as it would unsharded."""
         p = variables["params"] if "params" in variables else variables
 
         def body(p, pools, tokens, pos, table):

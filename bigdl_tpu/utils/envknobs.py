@@ -32,11 +32,6 @@ Knobs:
   overrides for the flash-attention forward / fused-backward kernels
   (`FLASH_FWD_TILES` / `FLASH_BWD_TILES`). Malformed values raise at
   import — failing fast beats silently sweeping the default tiles.
-* `BIGDL_PAGED_DECODE_TILES` — "BTxHT" (KV-block-tile x head-tile)
-  override for the one-launch paged-attention decode kernel
-  (`PAGED_DECODE_TILES`; ops/paged_decode.py). Both must divide the
-  launch's block-table width / local head count — the kernel raises
-  otherwise, same fail-fast contract as the flash tiles.
 """
 
 from __future__ import annotations
@@ -71,7 +66,6 @@ FUSED_RNN_ENABLED: bool = True
 FUSED_RNN_BLOCK_N: Optional[int] = None
 FLASH_FWD_TILES: Optional[Tuple[int, int]] = None
 FLASH_BWD_TILES: Optional[Tuple[int, int]] = None
-PAGED_DECODE_TILES: Optional[Tuple[int, int]] = None
 
 
 def refresh() -> None:
@@ -79,12 +73,11 @@ def refresh() -> None:
     in-process sweeps/tests that rotate a knob deliberately; see the
     module docstring for the jit-cache caveat."""
     global FUSED_RNN_ENABLED, FUSED_RNN_BLOCK_N
-    global FLASH_FWD_TILES, FLASH_BWD_TILES, PAGED_DECODE_TILES
+    global FLASH_FWD_TILES, FLASH_BWD_TILES
     FUSED_RNN_ENABLED = _parse_switch("BIGDL_FUSED_RNN")
     FUSED_RNN_BLOCK_N = _parse_optional_int("BIGDL_FUSED_RNN_BLOCK_N")
     FLASH_FWD_TILES = _parse_tiles("BIGDL_FLASH_FWD_TILES")
     FLASH_BWD_TILES = _parse_tiles("BIGDL_FLASH_BWD_TILES")
-    PAGED_DECODE_TILES = _parse_tiles("BIGDL_PAGED_DECODE_TILES")
 
 
 refresh()

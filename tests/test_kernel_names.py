@@ -10,7 +10,7 @@ name. Checked here WITHOUT the TPU's compiler: each family's entry is
 lowered for the TPU platform at a tiny shape (lowering only runs Pallas's
 Mosaic lowering, which is Python) and every `tpu_custom_call` of the text
 must carry a name of its family. Metadata only — the numerics tests of
-each family (test_attention, test_paged_decode, test_fused_rnn) pin that
+each family (test_attention, test_fused_rnn) pin that
 nothing else moved."""
 
 import importlib
@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import pytest
 
 from bigdl_tpu.ops import fused_rnn as fr
-from bigdl_tpu.ops.paged_decode import paged_decode_attention
 
 # `bigdl_tpu.ops.flash_attention` the attribute is the function
 fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
@@ -73,17 +72,6 @@ def test_flash_backward_split(monkeypatch):
     monkeypatch.setattr(fa, "_FUSED_BWD_MAX_RESIDENT_BYTES", 0)
     names = _kernel_names(jax.grad(_attn_loss, argnums=(0, 1, 2)), *_qkv())
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-
-
-def test_paged_decode():
-    b, h, d, bs, nb, pool = 2, 2, 64, 16, 4, 9
-    names = _kernel_names(
-        lambda q, kp, vp, tbl, pos: paged_decode_attention(
-            q, kp, vp, tbl, pos, impl="pallas"),
-        _f32(b, h, 1, d), _f32(pool, bs, h * d), _f32(pool, bs, h * d),
-        jax.ShapeDtypeStruct((b, nb), jnp.int32),
-        jax.ShapeDtypeStruct((b,), jnp.int32))
-    assert names == ["paged_decode"]
 
 
 @pytest.mark.parametrize("scan,args,family", [

@@ -282,11 +282,8 @@ def test_spill_handoff_migration_and_scrub_on_a_latent_pool(lm):
 
 def test_what_the_model_does_not_serve_is_refused_by_name(lm):
     model, variables = lm
-    for kw, word in ((dict(attn_impl="pallas"), "attn_impl"),
-                     (dict(attn_impl="interpret"), "attn_impl"),
-                     (dict(weight_dtype="int8"), "int8")):
-        with pytest.raises(NotImplementedError, match=word):
-            _engine(lm, **kw)
+    with pytest.raises(NotImplementedError, match="int8"):
+        _engine(lm, weight_dtype="int8")
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("model",))
     with pytest.raises(NotImplementedError, match="tp_mesh"):
         _engine(lm, tp_mesh=mesh)

@@ -1,0 +1,164 @@
+"""`ops/kv_cache.paged_attention`, the one way the decode step attends
+the paged cache (ISSUE 31), against a plain reference.
+
+The reference is float32 NumPy attention over each slot's OWN contiguous
+rows `[0, pos]`, written here: it never sees a pool, a block table or
+anything of `ops/kv_cache`. The case builder lays those rows out into a
+pool through shuffled disjoint chains (block 0, the scratch block, in no
+chain) and the function under test has to find them again. Both operand
+layouts are held to it, each at widths where `paged_attention_form`
+chooses it from the shape: "heads" at the toy widths of the CPU suites
+and at a 128-wide head, "rows" where a row is whole 128-lane tiles and a
+head is not (gpt2-medium's 16 x 64). What the two forms owe each other is
+tests/test_rows_attention.py's. CPU, float32 unless a test says so."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bigdl_tpu.ops.kv_cache import (init_block_pool, paged_attention,
+                                    paged_attention_form)
+
+# one (b, h, nb, bs, d) per form for the tests that take `form`
+SHAPE_OF = {"heads": (3, 2, 4, 4, 8), "rows": (3, 2, 4, 4, 64)}
+FORMS = sorted(SHAPE_OF)
+
+
+def _case(b, h, nb, bs, d, seed=0, pos=None, dtype=jnp.float32,
+          unowned=None, tails_to_scratch=False):
+    """Dense per-slot keys and values (b, nb*bs, h*d) and the same rows
+    laid into a pool through shuffled disjoint chains. `unowned` fills
+    every block no visible row lives in (block 0 always among them);
+    `tails_to_scratch` points the table beyond a slot's clock at block
+    0, as the engine's table rows do. Returns (paged_attention's
+    arguments, the dense q, k, v and clocks the reference takes)."""
+    rng = np.random.RandomState(seed)
+    n = b * nb + 1
+    dense_k = rng.randn(b, nb * bs, h * d).astype(np.float32)
+    dense_v = rng.randn(b, nb * bs, h * d).astype(np.float32)
+    q = rng.randn(b, h, 1, d).astype(np.float32)
+    if pos is None:
+        pos = rng.randint(0, nb * bs, size=b)
+    pos = np.asarray(pos, np.int32)
+    table = rng.permutation(np.arange(1, n)).reshape(b, nb).astype(np.int32)
+    if dtype != jnp.float32:        # the reference sees what the pool holds
+        dense_k, dense_v, q = (
+            np.asarray(jnp.asarray(a, dtype).astype(jnp.float32))
+            for a in (dense_k, dense_v, q))
+    k_pool, v_pool = (np.array(p) for p in init_block_pool(n, h, bs, d))
+    assert k_pool.shape == (n, bs, h * d)
+    if unowned is not None:
+        k_pool[:] = v_pool[:] = unowned
+    for slot in range(b):
+        live = int(pos[slot]) // bs + 1 if tails_to_scratch else nb
+        for j in range(live):
+            k_pool[table[slot, j]] = dense_k[slot, j * bs:(j + 1) * bs]
+            v_pool[table[slot, j]] = dense_v[slot, j * bs:(j + 1) * bs]
+        table[slot, live:] = 0
+    args = (jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
+            jnp.asarray(v_pool, dtype), jnp.asarray(table), jnp.asarray(pos))
+    return args, (q, dense_k, dense_v, pos)
+
+
+def _reference(q, dense_k, dense_v, pos, sm_scale=None):
+    """Softmax attention, one slot and one head at a time, over the
+    slot's rows [0, pos]: (b, h, 1, d) float32."""
+    b, h, _, d = q.shape
+    scale = 1.0 / np.sqrt(d) if sm_scale is None else sm_scale
+    out = np.zeros((b, h, 1, d), np.float32)
+    for slot in range(b):
+        seen = int(pos[slot]) + 1
+        for head in range(h):
+            lanes = slice(head * d, (head + 1) * d)
+            k = dense_k[slot, :seen, lanes]
+            v = dense_v[slot, :seen, lanes]
+            s = (k @ q[slot, head, 0]) * np.float32(scale)
+            p = np.exp(s - s.max())
+            out[slot, head, 0] = (p / p.sum()) @ v
+    return out
+
+
+def _close(got, want, tol=1e-5):
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol,
+                               atol=tol * np.abs(want).max())
+
+
+@pytest.mark.parametrize("b,h,nb,bs,d,form", [
+    (1, 1, 1, 4, 8, "heads"),       # one slot, one head, one block
+    (2, 2, 4, 4, 8, "heads"),
+    (3, 4, 4, 4, 16, "heads"),      # odd batch
+    (1, 4, 4, 4, 8, "heads"),
+    (4, 8, 8, 16, 64, "rows"),      # the 43M model of the engine suites
+    (2, 1, 4, 4, 8, "heads"),       # one head, several slots
+    (2, 2, 4, 4, 128, "heads"),     # a head is a whole tile: splits unpadded
+    (2, 16, 4, 16, 64, "rows"),     # gpt2-medium's widths
+], ids=lambda v: str(v))
+def test_paged_attention_equals_the_plain_reference(b, h, nb, bs, d, form):
+    assert paged_attention_form(h, d) == form
+    args, dense = _case(b, h, nb, bs, d)
+    got = paged_attention(*args)
+    assert got.shape == (b, h, 1, d) and got.dtype == jnp.float32
+    _close(got, _reference(*dense))
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_ragged_clocks(form):
+    """Clocks at 0, mid-block, at a block boundary, at the last
+    position: the visible extent is [0, pos], no more and no less."""
+    b, h, nb, bs, d = SHAPE_OF[form]
+    assert paged_attention_form(h, d) == form
+    for pos in ([0, 2, bs], [bs - 1, 2 * bs, nb * bs - 1]):
+        args, dense = _case(b, h, nb, bs, d, pos=pos)
+        _close(paged_attention(*args), _reference(*dense))
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_custom_sm_scale(form):
+    args, dense = _case(*SHAPE_OF[form])
+    got = paged_attention(*args, 0.25)
+    _close(got, _reference(*dense, sm_scale=0.25))
+    assert not np.allclose(np.asarray(got), np.asarray(paged_attention(*args)))
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_jit_equals_eager(form):
+    """The engine runs it inside a jitted step, the tests above eagerly:
+    the same numbers to rounding (fusion may reorder a sum)."""
+    args, dense = _case(*SHAPE_OF[form])
+    got = jax.jit(paged_attention)(*args)
+    _close(got, np.asarray(paged_attention(*args)), tol=1e-6)
+    _close(got, _reference(*dense))
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_a_nan_scratch_block_and_table_tail_are_never_read(form):
+    """Block 0 and every block that holds no visible row are NaN, and
+    each table row points at block 0 beyond its clock, as the engine's
+    do: nothing of that reaches the result (scores are masked after the
+    contraction, value rows zeroed before theirs)."""
+    b, h, nb, bs, d = SHAPE_OF[form]
+    args, dense = _case(b, h, nb, bs, d, pos=[1, bs, 2 * bs + 1],
+                        unowned=np.nan, tails_to_scratch=True)
+    assert np.isnan(np.asarray(args[1][0])).all()
+    assert (np.asarray(args[3])[:, -1] == 0).all()
+    got = np.asarray(paged_attention(*args))
+    assert np.isfinite(got).all()
+    _close(got, _reference(*dense))
+
+
+def test_bf16_pool_heads_form_within_the_bf16_tolerance():
+    """A bfloat16 pool: the head-split form widens the gathered rows to
+    float32 and rounds once, on the way out (the rows form's own bf16
+    bound is tests/test_rows_attention.py's)."""
+    args, dense = _case(2, 4, 4, 4, 16, dtype=jnp.bfloat16)
+    got = paged_attention(*args)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               _reference(*dense), atol=2e-2, rtol=2e-2)
+
+
+def test_rejects_a_query_of_more_than_one_row():
+    (q, *rest), _ = _case(2, 2, 4, 4, 8)
+    with pytest.raises(ValueError, match="one row"):
+        paged_attention(jnp.concatenate([q, q], axis=2), *rest)

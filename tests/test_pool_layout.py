@@ -104,7 +104,7 @@ def programs(topo, no_cache):
               vec(i32, SLOTS, per_slot)))
     pre = on((params, pools, vec(i32, 1, BUCKET), vec(i32),
               vec(i32, BUCKET // BLOCK), vec(i32, 1, per_slot)))
-    decode_text = eng._decode_step.lower(model, *dec, "xla") \
+    decode_text = eng._decode_step.lower(model, *dec) \
         .compile().as_text()
     return {
         "leaf": pools[0]["k"].shape,

@@ -3,9 +3,10 @@ repack (structure preservation, dequant error bound, bytes win), the
 int8-weight/bf16-KV engine end to end under the TOLERANCE contract
 (lossy by design — the fp32 bitwise pins stay fp32-scoped and are
 re-run untouched by test_kv_pool/test_tp_serving/test_speculative),
-per-engine constructor gating (layout and attn_impl are ctor args,
-never env; tp engines refuse both — their pins are bitwise), the
-#buckets+1 compile contract re-run with quant + attn_impl armed, and
+per-engine constructor gating (the layout is a ctor arg, never env; tp
+engines refuse the lossy one — their pins are bitwise; how decode
+attends the cache is no option at all), the #buckets+1 compile
+contract re-run with quant armed, and
 the router refusing cross-layout-family failover."""
 
 import jax
@@ -122,7 +123,7 @@ class TestQuantEngine:
         h = eng.health()
         assert h["weight_dtype"] == "int8"
         assert h["cache_dtype"] == "bfloat16"
-        assert h["attn_impl"] == "xla"
+        assert h["attn_form"] == "heads"      # chosen from the shape
         assert eng.layout_family == "int8/bfloat16"
         assert _engine().layout_family == "fp32/float32"
 
@@ -178,17 +179,18 @@ class TestGating:
     def test_ctor_rejects_unknown_layout(self):
         with pytest.raises(ValueError, match="weight_dtype"):
             _engine(weight_dtype="fp16")
-        with pytest.raises(ValueError, match="attn_impl"):
-            _engine(attn_impl="mosaic")
+        # how decode attends the cache is chosen from the shape
+        # (ops/kv_cache.paged_attention_form): the constructor has no
+        # attn_impl to set
+        with pytest.raises(TypeError, match="attn_impl"):
+            _engine(attn_impl="xla")
 
-    def test_tp_mesh_refuses_lossy_and_kernel(self):
+    def test_tp_mesh_refuses_the_lossy_layout(self):
         # a 1-device mesh exercises the guard without multi-device
         # XLA flags: the refusal is about the LAYOUT, not the degree
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("model",))
         with pytest.raises(ValueError, match="tp"):
             _engine(tp_mesh=mesh, weight_dtype="int8")
-        with pytest.raises(ValueError, match="tp"):
-            _engine(tp_mesh=mesh, attn_impl="interpret")
 
     def test_router_refuses_cross_family_failover(self):
         """An fp32 engine dies mid-decode with only an int8 survivor:

@@ -153,7 +153,7 @@ def test_engine_surface_and_validation():
         _sim_engine(clk, pacing="warp")
     eng = _sim_engine(clk, obs_label="simT")
     h = eng.health()
-    assert h["state"] == "ok" and h["attn_impl"] == "simulated"
+    assert h["state"] == "ok" and h["attn_form"] == "simulated"
     assert h["slots"] == 2 and h["queue_depth"] == 0
     assert eng.obs_name == "simT"
     # one sim_calibration provenance event per engine construction
