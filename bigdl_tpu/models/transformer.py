@@ -750,11 +750,14 @@ class TransformerLM(Module):
         layer writes all rows' k/v (write_decode_blocks, distinct
         (block, offset) destinations) before any row's attention
         gathers the pool, so row j SEES rows < j's writes — and
-        because every op here is per-row with the full-table
-        attention extent, a verify row's logits are BITWISE the
-        logits the sequential one-row call computes for that position
-        (per-row bits are batch-extent-independent on this backend;
-        verified at the tiny and 43M shapes). Scoring positions as
+        because every op here is per-row and a row's attention hangs
+        on its own clock alone (the full table extent in the
+        head-split form, the row's own live chunks in the rows form:
+        ops/kv_cache.py, "Bit-identity contract"), a verify row's
+        logits are BITWISE the logits the sequential one-row call
+        computes for that position (per-row bits are
+        batch-extent-independent on this backend; verified at the
+        tiny and 43M shapes). Scoring positions as
         Q=1 rows rather than as a Q=k+1 prefill is deliberate: Q=1
         and Q>=2 gemms lower to different kernels (ops/kv_cache.py),
         so a prefill-shaped verify would score in the wrong regime

@@ -33,37 +33,47 @@ table entries point at it, inactive batch rows write their garbage
 into it, and no reader ever sees it unmasked.
 
 Bit-identity contract (the load-bearing bar of the prefix cache):
-every attention read — multi-row suffix prefill and one-row decode —
-spans the FULL gathered table extent with per-query masking, so the
-reduction shapes (and therefore the fp32 accumulation order) are
-independent of WHERE a position was computed: a KV row produced by a
-cold bucket-64 prefill, a warm bucket-16 suffix prefill after a prefix
-hit, or a donor request's earlier prefill is bitwise the same array,
-and cached-prefix decode emits tokens bit-identical to cold decode
-(pinned by tests/test_kv_pool.py and the serve_prefix drill). The one
-deliberate asymmetry: Q=1 decode gemms lower to different kernels
-than Q>=2 prefill gemms (measured on CPU XLA), so positions a decode
-step wrote are NEVER shared — the serving engine caps reuse and tree
-insertion at `(len(prompt) - 1) // block_size` full blocks, keeping
-the re-decoded last prompt token (and everything generated) out of
-shared blocks.
+every PREFILL read (the multi-row suffix prefill) spans the FULL
+gathered table extent with per-query masking, so the reduction shapes
+(and therefore the fp32 accumulation order) are independent of WHERE a
+position was computed: a KV row produced by a cold bucket-64 prefill,
+a warm bucket-16 suffix prefill after a prefix hit, or a donor
+request's earlier prefill is bitwise the same array, and cached-prefix
+decode emits tokens bit-identical to cold decode (pinned by
+tests/test_kv_pool.py and the serve_prefix drill). The one-row DECODE
+read writes nothing another request shares, so what it owes is less:
+that a row's result hangs on that row's own query, clock and cache
+rows and on nothing else of the call. The head-split form
+(`paged_attention_heads`) keeps it by reading the full extent too; the
+rows and latent forms read each slot's own live chunks, in order
+(`_ragged_attention`, ISSUE 32), at shapes the table's shape fixes.
+The one deliberate asymmetry: Q=1 decode gemms lower to different
+kernels than Q>=2 prefill gemms (measured on CPU XLA), so positions a
+decode step wrote are NEVER shared — the serving engine caps reuse and
+tree insertion at `(len(prompt) - 1) // block_size` full blocks,
+keeping the re-decoded last prompt token (and everything generated)
+out of shared blocks.
 
 Two operand layouts, one algorithm (ISSUE 29): the decode read,
 `paged_attention`, contracts either per head over a head-split copy of
-the gathered table (`paged_attention_heads`, the form all of the above
-was written about) or over the gathered rows as the pool stores them,
-with a block-diagonal query (`paged_attention_rows`): the second where
-a TPU would pad the split head, chosen by `paged_attention_form` from
-the shape and nothing else. Extent, mask, softmax and hygiene are the
+the gathered table (`paged_attention_heads`, the form the prefill core
+has) or over the gathered rows as the pool stores them, with a
+block-diagonal query (`paged_attention_rows`): the second where a TPU
+would pad the split head, chosen by `paged_attention_form` from the
+shape and nothing else. Mask, float32 softmax and hygiene are the
 same; the rows form's two contractions are matmuls at the backend's
-default matmul precision, as the prefill's are. What that does to the
-pins:
+default matmul precision, as the prefill's are, and its softmax is
+taken a chunk at a time and folded (the same mathematics within
+float32 rounding). What that does to the pins:
 - PATH AGAINST THE SAME PATH hold bitwise in either form, because both
-  sides run the one compiled program over bitwise-equal cache rows:
-  warm == cold (the prefill programs are untouched, decode-written
-  positions are never shared), the spill / re-admit round trip, the
-  speculative verify against sequential decode (every row of a call
-  takes the same form), tp against tp=1 at equal local form.
+  sides run the one compiled program over bitwise-equal cache rows and
+  a row's result hangs on nothing else: warm == cold (the prefill
+  programs are untouched, decode-written positions are never shared),
+  the spill / re-admit round trip, the speculative verify against
+  sequential decode (every row of a call takes the same form and its
+  own clock's chunks, whatever rows are called beside it:
+  tests/test_paged_attention.py), a slot beside other neighbours, tp
+  against tp=1 at equal local form.
 - FORM AGAINST FORM are bitwise only where the head-split form runs
   (every toy width of the CPU suites; it is the prefill core's layout
   and the form for 128-wide heads): paged against the dense
@@ -71,7 +81,8 @@ pins:
   tolerance: 1e-5 relative in float32 on the CPU
   (tests/test_rows_attention.py, tests/test_paged_attention.py), one
   bfloat16 pass on a TPU (the benchmark's `correct` judges the served
-  tokens).
+  tokens). The latent form (`latent_paged_attention`) is held to the
+  same tolerance against the naive form (tests/test_latent_moe.py).
 
 Host spill tier (ISSUE 16): the bit-identity contract is what makes a
 host-RAM block tier possible at all — a tree block's content is
@@ -91,10 +102,13 @@ serve_spill drill).
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 _NEG_INF = -1e30
@@ -372,6 +386,173 @@ def paged_attention_heads(q: jax.Array, k_pool: jax.Array,
                            sm_scale)
 
 
+# chunks a slot's table is read in, and the shares of all the chunks of
+# a batch that the read is compiled for: constants chosen once from
+# chip measurements (PERF.md, PR 32), never knobs. Every size is a copy
+# of the read in every layer's program (1.3 MB of device code each at
+# gpt2-medium's widths, 32 MB over 24 layers, and seconds of compile),
+# so a size is here only where a cell of the benchmark has shown end to
+# end what it buys: one for a batch that is mostly empty seats, and all
+# of it.
+_CHUNKS_PER_SLOT = 8
+_READ_SHARES = (1 / 16, 1.0)
+
+
+def ragged_read_sizes(slots: int, blocks_per_slot: int
+                      ) -> Tuple[int, Tuple[int, ...]]:
+    """The static half of the ragged decode read, from the block
+    table's shape alone: (blocks a chunk, the chunk counts a read is
+    compiled for, ascending, the last one every chunk of every slot).
+    A chunk is `blocks_per_slot / 8` blocks rounded up (8 blocks = 128
+    rows at gpt2-medium's 64 x 16 table)."""
+    chunk_blocks = -(-blocks_per_slot // _CHUNKS_PER_SLOT)
+    most = slots * -(-blocks_per_slot // chunk_blocks)
+    sizes = sorted({max(1, math.ceil(most * s)) for s in _READ_SHARES})
+    return chunk_blocks, tuple(sizes)
+
+
+def _live_chunks(pos, first_block, block_size: int, chunk_blocks: int):
+    """Chunks each slot's clock reaches into, (B,): its blocks
+    `pos // block_size + 1` rounded up to whole chunks; 0 for a row
+    whose first table entry is the scratch block (not seated: a seated
+    slot's first entry never is block 0). NumPy on the host, jax.numpy
+    inside the program: the same arithmetic."""
+    blocks = (pos // block_size + 1) * (first_block != 0)
+    return (blocks + chunk_blocks - 1) // chunk_blocks
+
+
+def _read_size_index(chunks, sizes: Tuple[int, ...]):
+    """Index of the smallest compiled read that holds `chunks`."""
+    return sum(chunks > n for n in sizes[:-1])
+
+
+def attended_blocks(pos, table, block_size: int) -> int:
+    """Blocks the decode read gathers for these clocks and this table
+    (host, NumPy): every seated slot's blocks rounded up to whole
+    chunks, their sum rounded up to the read compiled for it. The
+    ragged core takes both roundings from the same three functions, so
+    the engine's `attended_blocks` is what the program read. Never
+    more than the table holds: where a slot's last chunk is short of
+    whole (`blocks_per_slot` no multiple of the chunk), the rest of it
+    is the scratch block and not the table's."""
+    pos, table = np.asarray(pos), np.asarray(table)
+    chunk_blocks, sizes = ragged_read_sizes(*table.shape)
+    chunks = int(_live_chunks(pos, table[:, 0], block_size,
+                              chunk_blocks).sum())
+    read = sizes[int(_read_size_index(chunks, sizes))] * chunk_blocks
+    return min(read, table.size)
+
+
+@functools.partial(jax.jit, static_argnames=("lanes",))
+def _ragged_attention(q: jax.Array, k_pool: jax.Array,
+                      v_pool: Optional[jax.Array], table: jax.Array,
+                      pos: jax.Array, sm_scale, lanes: Optional[int]
+                      ) -> jax.Array:
+    """The decode read's one core: a query matrix q (B, Hq, W) against
+    each slot's OWN live rows of the pools (N, bs, W), float32 masked
+    softmax, `probs @ rows`. Returns what the caller keeps of the
+    product (B, Hq, W), in float32: its first `lanes` lanes, or with
+    `lanes=None` row h's own W/Hq lanes (B, Hq, W/Hq), the diagonal
+    that a block-diagonal query asks for. `v_pool=None`: a pool whose
+    rows are key and value at once (one gather).
+
+    Ragged per slot, in one program: the table is cut into chunks of
+    `chunk_blocks` blocks, and the live chunks of the whole batch
+    (those a seated slot's clock reaches into) are laid end to end,
+    slot after slot, as the ITEMS of one batched read: item t gathers
+    its chunk's blocks, contracts them with its slot's query, masks
+    (-1e30 AFTER the contraction), zeroes value rows beyond the clock
+    (0 * NaN) and takes a float32 softmax over its own rows: a (max,
+    sum, product) a chunk. A slot's items are then folded in chunk
+    order into its result. The number of items is rounded up to one of
+    a few compiled sizes (`lax.switch`, one conditional a call); the
+    items past the live ones point at the scratch block, are masked
+    whole and folded nowhere.
+
+    What a slot's result depends on: its own query, clock and rows.
+    Every step of it is per item or per slot at a shape the table's
+    shape fixes, so other slots' clocks, the compiled size that runs
+    and the batch a row is called in change no bit of it. A row that
+    is not seated reads nothing and returns zeros.
+
+    Jitted here, so that a caller outside a jitted step (the tests,
+    the benchmark's logit checks) compiles the conditional's branches
+    once a shape and not once a call; inside a step it is inlined."""
+    hq, width = q.shape[1:]
+
+    def keep(o):                        # (T, Hq, W) -> what is kept
+        if lanes is not None:
+            return o[..., :lanes]
+        return jnp.einsum("thhd->thd",
+                          o.reshape(-1, hq, hq, width // hq))
+
+    b, nb = table.shape
+    bs = k_pool.shape[1]
+    chunk_blocks, sizes = ragged_read_sizes(b, nb)
+    per_slot = -(-nb // chunk_blocks)
+    span = chunk_blocks * bs                        # rows of an item
+    chunks = _live_chunks(pos, table[:, 0], bs, chunk_blocks)   # (B,)
+    ends = jnp.cumsum(chunks)
+    starts = ends - chunks
+    item = jnp.arange(sizes[-1])
+    # the slot an item belongs to: how many slots end at or before it
+    slot = jnp.minimum(
+        jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
+    chunk = jnp.clip(item - starts[slot], 0, per_slot - 1)
+    live = item < ends[-1]
+    table = jnp.pad(table, ((0, 0), (0, per_slot * chunk_blocks - nb)))
+    ids = jnp.where(live[:, None],
+                    table.reshape(b, per_slot, chunk_blocks)[slot, chunk],
+                    0)                              # (T, chunk_blocks)
+    # rows of the item at or before its slot's clock (> span: all)
+    seen = jnp.where(live, pos[slot] + 1 - chunk * span, 0)
+    mine = jnp.arange(per_slot)[None, :] < chunks[:, None]  # (B, C)
+    at = jnp.where(mine, starts[:, None] + jnp.arange(per_slot), 0)
+    per_pass = max(1, sizes[-1] // 2)               # items a pass
+
+    def items(lo, hi):
+        """Items [lo, hi): each one's (max, sum, kept product)."""
+        n = hi - lo
+        k = k_pool[ids[lo:hi]].reshape(n, span, -1)         # (n, S, W)
+        v = k if v_pool is None else \
+            v_pool[ids[lo:hi]].reshape(n, span, -1)
+        visible = jnp.arange(span)[None, :] < seen[lo:hi, None]
+        s = jnp.einsum("thl,tsl->ths", q[slot[lo:hi]], k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        # the where AFTER the matmul launders NaN scores a non-finite
+        # masked KEY row would produce
+        s = jnp.where(visible[:, None, :], s, _NEG_INF)
+        m = jnp.max(s, axis=-1)                             # (n, Hq)
+        p = jnp.exp(s - m[..., None])
+        # 0.0 * NaN = NaN: value rows beyond the clock are zeroed
+        # exactly (block_attention's `valid` hygiene)
+        v = jnp.where(visible[:, :, None], v, jnp.zeros((), v.dtype))
+        o = jnp.einsum("ths,tsl->thl", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return m, jnp.sum(p, axis=-1), keep(o)
+
+    def read(n):
+        def branch():
+            # at most half of all chunks in one pass: nothing in the
+            # program is as large as the whole gathered table
+            passes = [items(lo, min(lo + per_pass, n))
+                      for lo in range(0, n, per_pass)]
+            m, l, o = (jnp.concatenate(x) for x in zip(*passes))
+            # a slot's items folded in chunk order; entries that are
+            # not its own weigh 0 and are zeroed (another slot's NaN)
+            ms = jnp.where(mine[..., None], m[at], _NEG_INF)  # (B, C, Hq)
+            w = jnp.exp(ms - jnp.max(ms, axis=1, keepdims=True))
+            den = jnp.sum(jnp.where(mine[..., None], w * l[at], 0.0),
+                          axis=1)
+            num = jnp.sum(jnp.where(mine[..., None, None],
+                                    w[..., None] * o[at], 0.0), axis=1)
+            return num / jnp.where(den > 0, den, 1.0)[..., None]
+        return branch
+
+    return lax.switch(_read_size_index(ends[-1], sizes),
+                      [read(n) for n in sizes])
+
+
 def paged_attention_rows(q: jax.Array, k_pool: jax.Array,
                          v_pool: jax.Array, table: jax.Array,
                          pos: jax.Array,
@@ -387,10 +568,11 @@ def paged_attention_rows(q: jax.Array, k_pool: jax.Array,
     lanes [h*D, (h+1)*D) and zeros elsewhere, so `qbd . row` is head
     h's score, and head h's output is its own D lanes of
     `probs[b, h] @ v_rows` (the diagonal of (B, H, H, D)). Same
-    mathematics, extent, mask (-1e30 AFTER the score contraction),
-    full-extent float32 softmax and zeroed value rows beyond the clock
-    as `block_attention`; H times the multiply-adds, on a matrix unit
-    that is otherwise idle.
+    mathematics, mask (-1e30 AFTER the score contraction), float32
+    softmax and zeroed value rows beyond the clock as
+    `block_attention`; H times the multiply-adds, on a matrix unit
+    that is otherwise idle. The extent is each slot's own live chunks
+    (`_ragged_attention`, since PR 32), not the table's.
 
     Precision: the two contractions are matmuls and run at the
     backend's default matmul precision like every other matmul of the
@@ -407,26 +589,11 @@ def paged_attention_rows(q: jax.Array, k_pool: jax.Array,
     b, h, _, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
-    k = gather_block_rows(k_pool, table)            # (B, S, H*D)
-    v = gather_block_rows(v_pool, table)
-    visible = jnp.arange(k.shape[1])[None, :] <= pos[:, None]  # (B, S)
     diag = jnp.eye(h, dtype=bool)[None, :, :, None]
     qbd = jnp.where(diag, q[:, :, 0, None, :], 0.0) \
-        .reshape(b, h, h * d).astype(k.dtype)
-    s = jnp.einsum("bhl,bsl->bhs", qbd, k,
-                   preferred_element_type=jnp.float32) * sm_scale
-    # the where AFTER the matmul launders NaN scores a non-finite
-    # masked KEY row would produce
-    s = jnp.where(visible[:, None, :], s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    probs = p / jnp.sum(p, axis=-1, keepdims=True)
-    # 0.0 * NaN = NaN: value rows beyond the clock are zeroed exactly
-    # (block_attention's `valid` hygiene)
-    v = jnp.where(visible[:, :, None], v, jnp.zeros((), v.dtype))
-    o = jnp.einsum("bhs,bsl->bhl", probs.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32)
-    out = jnp.einsum("bhhd->bhd", o.reshape(b, h, h, d))
+        .reshape(b, h, h * d).astype(k_pool.dtype)
+    out = _ragged_attention(qbd, k_pool, v_pool, table, pos, sm_scale,
+                            lanes=None)                     # (B, H, D)
     return out[:, :, None, :].astype(q.dtype)
 
 
@@ -436,7 +603,15 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """One query row per sequence against the paged pool: q
     (B, H, 1, D), pools (N, bs, H*D), table (B, nb), pos (B,) — the
     row clock, exactly as cached_attention. Gathers each row's blocks
-    and attends positions <= pos over the FULL table extent (nb*bs).
+    and attends positions <= pos: over the full table extent in the
+    head-split form, over the row's own live chunks in the rows form.
+    A row that is NOT SEATED (its first table entry is the scratch
+    block 0, as the engine leaves an empty seat; a seated slot's first
+    entry never is) gets the form's answer, not one answer: the rows
+    form reads nothing for it and returns zeros, the head-split form
+    attends the scratch block's rows up to `pos` like any other
+    block's (garbage, non-finite if the scratch rows are). The engine
+    emits for neither.
     Returns (B, H, 1, D). One algorithm in two operand layouts, chosen
     by `paged_attention_form` from the shape and nothing else: the
     head-split form is the dense cached_attention bit for bit when the
@@ -467,21 +642,13 @@ def latent_paged_attention(q_lat: jax.Array, q_rope: jax.Array,
     through W_UV: the same mathematics as expanding every row to
     per-head keys and values, without ever holding them. pool
     (N, bs, W >= rank + rope, the rest of a row zeros), table (B, nb),
-    pos (B,) as paged_attention: full table extent, positions <= pos
-    visible, invisible rows zeroed before the weighted sum (the
-    0 * NaN hygiene of block_attention)."""
-    rows = gather_block_rows(pool, table)           # (B, S, W)
+    pos (B,) as paged_attention: positions <= pos visible, invisible
+    rows zeroed before the weighted sum (the 0 * NaN hygiene of
+    block_attention). The same contraction as `paged_attention_rows`
+    with another query and another part of the product kept, so the
+    same core: each slot's own live chunks (`_ragged_attention`)."""
     q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], axis=-1)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1]))
-                ).astype(rows.dtype)
-    s = jnp.einsum("bhc,bsc->bhs", q, rows,
-                   preferred_element_type=jnp.float32) * sm_scale
-    visible = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
-    s = jnp.where(visible[:, None, :], s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    probs = p / jnp.sum(p, axis=-1, keepdims=True)
-    rows = jnp.where(visible[:, :, None], rows, jnp.zeros((), rows.dtype))
-    out = jnp.einsum("bhs,bsc->bhc", probs.astype(rows.dtype), rows,
-                     preferred_element_type=jnp.float32)
-    return out[..., :rank]
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1]))
+                ).astype(pool.dtype)
+    return _ragged_attention(q, pool, None, table, pos, sm_scale,
+                             lanes=rank)

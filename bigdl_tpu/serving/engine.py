@@ -155,6 +155,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import obs
+from bigdl_tpu.ops.kv_cache import attended_blocks
 from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
@@ -923,6 +924,10 @@ class InferenceEngine:
             # serving-layout provenance (ISSUE 17): which attention
             # form decodes and which numerics family tokens carry
             "attn_form": self.attn_form,
+            # the share of the block table a decode step reads at the
+            # slots' current clocks
+            "attended_share": round(
+                self._attended_blocks() / self._table.size, 4),
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
             "model_tag": self.model_tag,
@@ -2168,6 +2173,15 @@ class InferenceEngine:
                 span.set(attn_impl="xla", attn_form=self.attn_form,
                          **log)
 
+    def _attended_blocks(self) -> int:
+        """Blocks of the table a decode step's read gathers at the
+        slots' clocks: the rows form reads each slot's live chunks
+        (ops/kv_cache.attended_blocks: the program's own roundings),
+        the head-split form all of it."""
+        if self.attn_form != "rows":
+            return self._table.size
+        return attended_blocks(self._pos, self._table, self.block_size)
+
     def _round(self) -> List[GenerationResult]:
         self._admit()
         done = self._ensure_blocks()
@@ -2193,12 +2207,17 @@ class InferenceEngine:
                 with self._span("decode_step") as span_d:
                     if span_d.id is not None:
                         # cache rows the step's attention has to read:
-                        # each seated slot's clock, and the row it writes
+                        # each seated slot's clock, and the row it
+                        # writes; and the blocks its read gathers, of
+                        # the table's
                         span_d.set(step=stepno, active=n_active,
                                    cached_tokens=int(sum(
                                        self._pos[i] + 1 for i, r
                                        in enumerate(self._req)
-                                       if r is not None)))
+                                       if r is not None)),
+                                   attended_blocks=int(
+                                       self._attended_blocks()),
+                                   table_blocks=self._table.size)
                     nxt, finite = self._dispatch_and_fetch(poison,
                                                            slow_s)
                     self._report_aux(span_d)

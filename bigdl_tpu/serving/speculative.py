@@ -16,8 +16,9 @@ Exactness construction (the repo's bit-identity discipline)
 The verify call is the target's own paged decode executable with the
 k+1 chain positions riding the BATCH axis: row (slot, j) carries
 token_j at position pos+j through the slot's own block table. Every
-op in `decode_step_paged` is per-row (LN / gemm rows / full-table-
-extent `paged_attention` with mask <= pos+j), each layer WRITES all
+op in `decode_step_paged` is per-row (LN / gemm rows /
+`paged_attention` with mask <= pos+j over an extent that hangs on the
+row's own clock alone, ops/kv_cache.py), each layer WRITES all
 rows' k/v before any row's attention reads, and per-row bits are
 independent of the batch extent on this backend — verified bitwise at
 both the tiny and the 43M shape: a verify row's logits are EXACTLY the

@@ -181,6 +181,85 @@ def test_absorbed_decode_attention_equals_the_naive_form():
                                   np.asarray(o_lat[0]))
 
 
+def _latent_case(pos, nb=20, block=4, rank=16, rope=4, width=32, heads=3,
+                 seed=0, beyond=None, unowned=0.0):
+    """Dense latent rows (b, nb*block, rank+rope) and the same laid into
+    a `width`-lane pool through shuffled chains; the table points at the
+    scratch block beyond a slot's last live block, and a clock of -1 is
+    a row that is not seated (table all scratch, handed clock 0).
+    Returns (latent_paged_attention's arguments, the float32 NumPy
+    reference (b, heads, rank): zeros for a row that is not seated)."""
+    rng = np.random.RandomState(seed)
+    pos = np.asarray(pos)
+    b = len(pos)
+    q_lat = rng.randn(b, heads, rank).astype(np.float32)
+    q_rope = rng.randn(b, heads, rope).astype(np.float32)
+    dense = rng.randn(b, nb * block, rank + rope).astype(np.float32)
+    scale = np.float32((8 + rope) ** -0.5)
+    want = np.zeros((b, heads, rank), np.float32)
+    q = np.concatenate([q_lat, q_rope], -1)
+    for slot in np.flatnonzero(pos >= 0):
+        rows = dense[slot, :pos[slot] + 1]
+        for head in range(heads):
+            sc = (rows @ q[slot, head]) * scale
+            p = np.exp(sc - sc.max())
+            want[slot, head] = (p / p.sum()) @ rows[:, :rank]
+    if beyond is not None:
+        for slot in range(b):
+            dense[slot, max(pos[slot], 0) + 1:] = beyond
+    pool = np.full((1 + b * nb, block, width), unowned, np.float32)
+    table = rng.permutation(np.arange(1, 1 + b * nb)).reshape(b, nb)
+    for slot in range(b):
+        live = pos[slot] // block + 1 if pos[slot] >= 0 else 0
+        for j in range(live):
+            pool[table[slot, j], :, :rank + rope] = \
+                dense[slot, j * block:(j + 1) * block]
+            pool[table[slot, j], :, rank + rope:] = 0.0
+        table[slot, live:] = 0
+    args = (jnp.asarray(q_lat), jnp.asarray(q_rope), jnp.asarray(pool),
+            jnp.asarray(table, jnp.int32),
+            jnp.asarray(np.maximum(pos, 0), jnp.int32), rank, scale)
+    return args, want
+
+
+# a table of 20 blocks of 4 rows is read in chunks of 3 blocks = 12 rows
+@pytest.mark.parametrize("pos", [
+    [0, 11, 12, 23, 24, 79],            # chunk edges, a full table
+    [5, -1, 40, -1, -1, 79],            # unseated rows between seated ones
+    [-1, -1, -1, -1, -1, 7],
+    [35, 2, 70, 13, 47, 60],
+], ids=["chunk-edges", "unseated-between", "one-seated", "mixed"])
+def test_the_latent_read_is_ragged_and_equals_a_plain_reference(pos):
+    """`latent_paged_attention` reads each slot's own live chunks (the
+    core it shares with `paged_attention_rows`): against float32 NumPy
+    attention over each slot's rows [0, pos]; NaN in the scratch block,
+    in every block no visible row lives in and after the clock inside
+    the last live chunk changes no bit."""
+    args, want = _latent_case(pos)
+    got = np.asarray(latent_paged_attention(*args))
+    assert got.shape == want.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    assert not got[np.asarray(pos) < 0].any()
+    dirty, _ = _latent_case(pos, beyond=np.nan, unowned=np.nan)
+    assert np.isnan(np.asarray(dirty[2][0])).all()
+    np.testing.assert_array_equal(
+        np.asarray(latent_paged_attention(*dirty)), got)
+
+
+def test_a_latent_slots_result_does_not_hang_on_the_other_slots_clocks():
+    results = []
+    for others in (-1, 13, 30, 79):     # reads of 3, then of all 42 chunks
+        pos = [others] * 6
+        pos[2] = 30
+        args, want = _latent_case(pos)
+        got = np.asarray(latent_paged_attention(*args))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        results.append(got[2])
+    for other in results[1:]:
+        np.testing.assert_array_equal(other, results[0])
+
+
 # ------------------------------------------------- the model as a config
 
 def test_the_model_is_a_list_of_layer_kinds():

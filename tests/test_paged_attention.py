@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.ops.kv_cache import (init_block_pool, paged_attention,
-                                    paged_attention_form)
+from bigdl_tpu.ops.kv_cache import (attended_blocks, init_block_pool,
+                                    paged_attention, paged_attention_form,
+                                    ragged_read_sizes)
 
 # one (b, h, nb, bs, d) per form for the tests that take `form`
 SHAPE_OF = {"heads": (3, 2, 4, 4, 8), "rows": (3, 2, 4, 4, 64)}
@@ -26,13 +27,17 @@ FORMS = sorted(SHAPE_OF)
 
 
 def _case(b, h, nb, bs, d, seed=0, pos=None, dtype=jnp.float32,
-          unowned=None, tails_to_scratch=False):
+          unowned=None, tails_to_scratch=False, beyond=None):
     """Dense per-slot keys and values (b, nb*bs, h*d) and the same rows
     laid into a pool through shuffled disjoint chains. `unowned` fills
     every block no visible row lives in (block 0 always among them);
     `tails_to_scratch` points the table beyond a slot's clock at block
-    0, as the engine's table rows do. Returns (paged_attention's
-    arguments, the dense q, k, v and clocks the reference takes)."""
+    0, as the engine's table rows do; `beyond` fills the rows after a
+    slot's clock, those of its last live block too. A clock of -1 is a
+    row that is NOT SEATED: its table is all scratch, as the engine's
+    free slots are (it is handed clock 0), and the reference gives it
+    zeros. Returns (paged_attention's arguments, the dense q, k, v and
+    clocks the reference takes)."""
     rng = np.random.RandomState(seed)
     n = b * nb + 1
     dense_k = rng.randn(b, nb * bs, h * d).astype(np.float32)
@@ -42,6 +47,10 @@ def _case(b, h, nb, bs, d, seed=0, pos=None, dtype=jnp.float32,
         pos = rng.randint(0, nb * bs, size=b)
     pos = np.asarray(pos, np.int32)
     table = rng.permutation(np.arange(1, n)).reshape(b, nb).astype(np.int32)
+    if beyond is not None:
+        for slot in range(b):
+            dense_k[slot, pos[slot] + 1:] = beyond
+            dense_v[slot, pos[slot] + 1:] = beyond
     if dtype != jnp.float32:        # the reference sees what the pool holds
         dense_k, dense_v, q = (
             np.asarray(jnp.asarray(a, dtype).astype(jnp.float32))
@@ -56,8 +65,11 @@ def _case(b, h, nb, bs, d, seed=0, pos=None, dtype=jnp.float32,
             k_pool[table[slot, j]] = dense_k[slot, j * bs:(j + 1) * bs]
             v_pool[table[slot, j]] = dense_v[slot, j * bs:(j + 1) * bs]
         table[slot, live:] = 0
+        if pos[slot] < 0:
+            table[slot] = 0
     args = (jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
-            jnp.asarray(v_pool, dtype), jnp.asarray(table), jnp.asarray(pos))
+            jnp.asarray(v_pool, dtype), jnp.asarray(table),
+            jnp.asarray(np.maximum(pos, 0)))
     return args, (q, dense_k, dense_v, pos)
 
 
@@ -69,6 +81,8 @@ def _reference(q, dense_k, dense_v, pos, sm_scale=None):
     out = np.zeros((b, h, 1, d), np.float32)
     for slot in range(b):
         seen = int(pos[slot]) + 1
+        if not seen:                    # not seated: zeros
+            continue
         for head in range(h):
             lanes = slice(head * d, (head + 1) * d)
             k = dense_k[slot, :seen, lanes]
@@ -162,3 +176,125 @@ def test_rejects_a_query_of_more_than_one_row():
     (q, *rest), _ = _case(2, 2, 4, 4, 8)
     with pytest.raises(ValueError, match="one row"):
         paged_attention(jnp.concatenate([q, q], axis=2), *rest)
+
+
+# ------------------------------------------- the ragged read (ISSUE 32)
+# The rows form reads each slot's own live chunks. A table of 20 blocks
+# of 4 rows is read in chunks of 3 blocks = 12 rows (7 a slot, the last
+# one a block short of whole), six slots: 42 chunks, compiled for 3 of
+# them (a batch that is mostly empty seats) or all 42.
+RAGGED = (6, 2, 20, 4, 64)
+_FULL = RAGGED[2] * RAGGED[3] - 1
+
+
+def test_the_ragged_shape_reads_in_chunks_of_three_blocks():
+    assert paged_attention_form(RAGGED[1], RAGGED[4]) == "rows"
+    assert ragged_read_sizes(RAGGED[0], RAGGED[2]) == (
+        3, (3, 42))
+
+
+@pytest.mark.parametrize("pos", [
+    [0, 11, 12, 23, 24, _FULL],         # chunk edges: last row, first row
+    [_FULL] * 6,                        # a full table: the largest read
+    [0, 0, 0, 0, 0, 0],                 # the first row of each
+    [5, -1, 40, -1, -1, _FULL],         # unseated rows between seated ones
+    [-1, -1, -1, -1, -1, 7],            # one seated slot, the last
+    [-1] * 6,                           # nobody seated: zeros, finite
+    [35, 2, 70, 13, 47, 60],
+], ids=["chunk-edges", "full-table", "clock-zero", "unseated-between",
+        "one-seated", "none-seated", "mixed"])
+def test_ragged_read_equals_the_plain_reference(pos):
+    args, dense = _case(*RAGGED, pos=pos, tails_to_scratch=True)
+    got = np.asarray(paged_attention(*args))
+    assert np.isfinite(got).all()
+    _close(got, _reference(*dense))
+    unseated = np.asarray(pos) < 0
+    assert not got[unseated].any()
+
+
+@pytest.mark.parametrize("pos", [
+    [0, 11, 12, 23, 24, _FULL - 1],
+    [5, -1, 40, -1, -1, 30],
+    [35, 2, 70, 13, 47, 60],
+], ids=["chunk-edges", "unseated-between", "mixed"])
+def test_nothing_beyond_a_clock_is_read_not_in_its_last_live_chunk_either(
+        pos):
+    """NaN in the scratch block, in every block that holds no visible
+    row, in the table's tail (it points at the scratch block) and in
+    the rows after the clock INSIDE the last live chunk: the result is
+    the clean pool's bit for bit, and the reference's."""
+    clean, dense = _case(*RAGGED, pos=pos, tails_to_scratch=True)
+    dirty, _ = _case(*RAGGED, pos=pos, tails_to_scratch=True,
+                     unowned=np.nan, beyond=np.nan)
+    assert np.isnan(np.asarray(dirty[1][0])).all()
+    want = np.asarray(paged_attention(*clean))
+    got = np.asarray(paged_attention(*dirty))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    _close(got, _reference(*dense))
+
+
+@pytest.mark.parametrize("first", [0, 9, 21, _FULL - 3])
+def test_a_verify_shaped_call_equals_its_rows_called_one_at_a_time(first):
+    """The speculative verify's shape: k + 1 rows of ONE slot at
+    consecutive clocks, all pointing at that slot's table (9..12 and
+    21..24 cross a chunk's edge). Each row's result is bitwise what a
+    call of that row alone gives: its chunks hang on its own clock."""
+    (q, k_pool, v_pool, table, _), _ = _case(*RAGGED)
+    rows = 4
+    clocks = jnp.arange(first, first + rows, dtype=jnp.int32)
+    shared = jnp.broadcast_to(table[2], (rows, table.shape[1]))
+    together = np.asarray(paged_attention(q[:rows], k_pool, v_pool, shared,
+                                          clocks))
+    for j in range(rows):
+        alone = np.asarray(paged_attention(
+            q[j:j + 1], k_pool, v_pool, shared[:1], clocks[j:j + 1]))
+        np.testing.assert_array_equal(together[j:j + 1], alone)
+
+
+def test_a_slots_result_does_not_hang_on_the_other_slots_clocks():
+    """Slot 2 at clock 30 (3 chunks) beside empty, short, long and full
+    neighbours: alone it takes the read compiled for 3 chunks, beside
+    any of the others the one for all 42, and slot 2's result is the
+    same bits in all of them."""
+    results = []
+    for others in (-1, 13, 30, _FULL):  # 3, 13, 18, 38 live chunks
+        pos = [others] * RAGGED[0]
+        pos[2] = 30
+        args, dense = _case(*RAGGED, pos=pos, tails_to_scratch=True)
+        got = np.asarray(paged_attention(*args))
+        _close(got, _reference(*dense))
+        results.append(got[2])
+    for other in results[1:]:
+        np.testing.assert_array_equal(other, results[0])
+
+
+@pytest.mark.parametrize("pos,chunks,read,blocks", [
+    # blocks 1, 3, 4, 6, 7, 20 -> chunks 1, 1, 2, 2, 3, 7 = 16: all 42,
+    # whose 126 blocks hold 6 of padding (a seventh chunk is 2 blocks)
+    ([0, 11, 12, 23, 24, _FULL], 16, 42, 120),
+    # two seated: blocks 2 and 4 -> chunks 1 + 2 = 3, the small read
+    ([5, -1, 13, -1, -1, -1], 3, 3, 9),
+    # two seated: blocks 2 and 11 -> chunks 1 + 4 = 5, read as all 42
+    ([5, -1, 40, -1, -1, -1], 5, 42, 120),
+    # blocks 9, 1, 18, 4, 12, 16 -> chunks 3, 1, 6, 2, 4, 6 = 22, read as 42
+    ([35, 2, 70, 13, 47, 60], 22, 42, 120),
+    # one seated slot in its first block: 1 chunk, read as 3
+    ([-1, -1, 2, -1, -1, -1], 1, 3, 9),
+], ids=["chunk-edges", "two-seated-short", "two-seated", "mixed",
+        "one-seated"])
+def test_attended_blocks_is_the_count_made_by_hand(pos, chunks, read,
+                                                   blocks):
+    """What the engine hangs on its `decode_step` span: live chunks
+    (a slot's blocks rounded up to chunks of 3), rounded up to the read
+    compiled for them, in blocks, and never more than the table's 120
+    (its share cannot read above 1)."""
+    (_, _, _, table, clocks), _ = _case(*RAGGED, pos=pos,
+                                        tails_to_scratch=True)
+    b, _, nb, bs, _ = RAGGED
+    chunk_blocks, sizes = ragged_read_sizes(b, nb)
+    seated = [p for p in pos if p >= 0]
+    assert sum(-(-(p // bs + 1) // chunk_blocks) for p in seated) == chunks
+    assert read in sizes
+    assert attended_blocks(np.asarray(clocks), np.asarray(table), bs) \
+        == min(read * chunk_blocks, b * nb) == blocks

@@ -10,7 +10,9 @@ leaf on the way in or out, and every leaf is updated in place. A compile
 that passes is not a chip run. The decode program attends the gathered
 rows as they are stored (ISSUE 29): nothing cache-sized in it has a head
 for its minor dimension, and one layer's attention holds less in
-temporaries than the head-split form compiled beside it.
+temporaries than the head-split form compiled beside it. It reads each
+slot's live chunks only (ISSUE 32): nothing in it spans the full
+gathered table, and no second copy of a pool leaf exists.
 
 libtpu is touched only inside the `topo` fixture (one process at a time
 may load it; a module that touches it while being imported breaks the
@@ -187,22 +189,61 @@ def test_every_pool_leaf_is_donated_in_place(programs, program):
         f"input_output_alias ({sorted(aliased)})")
 
 
+def _results(text):
+    """(dtype, dims, opcode) of every instruction of the module: the
+    entry computation, the fusions' bodies and the conditionals'
+    branches alike."""
+    return [(m[1], tuple(int(d) for d in m[2].split(",")), m[3])
+            for m in re.finditer(
+                r"= (\w+)\[([\d,]+)\](?:\{[^}]*\})? ([\w-]+)\(", text)]
+
+
 def test_decode_never_makes_the_head_the_minor_dimension(programs):
     """gpt2-medium's 16 x 64 lanes take the rows form: no instruction
-    of the decode program, inside a fusion or out, has a result over
-    the gathered extent (64 slots x 1,024 positions x 1,024 lanes)
-    whose minor dimension is one head's 64 (`f32[64,1024,16,64]`, which
-    the device pads to 128 lanes: the head-split form's two reshapes a
-    layer)."""
+    of the decode program, inside a fusion or out, has a cache-sized
+    result (a sixteenth of the gathered table, the smallest read, or
+    more) whose minor dimension is one head's 64 (`f32[64,1024,16,64]`,
+    which the device pads to 128 lanes: the head-split form's two
+    reshapes a layer)."""
     assert paged_attention_form(HEADS, DIM // HEADS) == "rows"
-    gathered = SLOTS * MAX_LEN * DIM
-    shapes = {tuple(int(d) for d in dims.split(","))
-              for dims in re.findall(r"= \w+\[([\d,]+)\]",
-                                     programs["decode_text"])}
-    assert any(int(np.prod(s)) == gathered for s in shapes)  # it gathers
+    smallest = SLOTS * MAX_LEN * DIM // 16
+    shapes = {dims for _, dims, _ in _results(programs["decode_text"])}
+    assert any(s[-1] == DIM and int(np.prod(s)) >= smallest
+               for s in shapes)                     # it gathers rows
     split = sorted(s for s in shapes if s[-1] == DIM // HEADS
-                   and int(np.prod(s)) >= gathered)
+                   and int(np.prod(s)) >= smallest)
     assert not split, split
+
+
+def test_decode_reads_live_chunks_and_holds_no_second_pool(programs):
+    """The decode read is ragged (ISSUE 32): the live chunks of the
+    batch, at most half of all chunks in one pass. So (a) nothing in
+    the program, in a branch of the read's conditional or out, has a
+    result over the full gathered extent (64 slots x 1,024 rows of
+    1,024 lanes); (b) nothing is as large as a pool leaf but the leaf
+    itself, float32 as it is stored, handed on or scattered into in
+    place: no bfloat16 copy of a pool, which is what the compiler makes
+    when the chunks are a loop (it rounds the WHOLE pool for the dot
+    and hoists that out); (c) the program has no loop to carry one."""
+    text = programs["decode_text"]
+    leaf = programs["leaf"]
+    n, gathered = int(np.prod(leaf)), SLOTS * MAX_LEN * DIM
+    results = _results(text)
+    over = sorted({(d, dims, op) for d, dims, op in results
+                   if int(np.prod(dims)) >= gathered})
+    assert not over, over
+    handed_on = {"parameter", "get-tuple-element", "bitcast", "tuple"}
+    second = sorted({(d, dims, op) for d, dims, op in results
+                     if int(np.prod(dims)) == n
+                     and not (d == "f32" and dims == leaf
+                              and op in handed_on | {"scatter", "fusion"})})
+    assert not second, second
+    written = [op for d, dims, op in results
+               if dims == leaf and op == "scatter"]
+    assert len(written) == 2 * LAYERS, len(written)
+    assert " while(" not in text
+    # the read is one conditional a layer (the sampler has its own)
+    assert LAYERS <= text.count(" conditional(") <= LAYERS + 2
 
 
 def test_rows_form_holds_less_than_the_head_split_form(topo, no_cache):

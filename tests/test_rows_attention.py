@@ -163,6 +163,15 @@ def test_engine_at_tile_widths_serves_warm_equal_cold_in_the_rows_form(
     warm, stranger = eng.run([Request(**A), Request(**S)])
     assert eng.stats["prefix_hits"] == 1
     assert warm.tokens == cold.tokens
+    # how far the ragged read engaged (ISSUE 32): every `decode_step`
+    # span says what its read gathered of the 2 x 16 table, in chunks of
+    # 2 blocks: reads of 1 chunk or of all 16. One slot of 13 + 6
+    # tokens reaches into 3 chunks, two slots into 5 at most
+    steps = [e["args"] for e in obs.get_tracer().events("decode_step")
+             if e["ph"] == "X"]
+    assert steps and {a["table_blocks"] for a in steps} == {32}
+    assert {a["attended_blocks"] for a in steps} <= {2, 32}
+    assert eng.health()["attended_share"] == 2 / 32    # nobody seated
     assert stranger.tokens == engine().run([Request(**S)])[0].tokens
     rounds = [e for e in obs.get_tracer().events("round") if e["ph"] == "X"]
     assert rounds and {r["args"]["attn_form"] for r in rounds} == {"rows"}
@@ -170,4 +179,6 @@ def test_engine_at_tile_widths_serves_warm_equal_cold_in_the_rows_form(
     tiny = build_lm(vocab_size=61, dim=32, num_heads=2, num_layers=1,
                     max_len=64)
     tiny.build(jax.random.PRNGKey(0))
-    assert InferenceEngine(tiny, slots=2).health()["attn_form"] == "heads"
+    heads = InferenceEngine(tiny, slots=2)
+    assert heads.health()["attn_form"] == "heads"
+    assert heads.health()["attended_share"] == 1.0      # the full table
