@@ -54,7 +54,8 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.kv_cache import (block_attention, gather_block_rows,
                                     init_row_pool, latent_paged_attention,
                                     write_decode_rows, write_prompt_rows)
-from bigdl_tpu.parallel.moe import DroplessMoE, gated_ffn
+from bigdl_tpu.parallel.moe import (DroplessMoE, expert_load_report,
+                                    gated_ffn)
 
 LAYER_KINDS = ("dense", "moe")
 
@@ -293,9 +294,12 @@ class LatentMoELM(Module):
     # ------------------------------------------------------ the paged trio
 
     def check_serving_options(self, weight_dtype="fp32", tp=False,
-                              speculative=False):
+                              speculative=False, prefix_cache=False,
+                              spill=False, role="both"):
         """What `InferenceEngine` and `SpeculativeEngine` ask a model
-        that has limits; raises for what this one does not do."""
+        that has limits; raises for what this one does not do (the
+        prefix cache, the spill tier and the handoff roles it does:
+        its rows live in table blocks like any other model's)."""
         for bad, what, why in (
                 (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
                  "serving/quant.py repacks TransformerLM's block leaves"),
@@ -413,17 +417,7 @@ class LatentMoELM(Module):
     # ------------------------------------------------- what the spans say
 
     def decode_aux_report(self, aux):
-        """From one step's fetched `aux` (MoE layers, E): the args the
-        engine hangs on its `decode_step` span, and the engine counters
-        to bump."""
-        import numpy as np
-
-        aux = np.asarray(aux)
-        mean = np.maximum(aux.mean(axis=1), 1e-9)
-        return ({"experts_touched": [int(n) for n in (aux > 0).sum(1)],
-                 "expert_load_max_over_mean": [
-                     float(v) for v in aux.max(axis=1) / mean]},
-                {"moe_tokens_routed": int(aux.sum())})
+        return expert_load_report(aux)
 
     def prefill_span_args(self, bucket: int) -> dict:
         return {"moe_assignments": bucket * self.cfg.num_experts_per_tok}

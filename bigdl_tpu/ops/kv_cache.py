@@ -84,6 +84,21 @@ float32 rounding). What that does to the pins:
   tokens). The latent form (`latent_paged_attention`) is held to the
   same tolerance against the naive form (tests/test_latent_moe.py).
 
+Two kinds of leaf (ISSUE 33): beside the table leaves above, whose
+rows grow with a sequence, a RING leaf (`init_ring_pool`) is what a
+layer that attends a sliding window keeps: each slot owns a fixed run
+of `window / block_size + 1` blocks and position p overwrites what
+stood there `ring_blocks` blocks of positions earlier. No table is
+uploaded for it and no allocator hands its blocks out: the program
+derives a slot's window table from its clock (`ring_window`) and reads
+it through the same ragged core, with a LOWER bound of visibility
+beside the clock's upper one. Grouped-query rows (G key-value heads
+side by side, Hq/G query heads reading each) go through that core too
+(`grouped_paged_attention`): the block-diagonal query is laid over the
+groups. What indexes a leaf by a table block id (spill, handoff,
+migration, the prefix tree) does not apply to a ring, and a model with
+one refuses those options (models/window_moe.py).
+
 Host spill tier (ISSUE 16): the bit-identity contract is what makes a
 host-RAM block tier possible at all — a tree block's content is
 immutable after its prefill (COW discipline) and position-invariant in
@@ -443,18 +458,25 @@ def attended_blocks(pos, table, block_size: int) -> int:
     return min(read, table.size)
 
 
-@functools.partial(jax.jit, static_argnames=("lanes",))
+@functools.partial(jax.jit, static_argnames=("lanes", "groups"))
 def _ragged_attention(q: jax.Array, k_pool: jax.Array,
                       v_pool: Optional[jax.Array], table: jax.Array,
-                      pos: jax.Array, sm_scale, lanes: Optional[int]
-                      ) -> jax.Array:
+                      pos: jax.Array, sm_scale, lanes: Optional[int],
+                      groups: Optional[int] = None,
+                      lo: Optional[jax.Array] = None) -> jax.Array:
     """The decode read's one core: a query matrix q (B, Hq, W) against
     each slot's OWN live rows of the pools (N, bs, W), float32 masked
     softmax, `probs @ rows`. Returns what the caller keeps of the
     product (B, Hq, W), in float32: its first `lanes` lanes, or with
-    `lanes=None` row h's own W/Hq lanes (B, Hq, W/Hq), the diagonal
-    that a block-diagonal query asks for. `v_pool=None`: a pool whose
-    rows are key and value at once (one gather).
+    `lanes=None` row h's own lanes, the diagonal that a block-diagonal
+    query asks for: its W/Hq lanes (B, Hq, W/Hq), or with `groups` =
+    G key-value heads shared by Hq/G query heads each (grouped-query
+    attention) the W/G lanes of head h's group `h // (Hq/G)`
+    (B, Hq, W/G). `v_pool=None`: a pool whose rows are key and value
+    at once (one gather). `lo` (B,), or None: the lower bound of
+    visibility, a slot's rows before `lo` are masked like those after
+    its clock (a window: `pos - lo + 1` rows visible). With `groups`
+    and `lo` None the program is what it was before they existed.
 
     Ragged per slot, in one program: the table is cut into chunks of
     `chunk_blocks` blocks, and the live chunks of the whole batch
@@ -483,6 +505,11 @@ def _ragged_attention(q: jax.Array, k_pool: jax.Array,
     def keep(o):                        # (T, Hq, W) -> what is kept
         if lanes is not None:
             return o[..., :lanes]
+        if groups is not None:          # head (g, r) keeps group g's lanes
+            return jnp.einsum(
+                "tgrgd->tgrd", o.reshape(-1, groups, hq // groups, groups,
+                                         width // groups)
+            ).reshape(-1, hq, width // groups)
         return jnp.einsum("thhd->thd",
                           o.reshape(-1, hq, hq, width // hq))
 
@@ -506,18 +533,22 @@ def _ragged_attention(q: jax.Array, k_pool: jax.Array,
                     0)                              # (T, chunk_blocks)
     # rows of the item at or before its slot's clock (> span: all)
     seen = jnp.where(live, pos[slot] + 1 - chunk * span, 0)
+    # rows of the item before its slot's lower bound (<= 0: none)
+    below = None if lo is None else lo[slot] - chunk * span
     mine = jnp.arange(per_slot)[None, :] < chunks[:, None]  # (B, C)
     at = jnp.where(mine, starts[:, None] + jnp.arange(per_slot), 0)
     per_pass = max(1, sizes[-1] // 2)               # items a pass
 
-    def items(lo, hi):
-        """Items [lo, hi): each one's (max, sum, kept product)."""
-        n = hi - lo
-        k = k_pool[ids[lo:hi]].reshape(n, span, -1)         # (n, S, W)
+    def items(first, last):
+        """Items [first, last): each one's (max, sum, kept product)."""
+        n = last - first
+        k = k_pool[ids[first:last]].reshape(n, span, -1)    # (n, S, W)
         v = k if v_pool is None else \
-            v_pool[ids[lo:hi]].reshape(n, span, -1)
-        visible = jnp.arange(span)[None, :] < seen[lo:hi, None]
-        s = jnp.einsum("thl,tsl->ths", q[slot[lo:hi]], k,
+            v_pool[ids[first:last]].reshape(n, span, -1)
+        visible = jnp.arange(span)[None, :] < seen[first:last, None]
+        if below is not None:
+            visible &= jnp.arange(span)[None, :] >= below[first:last, None]
+        s = jnp.einsum("thl,tsl->ths", q[slot[first:last]], k,
                        preferred_element_type=jnp.float32) * sm_scale
         # the where AFTER the matmul launders NaN scores a non-finite
         # masked KEY row would produce
@@ -535,8 +566,8 @@ def _ragged_attention(q: jax.Array, k_pool: jax.Array,
         def branch():
             # at most half of all chunks in one pass: nothing in the
             # program is as large as the whole gathered table
-            passes = [items(lo, min(lo + per_pass, n))
-                      for lo in range(0, n, per_pass)]
+            passes = [items(at0, min(at0 + per_pass, n))
+                      for at0 in range(0, n, per_pass)]
             m, l, o = (jnp.concatenate(x) for x in zip(*passes))
             # a slot's items folded in chunk order; entries that are
             # not its own weigh 0 and are zeroed (another slot's NaN)
@@ -652,3 +683,111 @@ def latent_paged_attention(q_lat: jax.Array, q_rope: jax.Array,
                 ).astype(pool.dtype)
     return _ragged_attention(q, pool, None, table, pos, sm_scale,
                              lanes=rank)
+
+
+def grouped_paged_attention(q: jax.Array, k_pool: jax.Array,
+                            v_pool: jax.Array, table: jax.Array,
+                            pos: jax.Array, kv_heads: int,
+                            sm_scale: float,
+                            lo: Optional[jax.Array] = None) -> jax.Array:
+    """One query row per sequence against pools of GROUPED-QUERY rows:
+    q (B, Hq, D), pools (N, bs, G*D) with G = `kv_heads` heads side by
+    side, query head h reads key-value head `h // (Hq/G)`. The rows
+    form at any head width (`paged_attention_rows` with a group wider
+    than one head): the query is laid out block-diagonally over the G
+    groups, head h's D numbers at its group's lanes, so its score is
+    one contraction with the row as the pool stores it, and its output
+    is its group's lanes of `probs @ v_rows`. The block-diagonal query
+    costs G times the multiply-adds, not Hq times. `lo` (B,) is the
+    lower bound of visibility, for a window layer (`ring_window`).
+    Returns (B, Hq, D) float32. Same core, mask, softmax and hygiene
+    as the other two reads (`_ragged_attention`)."""
+    b, hq, d = q.shape
+    of_group = (jnp.arange(hq)[:, None] // (hq // kv_heads)
+                == jnp.arange(kv_heads)[None, :])           # (Hq, G)
+    qbd = jnp.where(of_group[None, :, :, None], q[:, :, None, :], 0.0) \
+        .reshape(b, hq, kv_heads * d).astype(k_pool.dtype)
+    return _ragged_attention(qbd, k_pool, v_pool, table, pos, sm_scale,
+                             lanes=None, groups=kv_heads, lo=lo)
+
+
+# ---------------------------------------------------------------- rings
+
+def init_ring_pool(slots: int, ring_blocks: int, block_size: int,
+                   width: int, dtype=jnp.float32) -> jax.Array:
+    """One RING pool leaf, (1 + slots * ring_blocks, block_size, width),
+    zeros: what a layer that attends a window keeps. The same contract
+    as `init_row_pool` (blocks are axis 0, block 0 is scratch), and no
+    table and no allocator: slot s owns the blocks `1 + s * ring_blocks`
+    onwards for good, and the rows of position p live in its ring block
+    `(p // block_size) % ring_blocks`, over whatever was there
+    `ring_blocks` blocks of positions earlier. With `ring_blocks =
+    window / block_size + 1` the ring always holds the window of the
+    newest position whole, and a slot never holds more, however long
+    its context."""
+    return init_row_pool(1 + slots * ring_blocks, block_size, width, dtype)
+
+
+def ring_window(pos, seated, block_size: int, ring_blocks: int,
+                window: int):
+    """A ring leaf seen as what `_ragged_attention` reads: for each slot
+    (row b of the batch IS slot b) the block table of its window
+    (B, ring_blocks), oldest block first, the clock RELATIVE to that
+    table's first row, and the lower bound of visibility `lo` in the
+    same terms (`window` rows end at the clock). Entries past the
+    clock's block, and every entry of a slot that is not `seated`,
+    are the scratch block, as in an engine's table. NumPy on the host,
+    jax.numpy inside the program: the same arithmetic, so the engine's
+    `attended_rows` is what the program read."""
+    xp = np if isinstance(pos, np.ndarray) else jnp
+    cur = pos // block_size                          # the clock's block
+    first = xp.maximum(cur - (ring_blocks - 1), 0)   # oldest block held
+    blocks = first[:, None] + xp.arange(ring_blocks)[None, :]
+    base = 1 + xp.arange(pos.shape[0]) * ring_blocks
+    table = xp.where(seated[:, None] & (blocks <= cur[:, None]),
+                     base[:, None] + blocks % ring_blocks, 0)
+    rel = pos - first * block_size
+    return table.astype(xp.int32), rel, rel - (window - 1)
+
+
+def ring_write_blocks(pos, seated, block_size: int, ring_blocks: int):
+    """Where a decode step's rows go in a ring leaf, (B,): slot b's ring
+    block of position `pos[b]`; the scratch block for a slot that is
+    not seated."""
+    slot = jnp.arange(pos.shape[0])
+    return jnp.where(seated, 1 + slot * ring_blocks
+                     + (pos // block_size) % ring_blocks, 0)
+
+
+def ring_prompt_sources(length: int, block_size: int, ring_blocks: int
+                        ) -> np.ndarray:
+    """Which of a prompt's blocks each of a slot's ring blocks takes at
+    prefill (host, NumPy), (ring_blocks,): the block of positions
+    `[b * block_size, (b + 1) * block_size)` for the last `ring_blocks`
+    blocks b up to the prompt's last token's, at ring block
+    `b % ring_blocks`; -1 for a ring block that gets none and is
+    zeroed (a short prompt; and so no row of the slot's last occupant
+    outlives the prefill)."""
+    last = (length - 1) // block_size
+    src = np.full(ring_blocks, -1, np.int32)
+    held = np.arange(max(0, last - ring_blocks + 1), last + 1)
+    src[held % ring_blocks] = held
+    return src
+
+
+def write_prompt_ring(pool: jax.Array, rows: jax.Array, slot, sources
+                      ) -> jax.Array:
+    """Prefill's write into a ring leaf: ALL of slot `slot`'s ring
+    blocks at once, in place, ring block c from the prompt's block
+    `sources[c]` of `rows` (S, W), or zeros where `sources[c] < 0`
+    (`ring_prompt_sources`)."""
+    bs, w = pool.shape[1:]
+    ring_blocks = sources.shape[0]
+    rows = jnp.pad(rows.astype(pool.dtype), ((0, -rows.shape[0] % bs),
+                                             (0, 0)))
+    blocks = rows.reshape(-1, bs, w)
+    region = jnp.where((sources >= 0)[:, None, None],
+                       blocks[jnp.maximum(sources, 0)],
+                       jnp.zeros((), pool.dtype))
+    return lax.dynamic_update_slice(
+        pool, region, (1 + slot * ring_blocks, 0, 0))

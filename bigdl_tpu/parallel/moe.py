@@ -321,6 +321,22 @@ class DroplessMoE(Module):
         return y.reshape(x.shape).astype(x.dtype), variables["state"]
 
 
+def expert_load_report(aux):
+    """From one decode step's fetched `aux` (MoE layers, E), the tokens
+    each expert got (`DroplessMoE.forward`'s second result, stacked by
+    the model): the args the serving engine hangs on its `decode_step`
+    span, and the engine counters to bump. What a model's
+    `decode_aux_report` returns."""
+    import numpy as np
+
+    aux = np.asarray(aux)
+    mean = np.maximum(aux.mean(axis=1), 1e-9)
+    return ({"experts_touched": [int(n) for n in (aux > 0).sum(1)],
+             "expert_load_max_over_mean": [
+                 float(v) for v in aux.max(axis=1) / mean]},
+            {"moe_tokens_routed": int(aux.sum())})
+
+
 def gated_ffn(x, w_gate, w_up, w_down):
     """(silu(x W_g) * x W_u) W_d with float32 accumulation; the hidden
     activation goes back to x's dtype between the matmuls."""

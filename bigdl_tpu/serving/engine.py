@@ -155,7 +155,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import obs
-from bigdl_tpu.ops.kv_cache import attended_blocks
+from bigdl_tpu.ops.kv_cache import attended_blocks, ring_prompt_sources
 from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
@@ -454,8 +454,11 @@ class InferenceEngine:
         check = getattr(model, "check_serving_options", None)
         if check is not None:
             # a model that does not serve under every option says so
-            # here, before anything is built (models/latent_moe.py)
-            check(weight_dtype=weight_dtype, tp=tp_mesh is not None)
+            # here, before anything is built (models/latent_moe.py,
+            # models/window_moe.py)
+            check(weight_dtype=weight_dtype, tp=tp_mesh is not None,
+                  prefix_cache=bool(prefix_cache), spill=bool(spill),
+                  role=role)
         if tp_mesh is not None:
             # memoized: engines over the same (model, mesh, axis)
             # share one wrapper and therefore every jitted executable
@@ -589,8 +592,23 @@ class InferenceEngine:
             raise ValueError("admit_requeue_budget must be >= 1")
         self.admit_requeue_budget = admit_requeue_budget
         self._admit_fails: Dict[int, int] = {}
-        self.pool = model.init_block_pool(pool_blocks, block_size,
-                                          cache_dtype)
+        # the KIND of each entry of the model's pool tuple, asked of
+        # the model (`cache_kinds`; a model without the method has
+        # "table" entries only): "table" rows live in blocks the slot's
+        # table names, allocated, grown and released below; "ring" rows
+        # in a region of the leaf the slot owns for good
+        # (ops/kv_cache.init_ring_pool), `_ring_blocks` blocks of it,
+        # which nothing here allocates or releases. What indexes every
+        # leaf by a TABLE block id (spill, handoff, migration, the
+        # prefix tree) is for table-only models, and a model with a
+        # ring refuses it (`check_serving_options`, `import_handoff`)
+        kinds = getattr(model, "cache_kinds", None)
+        self._cache_kinds = kinds() if kinds is not None else None
+        self._ring_blocks = model.ring_blocks(block_size) \
+            if self._cache_kinds and "ring" in self._cache_kinds else 0
+        self.pool = model.init_block_pool(
+            pool_blocks, block_size, cache_dtype,
+            **({"slots": slots} if self._ring_blocks else {}))
         self._pool_mgr = BlockPool(pool_blocks, block_size)
         self._prefix = RadixPrefixCache(self._pool_mgr,
                                         host_blocks=self.host_blocks)
@@ -732,6 +750,17 @@ class InferenceEngine:
                 ).labels(engine=self._obs_name, tier=tier,
                          tp=self._obs_tp)
             for tier in ("device", "host")}
+        # rows of ONE layer of each cache kind that the seated slots
+        # hold: "full" in table blocks (they grow with the context),
+        # "window" in rings (never more than slots x ring rows)
+        self._m_rows_gauges = {
+            kind: reg.gauge(
+                "serving_kv_rows_held",
+                "cache rows one layer of a kind holds for the seated "
+                "slots (full: table blocks; window: ring blocks)",
+                labelnames=("engine", "kind")
+                ).labels(engine=self._obs_name, kind=kind)
+            for kind in ("window", "full")}
         self._m_tp_gauge = reg.gauge(
             "serving_tp_shards",
             "tensor-parallel shard count serving this engine",
@@ -928,6 +957,9 @@ class InferenceEngine:
             # slots' current clocks
             "attended_share": round(
                 self._attended_blocks() / self._table.size, 4),
+            # rows one layer of each cache kind holds for the seated
+            # slots (the serving_kv_rows_held gauge)
+            "kv_rows_held": self._kv_rows_held(),
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
             "model_tag": self.model_tag,
@@ -1342,7 +1374,25 @@ class InferenceEngine:
             self._update_pool_gauge()
         return [n.block for n in nodes]
 
+    def _kv_rows_held(self) -> Dict[str, int]:
+        """Rows ONE layer of each cache kind holds for the seated
+        slots: "full", the table's assigned blocks; "window", each
+        seated slot's ring blocks that its clock has reached (0 for a
+        model without rings): never more than slots x ring rows."""
+        seated = self._table[:, 0] != 0
+        ring = np.minimum(self._pos // self.block_size + 1,
+                          self._ring_blocks)[seated].sum()
+        return {"window": int(ring) * self.block_size,
+                "full": int(np.count_nonzero(self._table))
+                * self.block_size}
+
+    def _update_rows_gauge(self) -> None:
+        if obs.enabled():
+            for kind, rows in self._kv_rows_held().items():
+                self._m_rows_gauges[kind].set(rows)
+
     def _update_pool_gauge(self) -> None:
+        self._update_rows_gauge()
         if obs.enabled():
             in_use = self._pool_mgr.capacity - self._pool_mgr.free_count
             self._m_pool_gauge.set(in_use)
@@ -1508,6 +1558,18 @@ class InferenceEngine:
             return False
         row = self._point_table_row(slot, hit, new)
         toks = pad_tokens(suffix, b)[None, :]          # (1, bucket)
+        # int32 on the host: jnp.asarray(list, dtype=) dispatches a
+        # jit(convert_element_type) program of its own, one more
+        # launch an admission
+        block_ids = jnp.asarray(np.asarray(new, np.int32))
+        if self._ring_blocks:
+            # a model with rings takes its destinations by cache kind:
+            # the fresh table blocks, and for the slot's rings the
+            # prompt's block that each ring block takes
+            block_ids = {"table": block_ids, "ring": {
+                "slot": np.int32(slot),
+                "sources": jnp.asarray(ring_prompt_sources(
+                    n, bs, self._ring_blocks))}}
         tracer = obs.get_tracer()
         t_admit = self._clock()
         if tracer.enabled:
@@ -1524,11 +1586,7 @@ class InferenceEngine:
                     category=UserWarning)
                 self.pool = _prefill_step(
                     self.model, self._params, self.pool,
-                    jnp.asarray(toks), np.int32(start),
-                    # int32 on the host: jnp.asarray(list, dtype=)
-                    # dispatches a jit(convert_element_type) program
-                    # of its own, one more launch an admission
-                    jnp.asarray(np.asarray(new, np.int32)),
+                    jnp.asarray(toks), np.int32(start), block_ids,
                     jnp.asarray(row[None, :]))
             if span.id is not None:
                 # THE one span that waits for the device, and only
@@ -1622,11 +1680,18 @@ class InferenceEngine:
         the belt to that suspenders, keeping the invariant local:
         nothing a poisoned request wrote survives its eviction (except
         inside a shared block, whose content is by construction the
-        same bits a healthy cold run computes)."""
+        same bits a healthy cold run computes). `blocks` are TABLE
+        blocks. A ring leaf has none and is left alone: its slot reads
+        nothing until it is seated again, and the next occupant's
+        prefill rewrites every block of the slot's region
+        (ops/kv_cache.write_prompt_ring)."""
         idx = jnp.asarray(blocks, jnp.int32)
-        self.pool = jax.tree_util.tree_map(
-            lambda leaf: leaf.at[idx].set(jnp.zeros((), leaf.dtype)),
-            self.pool)
+        kinds = self._cache_kinds or ("table",) * len(self.pool)
+        self.pool = tuple(
+            jax.tree_util.tree_map(
+                lambda leaf: leaf.at[idx].set(jnp.zeros((), leaf.dtype)),
+                layer) if kind == "table" else layer
+            for kind, layer in zip(kinds, self.pool))
         if hasattr(self.model, "place_pools"):
             # keep the tp head-axis placement through the eager scrub
             self.pool = self.model.place_pools(self.pool)
@@ -2043,6 +2108,10 @@ class InferenceEngine:
         tree, so a handed-off prompt seeds prefix reuse here too."""
         if self.role == "prefill":
             raise ValueError("import_handoff on a prefill-role engine")
+        if self._ring_blocks:
+            raise NotImplementedError(
+                "import_handoff into an engine whose model keeps ring "
+                "leaves: a package carries table blocks only")
         if self._degraded:
             raise EngineDegraded(
                 f"engine degraded ({self._degraded}); hand off to a "
@@ -2182,6 +2251,15 @@ class InferenceEngine:
             return self._table.size
         return attended_blocks(self._pos, self._table, self.block_size)
 
+    def _decode_read_report(self) -> dict:
+        """What the model adds to a recorded `decode_step` span about
+        the step's read of the cache (models/window_moe.py: the rows
+        visible and gathered, by cache kind)."""
+        report = getattr(self.model, "decode_read_report", None)
+        if report is None:
+            return {}
+        return report(self._pos, self._table, self.block_size)
+
     def _round(self) -> List[GenerationResult]:
         self._admit()
         done = self._ensure_blocks()
@@ -2217,7 +2295,8 @@ class InferenceEngine:
                                        if r is not None)),
                                    attended_blocks=int(
                                        self._attended_blocks()),
-                                   table_blocks=self._table.size)
+                                   table_blocks=self._table.size,
+                                   **self._decode_read_report())
                     nxt, finite = self._dispatch_and_fetch(poison,
                                                            slow_s)
                     self._report_aux(span_d)
@@ -2263,6 +2342,10 @@ class InferenceEngine:
                     continue
                 done.extend(self._emit_multi(i, [int(nxt[i])],
                                              [bool(finite[i])], now))
+        if self._ring_blocks:
+            # a ring fills by the step, with no allocation to hang the
+            # gauge on
+            self._update_rows_gauge()
         return done
 
     def run(self, requests: Optional[Sequence[Request]] = None

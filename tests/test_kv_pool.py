@@ -649,3 +649,119 @@ class TestSpillTier:
         with pytest.raises(ValueError, match="admit_requeue_budget"):
             InferenceEngine(m, slots=1, block_size=4, max_len=16,
                             admit_requeue_budget=0)
+
+
+class TestGroupedQueryAndRings:
+    """ISSUE 33: grouped-query rows through the decode read's core
+    (query head h over key-value head h // (Hq/G)) against the dense
+    `cached_attention` with the key-value heads repeated, and a ring
+    leaf read through the table of its window with the lower bound of
+    visibility."""
+
+    @staticmethod
+    def _dense(q, k, v, pos, lo=None):
+        """q (B, Hq, D), k/v (B, G, S, D): `cached_attention` with each
+        key-value head repeated for its group; with `lo`, over the
+        cache's rows from `lo` on alone."""
+        rep = q.shape[1] // k.shape[1]
+        out = []
+        for b in range(q.shape[0]):
+            first = 0 if lo is None else max(int(lo[b]), 0)
+            out.append(cached_attention(
+                q[b:b + 1, :, None, :],
+                jnp.repeat(k[b:b + 1, :, first:], rep, axis=1),
+                jnp.repeat(v[b:b + 1, :, first:], rep, axis=1),
+                pos[b:b + 1] - first)[0, :, 0])
+        return np.asarray(jnp.stack(out))
+
+    @pytest.mark.parametrize("hq,g,d", [(32, 4, 128), (4, 2, 8), (6, 6, 4)])
+    def test_grouped_rows_equal_cached_attention_with_repeated_heads(
+            self, hq, g, d):
+        from bigdl_tpu.ops.kv_cache import (grouped_paged_attention,
+                                            init_row_pool,
+                                            write_prompt_rows)
+
+        rng = np.random.RandomState(hq + d)
+        B, S, bs = 3, 48, 4
+        nb = S // bs
+        k = jnp.asarray(rng.randn(B, g, S, d), jnp.float32)
+        v = jnp.asarray(rng.randn(B, g, S, d), jnp.float32)
+        q = jnp.asarray(rng.randn(B, hq, d), jnp.float32)
+        pos = jnp.asarray([5, 31, 47], jnp.int32)
+        table = rng.permutation(np.arange(1, 1 + B * nb)).reshape(B, nb)
+        kp = vp = init_row_pool(1 + B * nb, bs, g * d)
+        for b in range(B):          # a token's G heads side by side
+            kp = write_prompt_rows(
+                kp, k[b].transpose(1, 0, 2).reshape(S, g * d),
+                jnp.asarray(table[b]))
+            vp = write_prompt_rows(
+                vp, v[b].transpose(1, 0, 2).reshape(S, g * d),
+                jnp.asarray(table[b]))
+        got = grouped_paged_attention(q, kp, vp, jnp.asarray(table), pos,
+                                      g, d ** -0.5)
+        assert got.shape == (B, hq, d) and got.dtype == jnp.float32
+        want = self._dense(q, k, v, pos)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+        # and with a lower bound: the rows from `lo` on alone
+        lo = jnp.asarray([-3, 20, 40], jnp.int32)
+        got = grouped_paged_attention(q, kp, vp, jnp.asarray(table), pos,
+                                      g, d ** -0.5, lo=lo)
+        want = self._dense(q, k, v, pos, lo)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+
+    @pytest.mark.parametrize("bad", [0.0, np.nan], ids=["clean", "nan"])
+    def test_a_ring_reads_the_last_window_of_a_long_history(self, bad):
+        """Three slots decode to different lengths, one past three wraps
+        of its ring, one not seated; each step's rows go where
+        `ring_write_blocks` says. The read through `ring_window` equals
+        dense attention over the last `window` positions of the slot's
+        whole history; the block of rows that the ring still holds and
+        the window has left behind changes nothing, non-finite or
+        not."""
+        from bigdl_tpu.ops.kv_cache import (grouped_paged_attention,
+                                            init_ring_pool, ring_window,
+                                            ring_write_blocks,
+                                            write_decode_rows)
+
+        rng = np.random.RandomState(7)
+        B, g, d, hq, bs, window = 3, 2, 8, 4, 4, 8
+        ring = window // bs + 1
+        S = 44
+        k = rng.randn(B, g, S, d).astype(np.float32)
+        v = rng.randn(B, g, S, d).astype(np.float32)
+        lengths = np.array([6, 44, 20])
+        seated = jnp.asarray([True, True, False])
+        kp = vp = init_ring_pool(B, ring, bs, g * d)
+        assert kp.shape == (1 + B * ring, bs, g * d)
+        for t in range(S):
+            pos = jnp.asarray(np.minimum(t, lengths - 1), jnp.int32)
+            ids = ring_write_blocks(pos, seated, bs, ring)
+            kp = write_decode_rows(
+                kp, jnp.asarray(k[np.arange(B), :, np.asarray(pos)]
+                                .reshape(B, -1)), ids, pos % bs)
+            vp = write_decode_rows(
+                vp, jnp.asarray(v[np.arange(B), :, np.asarray(pos)]
+                                .reshape(B, -1)), ids, pos % bs)
+        pos = jnp.asarray(lengths - 1, jnp.int32)
+        # slot 1 is at position 43: its ring holds the blocks of
+        # positions 32-43, its window sees 36-43; the block of 32-35 is
+        # ring block 8 % 3 of its region
+        out = 1 + 1 * ring + 8 % ring
+        assert np.asarray(kp[out]).any()
+        kp, vp = kp.at[out].set(bad), vp.at[out].set(bad)
+        q = jnp.asarray(rng.randn(B, hq, d), jnp.float32)
+        table, rel, lo = ring_window(pos, seated, bs, ring, window)
+        host = ring_window(np.asarray(pos), np.asarray(seated), bs, ring,
+                           window)
+        for a, b in zip((table, rel, lo), host):    # the same arithmetic
+            np.testing.assert_array_equal(np.asarray(a), b)
+        assert not np.asarray(table[2]).any()       # not seated: scratch
+        got = np.asarray(grouped_paged_attention(
+            q, kp, vp, table, rel, g, d ** -0.5, lo=lo))
+        want = self._dense(q, jnp.asarray(k), jnp.asarray(v), pos,
+                           np.asarray(pos) - (window - 1))
+        np.testing.assert_allclose(got[:2], want[:2], rtol=1e-5,
+                                   atol=1e-5 * np.abs(want[:2]).max())
+        assert not got[2].any()                     # reads nothing
