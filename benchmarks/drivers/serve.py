@@ -213,7 +213,8 @@ def run(ctx) -> dict:
     counters = {
         "requests_due_or_done": len(measured), "requests_timed": len(ok),
         "requests_unfinished": len(measured) - len(finished),
-        "tail_s": tail_s,
+        "requests_submitted": len(arrivals),
+        "queued_at_close": queued_at_close, "tail_s": tail_s,
         "tokens_in_window": tokens, "window_s": window_s,
         "rounds_in_window": len(window_steps),
         "batch_occupancy_pct": (100.0 * sum(s[2] for s in window_steps)

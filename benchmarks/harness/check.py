@@ -30,6 +30,15 @@ class Check:
     def correct(self) -> bool:
         return bool(self.rows) and all(r[3] for r in self.rows)
 
+    def report(self) -> dict:
+        """Every comparison under its name, the number beside its limit
+        (both None for a requirement): the result line's last key."""
+        def plain(x):       # inf and nan are no JSON
+            return x if x is None or math.isfinite(x) else repr(x)
+
+        return {name: {"value": plain(value), "limit": limit, "ok": ok}
+                for name, value, limit, ok in self.rows}
+
 
 DEAD_LEAF = 1e-3
 

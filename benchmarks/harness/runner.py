@@ -9,6 +9,7 @@ import importlib.util
 import json
 import os
 import shutil
+import sys
 import time
 
 from benchmarks.harness import device as dev
@@ -152,5 +153,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         device["busy_s"], device["window_s"] = ts["busy_s"], ts["window_s"]
         result["breakdown"] = {"device_ops": ts["device_ops"],
                                "idle_gaps": ts["idle_gaps"]}
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines of standard error
+    result["checks"] = ctx.check.report()
+    for name, row in result["checks"].items():
+        print(f"check {name}: {row['value']!r} limit {row['limit']!r} "
+              f"{'ok' if row['ok'] else 'NOT OK'}", file=sys.stderr)
     out(json.dumps(result))
     return result

@@ -344,6 +344,7 @@ def test_a_traced_run_reads_the_new_spans_and_both_readers_answer(
     assert 1.0 <= load <= 8.0           # 8 experts: at most all on one
     waste = result["metrics"]["attn_rows_read_over_visible"]["value"]
     assert 1.0 <= waste < 8.0           # whole chunks of blocks of 4
+    assert 0 < result["metrics"]["backlog_queue_left"]["value"] < 100
     steps = [e for e in obs.get_tracer().events("decode_step")]
     assert steps and all(
         len(e["args"]["experts_touched"]) == 4

@@ -299,6 +299,7 @@ def test_a_traced_run_reads_the_new_spans(tiny_root):
     assert "moe_decode_roofline" not in result["metrics"]
     load = result["metrics"]["moe_expert_load_max_over_mean"]["value"]
     assert 1.0 <= load <= 8.0           # 8 experts: at most all on one
+    assert 0 < result["metrics"]["backlog_queue_left"]["value"] < 100
     steps = [e["args"] for e in obs.get_tracer().events("decode_step")]
     assert steps and all(
         len(a["experts_touched"]) == 2 and a["cached_tokens"] > 0

@@ -50,7 +50,7 @@ def test_cells_were_added_as_files_only(tiny_root):
     ("tiny-train", False), ("tiny-train", True), ("tiny-chat", False),
     ("tiny-chat", True), ("tiny-backlog", False), ("tiny-backlog", True),
     ("tiny-dp4", False)])
-def test_rehearsal(tiny_root, workload, trace):
+def test_rehearsal(tiny_root, workload, trace, capfd):
     root, _ = tiny_root
     manifest = mf.load(root)
     cell = mf.cell_of(manifest, workload)
@@ -64,9 +64,19 @@ def test_rehearsal(tiny_root, workload, trace):
         sum(setup[p] for p in ("import_s", "backend_init_s", "build_s",
                                "compile_or_load_s", "warmup_s", "lead_s")))
     assert any(l.startswith("check ") and " <= limit " in l for l in lines)
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines of standard error
+    checks = result["checks"]
+    assert list(result)[-1] == "checks" and all(c["ok"] for c in checks.values())
+    assert any(c["limit"] is not None and c["value"] <= c["limit"]
+               for c in checks.values())
+    err = capfd.readouterr().err.strip().splitlines()[-len(checks):]
+    assert [l.split(":")[0] for l in err] == [f"check {n}" for n in checks]
     if trace:
         # the metric that exists only as an added file was found by name
         assert result["metrics"]["tiny_rounds"]["value"] > 0
+        if workload == "tiny-backlog":      # closed with requests queued
+            assert 0 < result["metrics"]["backlog_queue_left"]["value"] < 100
     else:
         assert result["metrics"]["setup_s"]["value"] == setup["setup_s"]
         assert len(result["metrics"]) == len(names)

@@ -5,6 +5,7 @@ known answers and on the small traces recorded on a v5e
 import json
 import os
 
+import numpy as np
 import pytest
 
 from benchmarks.harness import trace_reduce as tr
@@ -70,6 +71,29 @@ def test_interval_arithmetic():
                                                           (7, 10)]
     assert tr.gaps([(2, 3)], 0, 5) == [(0, 2), (3, 5)]
     assert tr.clip([(0, 10), (20, 30)], 5, 25) == [(5, 10), (20, 25)]
+
+
+def test_the_sweep_gives_each_gap_what_the_plain_loop_gave_it():
+    """The loop that asked every span about every gap stays here as the
+    reference: spans that nest, overlap without nesting, begin long before
+    (a request's), tie in length, touch a point with an end, or miss."""
+    rng = np.random.RandomState(36)
+    starts = rng.uniform(0, 1000, 400)
+    spans = [(f"s{i % 7}", float(a), float(a + d)) for i, (a, d) in
+             enumerate(zip(starts, rng.choice([1, 5, 5, 40, 300], 400)))]
+    spans += [("tie_b", 10.0, 20.0), ("tie_a", 10.0, 20.0),
+              ("request", -500.0, 600.0)]
+    points = sorted(rng.uniform(-20, 1400, 900).tolist() + [10.0, 20.0])
+
+    def plain(at):
+        cover = [(e - s, name) for name, s, e in spans if s <= at <= e]
+        return min(cover)[1] if cover else "unattributed"
+
+    got = tr.shortest_span_over(points, spans)
+    assert got == [plain(at) for at in points]
+    assert {"unattributed", "request", "tie_a"} <= set(got)
+    assert tr.shortest_span_over([1.0, 2.0], []) == ["unattributed"] * 2
+    assert tr.shortest_span_over([], spans) == []
 
 
 def test_labels():

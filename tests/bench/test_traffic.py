@@ -1,11 +1,20 @@
 """The one generator of serving traffic: every seed gets the same work in
 another order."""
 
+import types
+
 import numpy as np
 import pytest
 
+from bench_helpers import REPO
+from benchmarks.harness import manifest as mf
 from benchmarks.harness import traffic as tg
+from benchmarks.harness.runner import load_part
 from benchmarks.harness.stats import percentile, quartile_spread
+
+MANIFEST = mf.load(REPO)
+BACKLOG_CELLS = [c["name"] for c in MANIFEST["workloads"] if mf.load_json(
+    REPO, mf.traffic_path(c)).get("arrival") == "backlog"]
 
 MIX = {"arrival": "stratified_exponential", "rate_per_s": 5.0, "lead_s": 2.0, "tail_s": 3.0,
        "prompt_len": {"median": 192, "sigma": 0.7, "min": 32, "max": 768},
@@ -54,6 +63,64 @@ def test_backlog_is_queued_before_the_window():
     mix = dict(MIX, arrival="backlog", backlog_requests_per_window_s=3)
     a = tg.make_arrivals(mix, 1, 4.0, 100)
     assert len(a) == 32 and all(x.due_s == -float("inf") for x in a)
+
+
+def _answer_tokens(cell):
+    """(requests, answer tokens) the cell's backlog holds at the manifest's
+    `run_seconds`: the same under every seed."""
+    mix = mf.load_json(REPO, mf.traffic_path(mf.cell_of(MANIFEST, cell)))
+    a = tg.make_arrivals(mix, 2 ** 31 + 36, MANIFEST["run_seconds"], 50257)
+    return len(a), sum(x.max_new_tokens for x in a)
+
+
+def test_chat_backlog_is_2464_requests_deep():
+    """48 requests a second of window: 77 blocks of 32, each block 1,965
+    answer tokens. Less the lead's 64 x 64 and the 2,850 or so seated at
+    the close, over 51 s: dry above 2,830 tokens/s (benchmarks/README.md)."""
+    assert _answer_tokens("gpt2m-serve-backlog") == (2464, 151305)
+
+
+# answer tokens a second of window that a backlog holds at the least; a
+# cell that a later PR adds is held to the general floor
+FLOORS = {"joyai-flash-serve-backlog": 5000,
+          "trinity-mini-serve-backlog": 5000}
+
+
+@pytest.mark.parametrize("cell", BACKLOG_CELLS)
+def test_no_backlog_is_thinner_than_2800_answer_tokens_a_second(cell):
+    """A later edit cannot thin a backlog unseen: a cell whose queue can be
+    emptied inside the window refuses the PR that makes the program fast
+    enough to do it (`backlog_never_dry`). The expert cells serve 900 to
+    1,000 tokens/s and hold over 5,000."""
+    per_s = _answer_tokens(cell)[1] / MANIFEST["run_seconds"]
+    assert per_s >= FLOORS.get(cell, 2800)
+
+
+def test_every_backlog_cell_reports_what_it_has_left():
+    metric = next(m for m in MANIFEST["per_layer"]
+                  if m["name"] == "backlog_queue_left")
+    assert set(metric["workloads"]) == set(BACKLOG_CELLS) >= set(FLOORS)
+
+
+@pytest.mark.parametrize("arrival,record,expected", [
+    ("backlog", {"counters": {"queued_at_close": 1280,
+                              "requests_submitted": 2464}}, 51.948),
+    ("backlog", {"counters": {"queued_at_close": 64,
+                              "requests_submitted": 1248}}, 5.128),
+    # a chat cell, a train cell, a run whose record lacks the counter
+    ("stratified_exponential", {"counters": {
+        "queued_at_close": 3, "requests_submitted": 224}}, None),
+    (None, {"counters": {"steps": 230}}, None),
+    ("backlog", {"counters": {"requests_submitted": 2464}}, None),
+    ("backlog", {}, None)])
+def test_backlog_queue_left_reads_the_drivers_counters(arrival, record,
+                                                       expected):
+    ctx = types.SimpleNamespace(
+        traffic={} if arrival is None else {"arrival": arrival},
+        record=record)
+    value = load_part(REPO, "layer_metrics", "backlog_queue_left").read(ctx)
+    assert value == (None if expected is None
+                     else pytest.approx(expected, abs=1e-3))
 
 
 def test_statistics():
