@@ -160,7 +160,8 @@ from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
 from bigdl_tpu.serving.prefix_cache import RadixPrefixCache
-from bigdl_tpu.serving.sampler import sample_logits
+from bigdl_tpu.serving.sampler import (SAMPLER_PATHS, sample_logits,
+                                       step_path)
 from bigdl_tpu.utils import faults
 from bigdl_tpu.utils.anomaly import rows_finite
 
@@ -719,6 +720,17 @@ class InferenceEngine:
                              ).labels(engine=self._obs_name,
                                       tp=self._obs_tp)
             for key, help_ in op_help.items()}
+        # decode steps by what the program's sampler ran for the seated
+        # rows (serving/sampler.py: the costliest row's class), counted
+        # on the host from the step's own operands
+        self._sampler_steps = dict.fromkeys(SAMPLER_PATHS, 0)
+        self._m_sampler = {
+            path: reg.counter(
+                "serving_sampler_steps_total",
+                "decode steps by the sampler path their rows took",
+                labelnames=("engine", "path")
+                ).labels(engine=self._obs_name, path=path)
+            for path in SAMPLER_PATHS}
         self._m_lat = reg.histogram(
             "serving_decode_step_seconds",
             "decode dispatch+fetch wall seconds",
@@ -960,6 +972,10 @@ class InferenceEngine:
             # rows one layer of each cache kind holds for the seated
             # slots (the serving_kv_rows_held gauge)
             "kv_rows_held": self._kv_rows_held(),
+            # decode steps since start by sampler path, as shares
+            "sampler_path_share": {
+                path: round(n / max(1, s["decode_steps"]), 4)
+                for path, n in self._sampler_steps.items()},
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
             "model_tag": self.model_tag,
@@ -2268,6 +2284,8 @@ class InferenceEngine:
             return done
         plan = faults.get_plan()
         stepno = self._stats["decode_steps"]
+        sampler_path = step_path(self._temp, self._topk, self._topp,
+                                 self.model.cfg.vocab_size)
         poison = np.zeros(self.slots, bool)
         if plan.fires("serve_nan", stepno):
             active = [i for i, r in enumerate(self._req) if r is not None]
@@ -2296,6 +2314,7 @@ class InferenceEngine:
                                    attended_blocks=int(
                                        self._attended_blocks()),
                                    table_blocks=self._table.size,
+                                   sampler_path=sampler_path,
                                    **self._decode_read_report())
                     nxt, finite = self._dispatch_and_fetch(poison,
                                                            slow_s)
@@ -2335,6 +2354,9 @@ class InferenceEngine:
                 if self.retry_backoff_s:
                     time.sleep(self.retry_backoff_s * (2 ** attempt))
         self._bump("decode_steps")
+        self._sampler_steps[sampler_path] += 1
+        if obs.enabled():
+            self._m_sampler[sampler_path].inc()
         now = self._clock()
         with self._span("emit"):
             for i, req in enumerate(self._req):

@@ -251,6 +251,60 @@ def test_compile_guard_with_telemetry_enabled():
     assert all(e["ph"] in ("X", "i") for e in doc["traceEvents"])
 
 
+def test_sampler_path_counted_by_what_the_seated_rows_ask():
+    """ISSUE 37: every decode step is classed on the host by the
+    predicate the program's sampler branches on (the costliest seated
+    row's class): counter, `health()` shares and the recorded
+    `decode_step` spans say the same word. V = 50 here, so K = 50."""
+    from bigdl_tpu.serving import InferenceEngine, Request
+    from bigdl_tpu.serving.sampler import SAMPLER_PATHS
+
+    obs.set_tracer(obs.SpanTracer(enabled=True))
+    eng = InferenceEngine(_tiny_lm(), slots=2, prefill_buckets=(8,))
+
+    def counted():
+        series = obs.get_registry().snapshot()["metrics"].get(
+            "serving_sampler_steps_total", {"series": []})["series"]
+        got = dict.fromkeys(SAMPLER_PATHS, 0)
+        got.update({s["labels"]["path"]: s["value"] for s in series})
+        return got
+
+    sampled = dict(prompt=[4, 5, 6], max_new_tokens=4, temperature=0.8,
+                   seed=3)
+    waves = [
+        ("greedy", [Request(prompt=[1, 2, 3], max_new_tokens=3),
+                    Request(prompt=[7, 8], max_new_tokens=3)]),
+        ("unfiltered", [Request(**sampled)]),
+        # a candidates row beside a greedy one that outlasts it: the
+        # steps they share are the sampling row's, the rest greedy
+        ("candidates", [Request(**sampled, top_k=5, top_p=0.9),
+                        Request(prompt=[1, 2, 3], max_new_tokens=6)]),
+        ("full_sort", [Request(**sampled, top_p=0.9),
+                       Request(**sampled, top_k=5, top_p=0.9)]),
+        ("full_sort", [Request(**sampled, top_k=51)]),
+    ]
+    want = dict.fromkeys(SAMPLER_PATHS, 0)
+    for path, reqs in waves:
+        before = eng.stats["decode_steps"]
+        assert all(r.status == "done" for r in eng.run(reqs))
+        steps = eng.stats["decode_steps"] - before
+        if path == "candidates":
+            # a token a step: the sampling row is seated for 4 of them
+            want["candidates"] += 4
+            want["greedy"] += steps - 4
+        else:
+            want[path] += steps
+        assert counted() == want, path
+    assert eng.stats["decode_traces"] == 1        # ONE executable
+    total = sum(want.values())
+    assert total == eng.stats["decode_steps"]
+    assert eng.health()["sampler_path_share"] == {
+        p: round(n / total, 4) for p, n in want.items()}
+    spans = [e["args"]["sampler_path"] for e in
+             obs.get_tracer().events("decode_step") if e["ph"] == "X"]
+    assert {p: spans.count(p) for p in SAMPLER_PATHS} == want
+
+
 def test_engine_metrics_off_keeps_core_bookkeeping():
     """BIGDL_OBS=off: stats AND health() — including the latency
     percentiles, which are core bookkeeping fed unconditionally —
