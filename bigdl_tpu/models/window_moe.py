@@ -172,6 +172,46 @@ def rope_half_split(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
+def grouped_prompt_attention(q, k, v, kv_heads: int, sm_scale: float,
+                             window=None):
+    """Causal (and, with a `window`, windowed) attention of one
+    sequence over its own keys, grouped-query heads: q (S, Hq, D), k
+    and v (S, G, D) in the compute dtype → (S, Hq * D) float32. A block
+    of queries at a time, against all S keys without a window and
+    against the `window + block` keys that end at the block's last
+    query with one; the mask is on positions either way."""
+    s, hq, dh = q.shape
+    g = kv_heads
+    # every prefill bucket is whole blocks; another length (a test's
+    # `apply`) is one block
+    qb = s if s % _QUERY_BLOCK else _QUERY_BLOCK
+    sliding = window is not None
+    extent = min(s, window + qb) if sliding else s
+    q = q.reshape(s // qb, qb, g, hq // g, dh)
+
+    def block(args):
+        i, qi = args                            # qi (qb, G, R, D)
+        lo = jnp.clip(i * qb + qb - extent, 0, s - extent)
+        ks = jax.lax.dynamic_slice_in_dim(k, lo, extent)
+        vs = jax.lax.dynamic_slice_in_dim(v, lo, extent)
+        sc = jnp.einsum("qgrd,kgd->grqk", qi, ks,
+                        preferred_element_type=jnp.float32) * sm_scale
+        iq = (i * qb + jnp.arange(qb))[:, None]
+        jk = (lo + jnp.arange(extent))[None, :]
+        visible = jk <= iq
+        if sliding:
+            visible &= iq - jk < window
+        sc = jnp.where(visible, sc, _NEG_INF)
+        p = jnp.exp(sc - jnp.max(sc, -1, keepdims=True))
+        p = p / jnp.sum(p, -1, keepdims=True)
+        o = jnp.einsum("grqk,kgd->qgrd", p.astype(vs.dtype), vs,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(qb, hq * dh)
+
+    out = jax.lax.map(block, (jnp.arange(s // qb), q))
+    return out.reshape(s, hq * dh)
+
+
 class WindowMoELM(Module):
     """See the module docstring. Parameters are per layer from the
     start, as `LatentMoELM`'s: `{"embed" (V, d), "head" (d, V), "norm"
@@ -274,44 +314,10 @@ class WindowMoELM(Module):
         return x + rms_norm(f, lp["ln_post_mlp"], c.rms_norm_eps), n
 
     def _prompt_attention(self, q, k, v, kind):
-        """Causal (and, a sliding layer, windowed) attention of one
-        sequence over its own keys: q (S, Hq, D), k and v (S, G, D) in
-        the compute dtype → (S, Hq * D) float32. A block of queries at
-        a time, against all S keys for a full layer and against the
-        `window + block` keys that end at the block's last query for a
-        sliding one; the mask is on positions either way."""
         c = self.cfg
-        s, hq, dh = q.shape
-        g = c.num_key_value_heads
-        # every prefill bucket is whole blocks; another length (a test's
-        # `apply`) is one block
-        qb = s if s % _QUERY_BLOCK else _QUERY_BLOCK
-        sliding = kind == "sliding_attention"
-        extent = min(s, c.sliding_window + qb) if sliding else s
-        q = q.reshape(s // qb, qb, g, hq // g, dh)
-
-        def block(args):
-            i, qi = args                            # qi (qb, G, R, D)
-            lo = jnp.clip(i * qb + qb - extent, 0, s - extent)
-            ks = jax.lax.dynamic_slice_in_dim(k, lo, extent)
-            vs = jax.lax.dynamic_slice_in_dim(v, lo, extent)
-            sc = jnp.einsum("qgrd,kgd->grqk", qi, ks,
-                            preferred_element_type=jnp.float32) \
-                * self.sm_scale
-            iq = (i * qb + jnp.arange(qb))[:, None]
-            jk = (lo + jnp.arange(extent))[None, :]
-            visible = jk <= iq
-            if sliding:
-                visible &= iq - jk < c.sliding_window
-            sc = jnp.where(visible, sc, _NEG_INF)
-            p = jnp.exp(sc - jnp.max(sc, -1, keepdims=True))
-            p = p / jnp.sum(p, -1, keepdims=True)
-            o = jnp.einsum("grqk,kgd->qgrd", p.astype(vs.dtype), vs,
-                           preferred_element_type=jnp.float32)
-            return o.reshape(qb, hq * dh)
-
-        out = jax.lax.map(block, (jnp.arange(s // qb), q))
-        return out.reshape(s, hq * dh)
+        return grouped_prompt_attention(
+            q, k, v, c.num_key_value_heads, self.sm_scale,
+            c.sliding_window if kind == "sliding_attention" else None)
 
     # ------------------------------------------------------- full forward
 

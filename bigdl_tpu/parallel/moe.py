@@ -289,24 +289,42 @@ class DroplessMoE(Module):
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx, w * self.scale
 
-    def forward(self, p, x, x32=None):
+    def forward(self, p, x, x32=None, routing=None):
         """x (T, D) in the experts' compute dtype (x32: the same rows
         in float32 for the router, where the caller has them) →
-        (y (T, D) float32, tokens per expert int32[E])."""
+        (y (T, D) float32, tokens per expert int32[E]).
+
+        `routing` is a routing the MODEL decided, in place of `route`:
+        (experts (T, k) int32, weights (T, k) float32), whatever its
+        router is (models/cca_moe.py: an MLP's softmax, top-1). The id
+        `num_experts` there sends an assignment to NO expert: it sorts
+        behind every expert's rows, lies in no group of the grouped
+        matmuls (which read no expert for it), adds nothing to `y`,
+        and is counted in a last column of its own, int32[E + 1]."""
         t, k, e = x.shape[0], self.top_k, self.num_experts
-        idx, w = self.route(p, x.astype(jnp.float32) if x32 is None
-                            else x32)
+        if routing is None:
+            idx, w = self.route(p, x.astype(jnp.float32) if x32 is None
+                                else x32)
+            bins = e
+        else:
+            idx, w = routing
+            bins = e + 1
         flat = idx.reshape(t * k)
         order = jnp.argsort(flat)             # stable: ties by token
-        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        counts = jnp.zeros((bins,), jnp.int32).at[flat].add(1)
+        sizes = counts if bins == e else counts[:e]
         xs = x[order // k]                    # (T*k, D), expert-sorted
 
         def grouped(a, wgt):
-            return lax.ragged_dot(a, wgt, counts,
+            return lax.ragged_dot(a, wgt, sizes,
                                   preferred_element_type=jnp.float32)
 
         h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
         ys = grouped(h.astype(x.dtype), p["w_down"])      # (T*k, D)
+        if bins != e:
+            # rows past the last group: whatever the grouped matmul
+            # leaves there is not a result
+            ys = jnp.where((flat[order] < e)[:, None], ys, 0.0)
         # back to (token, choice) order, then the weighted sum over a
         # token's k experts in a fixed order (no scatter-add)
         y = ys[jnp.argsort(order)].reshape(t, k, self.dim)
@@ -321,19 +339,27 @@ class DroplessMoE(Module):
         return y.reshape(x.shape).astype(x.dtype), variables["state"]
 
 
-def expert_load_report(aux):
+def expert_load_report(aux, skip_column: bool = False):
     """From one decode step's fetched `aux` (MoE layers, E), the tokens
     each expert got (`DroplessMoE.forward`'s second result, stacked by
     the model): the args the serving engine hangs on its `decode_step`
     span, and the engine counters to bump. What a model's
-    `decode_aux_report` returns."""
+    `decode_aux_report` returns. With `skip_column` the aux is (MoE
+    layers, E + 1), its last column the rows that went to no expert
+    (a routing the model decided): `skipped_rows` a layer, and
+    `routed_rows`, all the rows the layers' routers placed."""
     import numpy as np
 
     aux = np.asarray(aux)
+    args = {}
+    if skip_column:
+        args = {"skipped_rows": [int(n) for n in aux[:, -1]],
+                "routed_rows": int(aux.sum())}
+        aux = aux[:, :-1]
     mean = np.maximum(aux.mean(axis=1), 1e-9)
     return ({"experts_touched": [int(n) for n in (aux > 0).sum(1)],
              "expert_load_max_over_mean": [
-                 float(v) for v in aux.max(axis=1) / mean]},
+                 float(v) for v in aux.max(axis=1) / mean], **args},
             {"moe_tokens_routed": int(aux.sum())})
 
 

@@ -298,6 +298,18 @@ def _decode_step(model, params, pools, tok, pos, seed, nout, temp,
     return nxt, finite, pools, tuple(aux)
 
 
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+def _clear_slot_state(kinds, pools, slot):
+    """Zero one slot's row of every "state" entry of the pools, in
+    place, in one program for all the layers: nothing a poisoned
+    request wrote outlives its eviction."""
+    return tuple(
+        jax.tree_util.tree_map(
+            lambda leaf: leaf.at[slot].set(jnp.zeros((), leaf.dtype)),
+            layer) if kind == "state" else layer
+        for kind, layer in zip(kinds, pools))
+
+
 @dataclass
 class Request:
     """One generation request. temperature <= 0 → greedy; top_k <= 0 /
@@ -599,17 +611,25 @@ class InferenceEngine:
         # table names, allocated, grown and released below; "ring" rows
         # in a region of the leaf the slot owns for good
         # (ops/kv_cache.init_ring_pool), `_ring_blocks` blocks of it,
-        # which nothing here allocates or releases. What indexes every
-        # leaf by a TABLE block id (spill, handoff, migration, the
-        # prefix tree) is for table-only models, and a model with a
-        # ring refuses it (`check_serving_options`, `import_handoff`)
+        # which nothing here allocates or releases; a "state" entry is
+        # one row a slot, with no position axis (models/cca_moe.py):
+        # the prefill sets the slot's whole row, the decode step
+        # rewrites it, `_release_slot` scrubs a poisoned request's.
+        # What indexes every leaf by a TABLE block id (spill, handoff,
+        # migration, the prefix tree) is for table-only models, and a
+        # model with a ring or a state refuses it
+        # (`check_serving_options`, `import_handoff`)
         kinds = getattr(model, "cache_kinds", None)
         self._cache_kinds = kinds() if kinds is not None else None
         self._ring_blocks = model.ring_blocks(block_size) \
             if self._cache_kinds and "ring" in self._cache_kinds else 0
+        # bytes ONE seated slot keeps in the model's "state" entries
+        self._slot_state_bytes = model.slot_state_bytes() \
+            if self._cache_kinds and "state" in self._cache_kinds else 0
         self.pool = model.init_block_pool(
             pool_blocks, block_size, cache_dtype,
-            **({"slots": slots} if self._ring_blocks else {}))
+            **({"slots": slots} if self._ring_blocks
+               or self._slot_state_bytes else {}))
         self._pool_mgr = BlockPool(pool_blocks, block_size)
         self._prefix = RadixPrefixCache(self._pool_mgr,
                                         host_blocks=self.host_blocks)
@@ -618,7 +638,10 @@ class InferenceEngine:
         # — model-agnostic
         self._kv_bytes_per_token = int(sum(
             leaf.nbytes // leaf.shape[0]
-            for leaf in jax.tree_util.tree_leaves(self.pool))
+            for kind, layer in zip(
+                self._cache_kinds or ("table",) * len(self.pool), self.pool)
+            if kind != "state"      # a slot's row, not a token's
+            for leaf in jax.tree_util.tree_leaves(layer))
             // block_size)
         self.buckets = tuple(sorted(
             prefill_buckets if prefill_buckets is not None
@@ -773,6 +796,11 @@ class InferenceEngine:
                 labelnames=("engine", "kind")
                 ).labels(engine=self._obs_name, kind=kind)
             for kind in ("window", "full")}
+        self._m_state_gauge = reg.gauge(
+            "serving_slot_state_bytes",
+            "bytes the seated slots keep in the model's per-slot state "
+            "entries (cache kind 'state': no position axis)",
+            labelnames=("engine",)).labels(engine=self._obs_name)
         self._m_tp_gauge = reg.gauge(
             "serving_tp_shards",
             "tensor-parallel shard count serving this engine",
@@ -972,6 +1000,9 @@ class InferenceEngine:
             # rows one layer of each cache kind holds for the seated
             # slots (the serving_kv_rows_held gauge)
             "kv_rows_held": self._kv_rows_held(),
+            # what the seated slots keep beside their rows (the
+            # serving_slot_state_bytes gauge; 0: no "state" entry)
+            "slot_state_bytes": self._slot_state_held(),
             # decode steps since start by sampler path, as shares
             "sampler_path_share": {
                 path: round(n / max(1, s["decode_steps"]), 4)
@@ -1402,10 +1433,15 @@ class InferenceEngine:
                 "full": int(np.count_nonzero(self._table))
                 * self.block_size}
 
+    def _slot_state_held(self) -> int:
+        return self._slot_state_bytes * int(
+            np.count_nonzero(self._table[:, 0]))
+
     def _update_rows_gauge(self) -> None:
         if obs.enabled():
             for kind, rows in self._kv_rows_held().items():
                 self._m_rows_gauges[kind].set(rows)
+            self._m_state_gauge.set(self._slot_state_held())
 
     def _update_pool_gauge(self) -> None:
         self._update_rows_gauge()
@@ -1586,6 +1622,12 @@ class InferenceEngine:
                 "slot": np.int32(slot),
                 "sources": jnp.asarray(ring_prompt_sources(
                     n, bs, self._ring_blocks))}}
+        elif self._slot_state_bytes:
+            # a model with a state: the slot, and the position whose
+            # rows it keeps, the last before the token that the first
+            # decode step re-decodes (-1: a prompt of one token)
+            block_ids = {"table": block_ids, "state": {
+                "slot": np.int32(slot), "keep": np.int32(n - 2)}}
         tracer = obs.get_tracer()
         t_admit = self._clock()
         if tracer.enabled:
@@ -1685,6 +1727,17 @@ class InferenceEngine:
             freed += pool.unref([b])
         if poisoned and freed:
             self._scrub_blocks(freed)
+        if poisoned and self._slot_state_bytes \
+                and not self._cache_consumed():
+            # the scrub of what it wrote outside table blocks. A sound
+            # request's row stays: the next prefill rewrites the whole
+            # of it (zeros for a prompt of one token) and a decode step
+            # rewrites seated slots only, so no result ever reads it
+            with warnings.catch_warnings():
+                warnings.filterwarnings(
+                    "ignore", message=".*[Dd]onat", category=UserWarning)
+                self.pool = _clear_slot_state(
+                    self._cache_kinds, self.pool, np.int32(slot))
         self._slot_blocks[slot] = [[], []]
         self._table[slot, :] = 0
         self._update_pool_gauge()
@@ -1700,7 +1753,8 @@ class InferenceEngine:
         blocks. A ring leaf has none and is left alone: its slot reads
         nothing until it is seated again, and the next occupant's
         prefill rewrites every block of the slot's region
-        (ops/kv_cache.write_prompt_ring)."""
+        (ops/kv_cache.write_prompt_ring). A state leaf has none
+        either: `_release_slot` zeroes the poisoned slot's row."""
         idx = jnp.asarray(blocks, jnp.int32)
         kinds = self._cache_kinds or ("table",) * len(self.pool)
         self.pool = tuple(
@@ -2124,10 +2178,10 @@ class InferenceEngine:
         tree, so a handed-off prompt seeds prefix reuse here too."""
         if self.role == "prefill":
             raise ValueError("import_handoff on a prefill-role engine")
-        if self._ring_blocks:
+        if self._ring_blocks or self._slot_state_bytes:
             raise NotImplementedError(
                 "import_handoff into an engine whose model keeps ring "
-                "leaves: a package carries table blocks only")
+                "or state leaves: a package carries table blocks only")
         if self._degraded:
             raise EngineDegraded(
                 f"engine degraded ({self._degraded}); hand off to a "

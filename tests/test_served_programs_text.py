@@ -1,0 +1,100 @@
+"""The lowered text of the three older served families' decode and prefill
+programs at toy sizes, pinned by its sha256 as taken on the parent commit
+(ac7b450): the sigmoid router's path through `DroplessMoE.forward`, the
+extraction of `grouped_prompt_attention` from `WindowMoELM` and the
+engine's third cache kind ("state", ISSUE 38) left them byte for byte what
+they were.
+
+To take the hashes of another checkout (the parent's, say), run this file
+there: `cd <checkout> && PYTHONPATH=. python <this file>` prints them as
+JSON; it imports of the checkout only what the parent has too.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.getcwd() if __name__ == "__main__" else os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))
+PARENTS = {
+    ("afmoe", "decode"): "03a1a387f0be2d69",
+    ("afmoe", "prefill"): "645e88e11c673841",
+    ("mla_moe", "decode"): "6b6fcd9efdf3251b",
+    ("mla_moe", "prefill"): "c208b75ce320454f",
+    ("gpt2", "decode"): "4fb2103bb9d60ab3",
+    ("gpt2", "prefill"): "cc70aade132de162",
+}
+
+
+def _vec(dtype, *shape):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _toy(family):
+    """(model, params, pools, the prefill's block ids) of a family's toy
+    size, as shapes: 3 slots of 64 positions in blocks of 4."""
+    i32 = jnp.int32
+    if family == "gpt2":
+        from bigdl_tpu.models.transformer import build_lm
+        from bigdl_tpu.serving import InferenceEngine
+
+        lm = build_lm(vocab_size=61, dim=32, num_heads=2, num_layers=2,
+                      max_len=64)
+        lm.build(jax.random.PRNGKey(0))
+        eng = InferenceEngine(lm, slots=3, max_len=64, block_size=4,
+                              prefill_buckets=(16, 32))
+        return (eng.model, jax.eval_shape(lambda: eng._params),
+                jax.eval_shape(lambda: eng.pool), _vec(i32, 8))
+    from benchmarks.families import afmoe, mla_moe
+
+    fam, tiny = {"afmoe": (afmoe, "tiny_afmoe/configs/tiny-afmoe.json"),
+                 "mla_moe": (mla_moe,
+                             "tiny_mla_moe/configs/tiny-mla-moe.json")}[family]
+    with open(os.path.join(REPO, "tests", "bench", tiny)) as f:
+        cfg = json.load(f)
+    model = fam.program_model(cfg)
+    params = jax.eval_shape(lambda: fam.make_variables(5, cfg)["params"])
+    if family == "mla_moe":
+        return (model, params, jax.eval_shape(
+            lambda: model.init_block_pool(33, 4, jnp.float32)), _vec(i32, 8))
+    pools = jax.eval_shape(
+        lambda: model.init_block_pool(33, 4, jnp.float32, slots=3))
+    return model, params, pools, {
+        "table": _vec(i32, 8),
+        "ring": {"slot": _vec(i32),
+                 "sources": _vec(i32, model.ring_blocks(4))}}
+
+
+def lowered_hash(family, program):
+    from bigdl_tpu.serving import engine as eng
+
+    model, params, pools, ids = _toy(family)
+    i32, f32, slots, per_slot = jnp.int32, jnp.float32, 3, 16
+    if program == "decode":
+        lowered = eng._decode_step.lower(
+            model, params, pools, _vec(i32, slots), _vec(i32, slots),
+            _vec(i32, slots), _vec(i32, slots), _vec(f32, slots),
+            _vec(i32, slots), _vec(f32, slots), _vec(jnp.bool_, slots),
+            _vec(i32, slots, per_slot))
+    else:
+        lowered = eng._prefill_step.lower(
+            model, params, pools, _vec(i32, 1, 32), _vec(i32), ids,
+            _vec(i32, 1, per_slot))
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("family,program", list(PARENTS),
+                         ids=["-".join(k) for k in PARENTS])
+def test_a_served_program_lowers_to_the_parents_text(family, program):
+    assert lowered_hash(family, program) == PARENTS[family, program]
+
+
+if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(REPO, "tests", "bench"))
+    print(json.dumps({"-".join(k): lowered_hash(*k) for k in PARENTS},
+                     indent=1))
