@@ -46,7 +46,12 @@ that a row's result hangs on that row's own query, clock and cache
 rows and on nothing else of the call. The head-split form
 (`paged_attention_heads`) keeps it by reading the full extent too; the
 rows and latent forms read each slot's own live chunks, in order
-(`_ragged_attention`, ISSUE 32), at shapes the table's shape fixes.
+(`_ragged_attention`, ISSUE 32), at shapes the table's shape fixes:
+the live chunks of a batch are rounded up to one of three compiled
+sizes, a sixteenth, half or all of the table's chunks (`_READ_SHARES`,
+whose comment says which cell of the benchmark showed what each buys;
+ISSUE 39 brought the half), and which one runs changes no bit of a
+row's result.
 The one deliberate asymmetry: Q=1 decode gemms lower to different
 kernels than Q>=2 prefill gemms (measured on CPU XLA), so positions a
 decode step wrote are NEVER shared — the serving engine caps reuse and
@@ -119,6 +124,7 @@ from __future__ import annotations
 
 import functools
 import math
+from fractions import Fraction
 from typing import Optional, Tuple
 
 import jax
@@ -403,14 +409,35 @@ def paged_attention_heads(q: jax.Array, k_pool: jax.Array,
 
 # chunks a slot's table is read in, and the shares of all the chunks of
 # a batch that the read is compiled for: constants chosen once from
-# chip measurements (PERF.md, PR 32), never knobs. Every size is a copy
-# of the read in every layer's program (1.3 MB of device code each at
-# gpt2-medium's widths, 32 MB over 24 layers, and seconds of compile),
-# so a size is here only where a cell of the benchmark has shown end to
-# end what it buys: one for a batch that is mostly empty seats, and all
-# of it.
+# chip measurements (PERF.md, PR 32 and PR 39), never knobs. Every size
+# is a copy of the read in every layer's program (1.3 MB of device code
+# each at gpt2-medium's widths, 32 MB over 24 layers, and seconds of
+# compile), and a compiled size is a cliff (a step one chunk over it
+# falls to the next), so a size is here only where a cell of the
+# benchmark has shown end to end what it buys:
+# - 1/16, a batch that is mostly empty seats: gpt2m-serve-chat (3% of
+#   the seats taken), `serve_tpot_mean` 47.80 -> 12.48 ms (ledger, PR 32);
+# - 1/2, a full batch of slots at mixed depths: the four backlog cells,
+#   whose live chunks are 23-45% of all in every step (PERF.md §7):
+#   gpt2m-serve-backlog 1,371 -> 1,911 tokens/s, zaya1-8b-serve-backlog
+#   1,616 -> 2,125 (builders' chip runs, PR 39; the two other backlog
+#   cells in PERF.md §6). 3/8 would read a quarter less and drop 6% of
+#   the GPT-2 backlog's steps and 78% of Trinity's to the read of all;
+# - all of it, whatever is fuller than that (Trinity's rings, 84% live).
 _CHUNKS_PER_SLOT = 8
-_READ_SHARES = (1 / 16, 1.0)
+_READ_SHARES = (1 / 16, 1 / 2, 1.0)
+# the shares by name, as a step's read is counted ("1/16", "1/2", "1")
+READ_SHARE_NAMES = tuple(str(Fraction(s)) for s in _READ_SHARES)
+
+
+def _share_sizes(slots: int, blocks_per_slot: int
+                 ) -> Tuple[int, Tuple[int, ...]]:
+    """(blocks a chunk, the chunk count each of `_READ_SHARES` is
+    compiled at, in their order: two may be equal at a small table)."""
+    chunk_blocks = -(-blocks_per_slot // _CHUNKS_PER_SLOT)
+    most = slots * -(-blocks_per_slot // chunk_blocks)
+    return chunk_blocks, tuple(max(1, math.ceil(most * s))
+                               for s in _READ_SHARES)
 
 
 def ragged_read_sizes(slots: int, blocks_per_slot: int
@@ -420,10 +447,8 @@ def ragged_read_sizes(slots: int, blocks_per_slot: int
     compiled for, ascending, the last one every chunk of every slot).
     A chunk is `blocks_per_slot / 8` blocks rounded up (8 blocks = 128
     rows at gpt2-medium's 64 x 16 table)."""
-    chunk_blocks = -(-blocks_per_slot // _CHUNKS_PER_SLOT)
-    most = slots * -(-blocks_per_slot // chunk_blocks)
-    sizes = sorted({max(1, math.ceil(most * s)) for s in _READ_SHARES})
-    return chunk_blocks, tuple(sizes)
+    chunk_blocks, by_share = _share_sizes(slots, blocks_per_slot)
+    return chunk_blocks, tuple(sorted(set(by_share)))
 
 
 def _live_chunks(pos, first_block, block_size: int, chunk_blocks: int):
@@ -441,21 +466,31 @@ def _read_size_index(chunks, sizes: Tuple[int, ...]):
     return sum(chunks > n for n in sizes[:-1])
 
 
-def attended_blocks(pos, table, block_size: int) -> int:
-    """Blocks the decode read gathers for these clocks and this table
-    (host, NumPy): every seated slot's blocks rounded up to whole
-    chunks, their sum rounded up to the read compiled for it. The
-    ragged core takes both roundings from the same three functions, so
-    the engine's `attended_blocks` is what the program read. Never
-    more than the table holds: where a slot's last chunk is short of
-    whole (`blocks_per_slot` no multiple of the chunk), the rest of it
-    is the scratch block and not the table's."""
-    pos, table = np.asarray(pos), np.asarray(table)
-    chunk_blocks, sizes = ragged_read_sizes(*table.shape)
+def decode_read(pos: np.ndarray, table: np.ndarray, block_size: int
+                ) -> Tuple[str, int]:
+    """The decode read of these clocks and this table (the engine's
+    host copies, NumPy arrays: nothing here touches the device):
+    which of `READ_SHARE_NAMES` it is compiled for, and the blocks it
+    gathers: every seated slot's blocks rounded up to whole chunks,
+    their sum rounded up to a compiled read. The ragged core takes both
+    roundings from the same three functions, so this is what the
+    program read. Never more blocks than the table holds: where a
+    slot's last chunk is short of whole (`blocks_per_slot` no multiple
+    of the chunk), the rest of it is the scratch block and not the
+    table's. Where a small table makes two shares one size, the read
+    goes by the larger one's name."""
+    chunk_blocks, by_share = _share_sizes(*table.shape)
     chunks = int(_live_chunks(pos, table[:, 0], block_size,
                               chunk_blocks).sum())
-    read = sizes[int(_read_size_index(chunks, sizes))] * chunk_blocks
-    return min(read, table.size)
+    n = by_share[int(_read_size_index(chunks, by_share))]
+    name = dict(zip(by_share, READ_SHARE_NAMES))[n]
+    return name, min(n * chunk_blocks, table.size)
+
+
+def attended_blocks(pos, table, block_size: int) -> int:
+    """Blocks the decode read gathers for these clocks and this table
+    (`decode_read`'s second): the engine's `attended_blocks`."""
+    return decode_read(np.asarray(pos), np.asarray(table), block_size)[1]
 
 
 @functools.partial(jax.jit, static_argnames=("lanes", "groups"))

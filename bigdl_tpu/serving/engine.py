@@ -155,7 +155,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import obs
-from bigdl_tpu.ops.kv_cache import attended_blocks, ring_prompt_sources
+from bigdl_tpu.ops.kv_cache import (READ_SHARE_NAMES, decode_read,
+                                    ring_prompt_sources)
 from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
@@ -754,6 +755,18 @@ class InferenceEngine:
                 labelnames=("engine", "path")
                 ).labels(engine=self._obs_name, path=path)
             for path in SAMPLER_PATHS}
+        # decode steps by the share of the table their read was compiled
+        # for (ops/kv_cache.decode_read: the program's own roundings),
+        # counted on the host from the step's clocks and table
+        self._read_steps = dict.fromkeys(READ_SHARE_NAMES, 0)
+        self._m_read = {
+            read: reg.counter(
+                "serving_decode_read_steps_total",
+                "decode steps by the share of the block table their "
+                "read was compiled for",
+                labelnames=("engine", "read")
+                ).labels(engine=self._obs_name, read=read)
+            for read in READ_SHARE_NAMES}
         self._m_lat = reg.histogram(
             "serving_decode_step_seconds",
             "decode dispatch+fetch wall seconds",
@@ -996,7 +1009,7 @@ class InferenceEngine:
             # the share of the block table a decode step reads at the
             # slots' current clocks
             "attended_share": round(
-                self._attended_blocks() / self._table.size, 4),
+                self._decode_read()[1] / self._table.size, 4),
             # rows one layer of each cache kind holds for the seated
             # slots (the serving_kv_rows_held gauge)
             "kv_rows_held": self._kv_rows_held(),
@@ -1007,6 +1020,10 @@ class InferenceEngine:
             "sampler_path_share": {
                 path: round(n / max(1, s["decode_steps"]), 4)
                 for path, n in self._sampler_steps.items()},
+            # and by the share of the table their read was compiled for
+            "read_share_steps": {
+                read: round(n / max(1, s["decode_steps"]), 4)
+                for read, n in self._read_steps.items()},
             "weight_dtype": self.weight_dtype,
             "cache_dtype": np.dtype(self.cache_dtype).name,
             "model_tag": self.model_tag,
@@ -2312,14 +2329,15 @@ class InferenceEngine:
                 span.set(attn_impl="xla", attn_form=self.attn_form,
                          **log)
 
-    def _attended_blocks(self) -> int:
-        """Blocks of the table a decode step's read gathers at the
-        slots' clocks: the rows form reads each slot's live chunks
-        (ops/kv_cache.attended_blocks: the program's own roundings),
-        the head-split form all of it."""
+    def _decode_read(self) -> Tuple[str, int]:
+        """The share of the table a decode step's read is compiled for
+        at the slots' clocks, by name, and the blocks it gathers: the
+        rows form reads each slot's live chunks (ops/kv_cache.
+        decode_read: the program's own roundings), the head-split form
+        all of it."""
         if self.attn_form != "rows":
-            return self._table.size
-        return attended_blocks(self._pos, self._table, self.block_size)
+            return READ_SHARE_NAMES[-1], self._table.size
+        return decode_read(self._pos, self._table, self.block_size)
 
     def _decode_read_report(self) -> dict:
         """What the model adds to a recorded `decode_step` span about
@@ -2340,6 +2358,7 @@ class InferenceEngine:
         stepno = self._stats["decode_steps"]
         sampler_path = step_path(self._temp, self._topk, self._topp,
                                  self.model.cfg.vocab_size)
+        read_share, read_blocks = self._decode_read()
         poison = np.zeros(self.slots, bool)
         if plan.fires("serve_nan", stepno):
             active = [i for i, r in enumerate(self._req) if r is not None]
@@ -2365,8 +2384,7 @@ class InferenceEngine:
                                        self._pos[i] + 1 for i, r
                                        in enumerate(self._req)
                                        if r is not None)),
-                                   attended_blocks=int(
-                                       self._attended_blocks()),
+                                   attended_blocks=read_blocks,
                                    table_blocks=self._table.size,
                                    sampler_path=sampler_path,
                                    **self._decode_read_report())
@@ -2409,8 +2427,10 @@ class InferenceEngine:
                     time.sleep(self.retry_backoff_s * (2 ** attempt))
         self._bump("decode_steps")
         self._sampler_steps[sampler_path] += 1
+        self._read_steps[read_share] += 1
         if obs.enabled():
             self._m_sampler[sampler_path].inc()
+            self._m_read[read_share].inc()
         now = self._clock()
         with self._span("emit"):
             for i, req in enumerate(self._req):

@@ -248,8 +248,11 @@ def test_the_latent_read_is_ragged_and_equals_a_plain_reference(pos):
 
 
 def test_a_latent_slots_result_does_not_hang_on_the_other_slots_clocks():
+    """Slot 2 beside neighbours whose clocks make the batch's live
+    chunks 3, 13, 18, 23 and 38 of 42: the reads compiled for 3, for
+    half (21, twice) and for all (twice); the same bits in each."""
     results = []
-    for others in (-1, 13, 30, 79):     # reads of 3, then of all 42 chunks
+    for others in (-1, 13, 30, 40, 79):
         pos = [others] * 6
         pos[2] = 30
         args, want = _latent_case(pos)

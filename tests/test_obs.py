@@ -251,6 +251,15 @@ def test_compile_guard_with_telemetry_enabled():
     assert all(e["ph"] in ("X", "i") for e in doc["traceEvents"])
 
 
+def _steps_counted(metric, label, names):
+    """A per-step counter's series by its label, 0 where none yet."""
+    series = obs.get_registry().snapshot()["metrics"].get(
+        metric, {"series": []})["series"]
+    got = dict.fromkeys(names, 0)
+    got.update({s["labels"][label]: s["value"] for s in series})
+    return got
+
+
 def test_sampler_path_counted_by_what_the_seated_rows_ask():
     """ISSUE 37: every decode step is classed on the host by the
     predicate the program's sampler branches on (the costliest seated
@@ -263,11 +272,8 @@ def test_sampler_path_counted_by_what_the_seated_rows_ask():
     eng = InferenceEngine(_tiny_lm(), slots=2, prefill_buckets=(8,))
 
     def counted():
-        series = obs.get_registry().snapshot()["metrics"].get(
-            "serving_sampler_steps_total", {"series": []})["series"]
-        got = dict.fromkeys(SAMPLER_PATHS, 0)
-        got.update({s["labels"]["path"]: s["value"] for s in series})
-        return got
+        return _steps_counted("serving_sampler_steps_total", "path",
+                              SAMPLER_PATHS)
 
     sampled = dict(prompt=[4, 5, 6], max_new_tokens=4, temperature=0.8,
                    seed=3)
@@ -303,6 +309,69 @@ def test_sampler_path_counted_by_what_the_seated_rows_ask():
     spans = [e["args"]["sampler_path"] for e in
              obs.get_tracer().events("decode_step") if e["ph"] == "X"]
     assert {p: spans.count(p) for p in SAMPLER_PATHS} == want
+
+
+def test_decode_read_counted_by_the_share_its_step_was_compiled_for():
+    """ISSUE 39: every decode step is counted under the share of the
+    table that its ragged read was compiled for, by the program's own
+    roundings on the host: 4 slots x 16 blocks in chunks of 2 blocks are
+    32 chunks, read as 2, as 16 or as all. One short request seated is
+    the 1/16 read, two of four seats the half read, four long ones the
+    read of all; counter, `health()` shares and the spans' blocks say
+    the same."""
+    import jax
+
+    from bigdl_tpu.models.transformer import build_lm
+    from bigdl_tpu.ops.kv_cache import READ_SHARE_NAMES
+    from bigdl_tpu.serving import InferenceEngine, Request
+
+    assert READ_SHARE_NAMES == ("1/16", "1/2", "1")
+    obs.set_tracer(obs.SpanTracer(enabled=True))
+    m = build_lm(vocab_size=61, dim=128, num_heads=2, num_layers=1,
+                 max_len=64)
+    m.build(jax.random.PRNGKey(1))
+    eng = InferenceEngine(m, slots=4, block_size=4,
+                          prefill_buckets=(8, 24, 48))
+    assert eng.health()["attn_form"] == "rows"
+    assert eng.health()["read_share_steps"] == dict.fromkeys(
+        READ_SHARE_NAMES, 0.0)
+    rng = np.random.RandomState(2)
+
+    def wave(n, length):
+        return [Request(prompt=[int(t) for t in rng.randint(1, 61, length)],
+                        max_new_tokens=4) for _ in range(n)]
+
+    want = dict.fromkeys(READ_SHARE_NAMES, 0)
+    for read, reqs in (("1/16", wave(1, 3)), ("1/2", wave(2, 20)),
+                       ("1", wave(4, 40))):
+        before = eng.stats["decode_steps"]
+        assert all(r.status == "done" for r in eng.run(reqs))
+        want[read] += eng.stats["decode_steps"] - before
+        assert _steps_counted("serving_decode_read_steps_total", "read",
+                              READ_SHARE_NAMES) == want, read
+    assert all(want.values())
+    total = eng.stats["decode_steps"]
+    assert eng.health()["read_share_steps"] == {
+        r: round(n / total, 4) for r, n in want.items()}
+    spans = [e["args"] for e in obs.get_tracer().events("decode_step")
+             if e["ph"] == "X"]
+    blocks = {"1/16": 4, "1/2": 32, "1": 64}
+    assert {r: sum(a["attended_blocks"] == b for a in spans)
+            for r, b in blocks.items()} == want
+    assert eng.stats["decode_traces"] == 1        # ONE executable
+
+
+def test_the_head_split_form_counts_every_step_as_the_read_of_all():
+    from bigdl_tpu.serving import InferenceEngine, Request
+
+    eng = InferenceEngine(_tiny_lm(), slots=2, prefill_buckets=(8,))
+    assert eng.health()["attn_form"] == "heads"
+    eng.run([Request(prompt=[1, 2, 3], max_new_tokens=3)])
+    steps = eng.stats["decode_steps"]
+    assert steps and _steps_counted(
+        "serving_decode_read_steps_total", "read",
+        ("1/16", "1/2", "1")) == {"1/16": 0, "1/2": 0, "1": steps}
+    assert eng.health()["read_share_steps"]["1"] == 1.0
 
 
 def test_engine_metrics_off_keeps_core_bookkeeping():

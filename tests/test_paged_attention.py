@@ -17,9 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.ops.kv_cache import (attended_blocks, init_block_pool,
-                                    paged_attention, paged_attention_form,
-                                    ragged_read_sizes)
+from bigdl_tpu.ops.kv_cache import (attended_blocks, decode_read,
+                                    init_block_pool, paged_attention,
+                                    paged_attention_form, ragged_read_sizes)
 
 # one (b, h, nb, bs, d) per form for the tests that take `form`
 SHAPE_OF = {"heads": (3, 2, 4, 4, 8), "rows": (3, 2, 4, 4, 64)}
@@ -182,7 +182,8 @@ def test_rejects_a_query_of_more_than_one_row():
 # The rows form reads each slot's own live chunks. A table of 20 blocks
 # of 4 rows is read in chunks of 3 blocks = 12 rows (7 a slot, the last
 # one a block short of whole), six slots: 42 chunks, compiled for 3 of
-# them (a batch that is mostly empty seats) or all 42.
+# them (a batch that is mostly empty seats), for 21 (half: full seats at
+# mixed depths, ISSUE 39) or for all 42.
 RAGGED = (6, 2, 20, 4, 64)
 _FULL = RAGGED[2] * RAGGED[3] - 1
 
@@ -190,7 +191,7 @@ _FULL = RAGGED[2] * RAGGED[3] - 1
 def test_the_ragged_shape_reads_in_chunks_of_three_blocks():
     assert paged_attention_form(RAGGED[1], RAGGED[4]) == "rows"
     assert ragged_read_sizes(RAGGED[0], RAGGED[2]) == (
-        3, (3, 42))
+        3, (3, 21, 42))
 
 
 @pytest.mark.parametrize("pos", [
@@ -252,13 +253,31 @@ def test_a_verify_shaped_call_equals_its_rows_called_one_at_a_time(first):
         np.testing.assert_array_equal(together[j:j + 1], alone)
 
 
+# the five neighbours' clock beside slot 2 at clock 30 (3 chunks): the
+# batch's live chunks are 3, 13, 18, 23 and 38 of 42, so alone it takes
+# the read compiled for 3, beside short or equal neighbours the one for
+# 21, beside longer ones the one for all
+NEIGHBOURS = (-1, 13, 30, 40, _FULL)
+
+
+def test_the_neighbours_clocks_take_all_three_compiled_sizes():
+    table = np.tile(np.arange(1, RAGGED[2] + 1), (RAGGED[0], 1))
+    reads = []
+    for others in NEIGHBOURS:
+        pos = np.full(RAGGED[0], others)
+        pos[2] = 30
+        reads.append(decode_read(np.maximum(pos, 0),
+                                 table * (pos >= 0)[:, None], RAGGED[3]))
+    assert reads == [("1/16", 9), ("1/2", 63), ("1/2", 63), ("1", 120),
+                     ("1", 120)]
+
+
 def test_a_slots_result_does_not_hang_on_the_other_slots_clocks():
-    """Slot 2 at clock 30 (3 chunks) beside empty, short, long and full
-    neighbours: alone it takes the read compiled for 3 chunks, beside
-    any of the others the one for all 42, and slot 2's result is the
-    same bits in all of them."""
+    """Slot 2 at clock 30 beside empty, short, equal, longer and full
+    neighbours: the batch takes each of the three compiled reads, and
+    slot 2's result is the same bits in all of them."""
     results = []
-    for others in (-1, 13, 30, _FULL):  # 3, 13, 18, 38 live chunks
+    for others in NEIGHBOURS:
         pos = [others] * RAGGED[0]
         pos[2] = 30
         args, dense = _case(*RAGGED, pos=pos, tails_to_scratch=True)
@@ -269,20 +288,59 @@ def test_a_slots_result_does_not_hang_on_the_other_slots_clocks():
         np.testing.assert_array_equal(other, results[0])
 
 
+@pytest.mark.parametrize("window", [None, 9])
+def test_a_grouped_slots_result_does_not_hang_on_the_other_slots_clocks(
+        window):
+    """The same pin for the grouped form (4 query heads over 2
+    key-value heads of 32), without and with a lower bound of
+    visibility `lo` (a window of 9 rows that ends at the clock)."""
+    from bigdl_tpu.ops.kv_cache import grouped_paged_attention
+
+    b, g, nb, bs, d = RAGGED[0], 2, RAGGED[2], RAGGED[3], 32
+    (_, k_pool, v_pool, table, _), _ = _case(b, g, nb, bs, d)
+    q = jnp.asarray(np.random.RandomState(5).randn(b, 4, d), jnp.float32)
+    results = []
+    for others in NEIGHBOURS:
+        pos = np.full(b, others, np.int32)
+        pos[2] = 30
+        seated = jnp.asarray(pos >= 0)
+        clocks = jnp.asarray(np.maximum(pos, 0), jnp.int32)
+        lo = None if window is None else clocks - (window - 1)
+        got = np.asarray(grouped_paged_attention(
+            q, k_pool, v_pool, table * seated[:, None], clocks, g,
+            d ** -0.5, lo=lo))
+        assert np.isfinite(got).all() and got[2].any()
+        assert not got[~np.asarray(seated)].any()
+        results.append(got[2])
+    for other in results[1:]:
+        np.testing.assert_array_equal(other, results[0])
+    if window is not None:          # the window is seen, not the prefix
+        whole = np.asarray(grouped_paged_attention(
+            q, k_pool, v_pool, table, jnp.full(b, 30, jnp.int32), g,
+            d ** -0.5))[2]
+        assert np.abs(whole - results[0]).max() > 1e-3
+
+
 @pytest.mark.parametrize("pos,chunks,read,blocks", [
-    # blocks 1, 3, 4, 6, 7, 20 -> chunks 1, 1, 2, 2, 3, 7 = 16: all 42,
-    # whose 126 blocks hold 6 of padding (a seventh chunk is 2 blocks)
-    ([0, 11, 12, 23, 24, _FULL], 16, 42, 120),
+    # blocks 1, 3, 4, 6, 7, 20 -> chunks 1, 1, 2, 2, 3, 7 = 16: half, 21
+    ([0, 11, 12, 23, 24, _FULL], 16, 21, 63),
+    # six full slots: all 42, whose 126 blocks hold 6 of padding (a
+    # seventh chunk is 2 blocks): never more than the table's 120
+    ([_FULL] * 6, 42, 42, 120),
     # two seated: blocks 2 and 4 -> chunks 1 + 2 = 3, the small read
     ([5, -1, 13, -1, -1, -1], 3, 3, 9),
-    # two seated: blocks 2 and 11 -> chunks 1 + 4 = 5, read as all 42
-    ([5, -1, 40, -1, -1, -1], 5, 42, 120),
-    # blocks 9, 1, 18, 4, 12, 16 -> chunks 3, 1, 6, 2, 4, 6 = 22, read as 42
+    # two seated: blocks 2 and 11 -> chunks 1 + 4 = 5, read as half: 21
+    ([5, -1, 40, -1, -1, -1], 5, 21, 63),
+    # blocks 9, 1, 18, 4, 12, 16 -> chunks 3, 1, 6, 2, 4, 6 = 22, one over
+    # half: read as all 42
     ([35, 2, 70, 13, 47, 60], 22, 42, 120),
+    # the same but slot 4 a chunk shorter (blocks 9): 21, the half read whole
+    ([35, 2, 70, 13, 35, 60], 21, 21, 63),
     # one seated slot in its first block: 1 chunk, read as 3
     ([-1, -1, 2, -1, -1, -1], 1, 3, 9),
-], ids=["chunk-edges", "two-seated-short", "two-seated", "mixed",
-        "one-seated"])
+], ids=["chunk-edges", "full-table", "two-seated-short", "two-seated",
+        "mixed-22",
+        "mixed-21", "one-seated"])
 def test_attended_blocks_is_the_count_made_by_hand(pos, chunks, read,
                                                    blocks):
     """What the engine hangs on its `decode_step` span: live chunks
@@ -298,3 +356,17 @@ def test_attended_blocks_is_the_count_made_by_hand(pos, chunks, read,
     assert read in sizes
     assert attended_blocks(np.asarray(clocks), np.asarray(table), bs) \
         == min(read * chunk_blocks, b * nb) == blocks
+
+
+@pytest.mark.parametrize("pos,want", [
+    ([0, -1], ("1/2", 1)),          # 1 chunk of 2: sizes (1, 1, 2)
+    ([0, 3], ("1", 2)),
+    ([-1, -1], ("1/2", 1)),         # nobody seated: the smallest read
+], ids=["one-of-two", "both", "none"])
+def test_a_small_tables_read_goes_by_the_larger_shares_name(pos, want):
+    """Two slots of one block: a sixteenth and a half of 2 chunks both
+    round up to 1, and a read of 1 chunk IS half of the table."""
+    pos = np.asarray(pos)
+    table = np.array([[1], [2]]) * (pos >= 0)[:, None]
+    assert ragged_read_sizes(2, 1) == (1, (1, 2))
+    assert decode_read(np.maximum(pos, 0), table, 4) == want

@@ -165,12 +165,15 @@ def test_engine_at_tile_widths_serves_warm_equal_cold_in_the_rows_form(
     assert warm.tokens == cold.tokens
     # how far the ragged read engaged (ISSUE 32): every `decode_step`
     # span says what its read gathered of the 2 x 16 table, in chunks of
-    # 2 blocks: reads of 1 chunk or of all 16. One slot of 13 + 6
-    # tokens reaches into 3 chunks, two slots into 5 at most
+    # 2 blocks: reads of 1 chunk, of half (8, ISSUE 39) or of all 16.
+    # One slot of 13 + 6 tokens reaches into 3 chunks, two slots into 5
+    # at most: the half read
     steps = [e["args"] for e in obs.get_tracer().events("decode_step")
              if e["ph"] == "X"]
     assert steps and {a["table_blocks"] for a in steps} == {32}
-    assert {a["attended_blocks"] for a in steps} <= {2, 32}
+    assert {a["attended_blocks"] for a in steps} <= {2, 16, 32}
+    assert 16 in {a["attended_blocks"] for a in steps}
+    assert eng.health()["read_share_steps"]["1"] == 0.0
     assert eng.health()["attended_share"] == 2 / 32    # nobody seated
     assert stranger.tokens == engine().run([Request(**S)])[0].tokens
     rounds = [e for e in obs.get_tracer().events("round") if e["ph"] == "X"]

@@ -1,9 +1,16 @@
 """The lowered text of the three older served families' decode and prefill
-programs at toy sizes, pinned by its sha256 as taken on the parent commit
-(ac7b450): the sigmoid router's path through `DroplessMoE.forward`, the
-extraction of `grouped_prompt_attention` from `WindowMoELM` and the
-engine's third cache kind ("state", ISSUE 38) left them byte for byte what
-they were.
+programs at toy sizes, pinned by its sha256.
+
+The PREFILL hashes are the parent's parent's (ac7b450) still, letter for
+letter: ISSUE 38 (the sigmoid router's path through `DroplessMoE.forward`,
+`grouped_prompt_attention` cut out of `WindowMoELM`, the engine's third
+cache kind) and ISSUE 39 (a third compiled size of the ragged decode read,
+`ops/kv_cache._READ_SHARES`) left the prefill programs byte for byte what
+they were: they do not call the ragged core. The DECODE hashes of the
+programs that do call it (afmoe, mla_moe, and gpt2 at a width that takes
+the rows form) are taken from ISSUE 39's change, on the parent ad46bad: one
+more branch of the one `lax.switch` a layer. gpt2's toy decode (dim 32:
+the head-split form, no ragged core) is ac7b450's still.
 
 To take the hashes of another checkout (the parent's, say), run this file
 there: `cd <checkout> && PYTHONPATH=. python <this file>` prints them as
@@ -22,12 +29,13 @@ import pytest
 REPO = os.getcwd() if __name__ == "__main__" else os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))
 PARENTS = {
-    ("afmoe", "decode"): "03a1a387f0be2d69",
+    ("afmoe", "decode"): "03580a61d64d741e",
     ("afmoe", "prefill"): "645e88e11c673841",
-    ("mla_moe", "decode"): "6b6fcd9efdf3251b",
+    ("mla_moe", "decode"): "c3fdc25b7d148b5a",
     ("mla_moe", "prefill"): "c208b75ce320454f",
     ("gpt2", "decode"): "4fb2103bb9d60ab3",
     ("gpt2", "prefill"): "cc70aade132de162",
+    ("gpt2_rows", "decode"): "f15800e0430e101b",
 }
 
 
@@ -39,12 +47,13 @@ def _toy(family):
     """(model, params, pools, the prefill's block ids) of a family's toy
     size, as shapes: 3 slots of 64 positions in blocks of 4."""
     i32 = jnp.int32
-    if family == "gpt2":
+    if family in ("gpt2", "gpt2_rows"):
         from bigdl_tpu.models.transformer import build_lm
         from bigdl_tpu.serving import InferenceEngine
 
-        lm = build_lm(vocab_size=61, dim=32, num_heads=2, num_layers=2,
-                      max_len=64)
+        # dim 128 over 2 heads decodes in the rows form (the ragged core)
+        lm = build_lm(vocab_size=61, dim=32 if family == "gpt2" else 128,
+                      num_heads=2, num_layers=2, max_len=64)
         lm.build(jax.random.PRNGKey(0))
         eng = InferenceEngine(lm, slots=3, max_len=64, block_size=4,
                               prefill_buckets=(16, 32))
