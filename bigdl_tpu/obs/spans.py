@@ -33,6 +33,24 @@ off (the default, and every untraced benchmark run) no span site
 touches a device array. `fetch` (inside `decode_step`) is the engine's
 own sampled-token fetch, there with or without the tracer.
 
+Categories: the serving round's tree (`submit`, `router_step`, `round`,
+`admit`, `prefill`, `ensure_blocks`, `decode_step`, `upload`,
+`dispatch`, `fetch`, `emit`, `queued`, `request[<status>]`,
+`first_token`) is category `"serving"`, and that set is closed: the
+benchmark's readers take `program_spans("serving")` and sum the self
+times of a fixed tuple of names, so a new child of `admit` in that
+category would take its time OUT of `*_round_host_share`, and one that
+wrapped `prefill` would put the prefill's time INTO it. Detail spans
+that split a span of the tree go in a category of their own: the
+parts of an admission (`queue_expire`, `queue_pop`, `seat_prepare`,
+`seat_commit`: leaves, children of `admit`, siblings of `prefill`;
+a `request[expired]` that the expiry records names `queue_expire` as
+the span open when it ENDED, and began long before it)
+are category `"serving.admit"`. The tree's readers never see them; a
+reader that wants them asks for every category, and the benchmark's
+idle-gap attribution, which is handed every complete span whatever its
+category, names the gaps under `admit` by them.
+
 The tracer is OFF by default (`enabled=False` → `span()` is a shared
 no-op context manager, one attribute test per site); drills and
 profiling sessions turn it on. Both the clock and the buffer are
@@ -103,6 +121,12 @@ class _Span:
             self.args = args
         else:
             self.args.update(args)
+
+    def elapsed(self) -> float:
+        """Seconds since the span was entered, on its own clock: a
+        reading taken INSIDE the span, for an arg that splits it
+        (`prefill`'s `launched_s`)."""
+        return self._clock() - self._t0
 
     def __enter__(self):
         stack = self.tracer._stack()

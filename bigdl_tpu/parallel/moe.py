@@ -343,11 +343,13 @@ def expert_load_report(aux, skip_column: bool = False):
     """From one decode step's fetched `aux` (MoE layers, E), the tokens
     each expert got (`DroplessMoE.forward`'s second result, stacked by
     the model): the args the serving engine hangs on its `decode_step`
-    span, and the engine counters to bump. What a model's
-    `decode_aux_report` returns. With `skip_column` the aux is (MoE
-    layers, E + 1), its last column the rows that went to no expert
-    (a routing the model decided): `skipped_rows` a layer, and
-    `routed_rows`, all the rows the layers' routers placed."""
+    span, what a model's `decode_aux_report` returns.
+    `moe_assignments` is the rows that reached an expert, summed over
+    the layers (the `prefill` span's word for the same count). With
+    `skip_column` the aux is (MoE layers, E + 1), its last column the
+    rows that went to no expert (a routing the model decided):
+    `skipped_rows` a layer, and `routed_rows`, all the rows the
+    layers' routers placed."""
     import numpy as np
 
     aux = np.asarray(aux)
@@ -357,10 +359,10 @@ def expert_load_report(aux, skip_column: bool = False):
                 "routed_rows": int(aux.sum())}
         aux = aux[:, :-1]
     mean = np.maximum(aux.mean(axis=1), 1e-9)
-    return ({"experts_touched": [int(n) for n in (aux > 0).sum(1)],
-             "expert_load_max_over_mean": [
-                 float(v) for v in aux.max(axis=1) / mean], **args},
-            {"moe_tokens_routed": int(aux.sum())})
+    return {"experts_touched": [int(n) for n in (aux > 0).sum(1)],
+            "expert_load_max_over_mean": [
+                float(v) for v in aux.max(axis=1) / mean],
+            "moe_assignments": int(aux.sum()), **args}
 
 
 def gated_ffn(x, w_gate, w_up, w_down):

@@ -189,6 +189,11 @@ _ENGINE_IDS = itertools.count()
 # engine snapshots them at creation and reports its own deltas
 _TRACES = {"prefill": 0, "decode": 0}
 
+# the category of the leaves that split `admit` (obs/spans.py: the
+# "serving" tree is a closed set of names that the benchmark's readers
+# sum by name; these are seen only by who asks for every category)
+_ADMIT_PARTS = "serving.admit"
+
 
 class OverloadError(RuntimeError):
     """submit() under overload_policy='reject' with a full queue."""
@@ -679,7 +684,7 @@ class InferenceEngine:
             "kv_spill_blocks": 0, "kv_readmit_blocks": 0,
             "kv_host_evictions": 0, "admit_requeue_exhausted": 0,
             "handoffs_out": 0, "handoffs_in": 0,
-            "weight_swaps": 0, "moe_tokens_routed": 0,
+            "weight_swaps": 0,
         }
         # ---- telemetry plane (ISSUE 5): every _stats increment also
         # mirrors into the process-wide registry under this engine's
@@ -734,9 +739,6 @@ class InferenceEngine:
                            "prefill tier",
             "weight_swaps": "weight hot-swaps re-placed into the live "
                             "serving layout (ISSUE 18)",
-            "moe_tokens_routed": "expert assignments of recorded decode "
-                                 "steps (a model's decode aux, fetched "
-                                 "only while the tracer is enabled)",
         }
         self._m_ops = {
             key: reg.counter(f"serving_{key}_total", help_,
@@ -1231,13 +1233,15 @@ class InferenceEngine:
             out["tenant"] = tenant
         return out
 
-    def _span(self, name: str, parent: Optional[int] = None):
+    def _span(self, name: str, parent: Optional[int] = None,
+              cat: str = "serving"):
         """One span of the scheduling round's tree (obs/spans.py): the
         shared no-op unless the tracer is enabled. A site that has
         counts for it tests `span.id is not None` first and hands them
-        to `span.set`, so that an untraced round builds nothing."""
-        return obs.get_tracer().span(name, "serving",
-                                     clock=self._span_clock,
+        to `span.set`, so that an untraced round builds nothing. `cat`
+        is "serving" for the tree itself, a closed set of names, and
+        `_ADMIT_PARTS` for the leaves that split `admit`."""
+        return obs.get_tracer().span(name, cat, clock=self._span_clock,
                                      parent=parent)
 
     def _bump(self, key: str, n: int = 1) -> None:
@@ -1505,8 +1509,19 @@ class InferenceEngine:
         return True
 
     def _admit(self):
-        with self._span("admit"):
-            self._expire_queued(self._clock())
+        with self._span("admit") as span:
+            # what a recorded `admit` counts: the queue's length on
+            # entry, the entries its walks ran over (the expiry's
+            # rebuild and every pop's scan take the whole queue), the
+            # requests it seated
+            queued = scanned = len(self._queue) if span.id is not None \
+                else 0
+            admitted = 0
+            with self._span("queue_expire", cat=_ADMIT_PARTS) as part:
+                self._expire_queued(self._clock())
+                if part.id is not None:
+                    part.set(queued=queued,
+                             expired=queued - len(self._queue))
             # quota-exceeded requests are set ASIDE and restored to the
             # queue front afterwards (order preserved) — a blocked tenant
             # must never head-of-line-block the other tenants' admissions
@@ -1514,11 +1529,20 @@ class InferenceEngine:
             try:
                 for slot in self._free_slots():
                     while self._queue:
-                        req = self._pop_next()
-                        if self._quota_blocked(req):
+                        with self._span("queue_pop",
+                                        cat=_ADMIT_PARTS) as part:
+                            if part.id is not None:
+                                scanned += len(self._queue)
+                                part.set(queued=len(self._queue))
+                            req = self._pop_next()
+                            blocked = self._quota_blocked(req)
+                            if part.id is not None:
+                                part.set(request=req.id)
+                        if blocked:
                             quota_skipped.append(req)
                             continue
                         if self._admit_into(slot, req):
+                            admitted += 1
                             self._admit_fails.pop(req.id, None)
                             self._quota_noted.discard(req.id)
                             break
@@ -1543,6 +1567,9 @@ class InferenceEngine:
             finally:
                 for r in reversed(quota_skipped):
                     self._queue.appendleft(r)
+                if span.id is not None:
+                    span.set(queued=queued, scanned=scanned,
+                             admitted=admitted)
 
     def _point_table_row(self, slot: int, hit: List[int],
                          new: List[int]) -> np.ndarray:
@@ -1590,9 +1617,14 @@ class InferenceEngine:
         self._topk[slot] = req.top_k
         self._topp[slot] = req.top_p
 
-    def _admit_into(self, slot: int, req: Request) -> bool:
-        """Prefix lookup + block allocation + suffix prefill into
-        `slot`. False = insufficient pool blocks (caller requeues)."""
+    def _prepare_seat(self, slot: int, req: Request, span):
+        """What an admission does on the host before its prefill is
+        launched: prefix lookup, block allocation, the slot's table
+        row, the padded suffix and the placement of the prefill's
+        destinations. Returns what `_admit_into` hands to the prefill
+        and the seating, or None = insufficient pool blocks. `span` is
+        the `seat_prepare` span around the call: a seating that went
+        through leaves its counts on it."""
         prompt = list(req.prompt)
         n = len(prompt)
         bs = self.block_size
@@ -1620,31 +1652,54 @@ class InferenceEngine:
         # game to it) — re-admitting any host-tier links on the way
         hit = self._readmit_chain(nodes)
         if hit is None:
-            return False
+            return None
         new = self._alloc_blocks(nb_new)
         if new is None:
             self._pool_mgr.unref(hit)         # back to cached parking
-            return False
+            return None
         row = self._point_table_row(slot, hit, new)
         toks = pad_tokens(suffix, b)[None, :]          # (1, bucket)
         # int32 on the host: jnp.asarray(list, dtype=) dispatches a
         # jit(convert_element_type) program of its own, one more
         # launch an admission
-        block_ids = jnp.asarray(np.asarray(new, np.int32))
+        ids = np.asarray(new, np.int32)
+        placed_bytes = ids.nbytes
+        block_ids = jnp.asarray(ids)
         if self._ring_blocks:
             # a model with rings takes its destinations by cache kind:
             # the fresh table blocks, and for the slot's rings the
             # prompt's block that each ring block takes
+            sources = ring_prompt_sources(n, bs, self._ring_blocks)
+            placed_bytes += sources.nbytes
             block_ids = {"table": block_ids, "ring": {
                 "slot": np.int32(slot),
-                "sources": jnp.asarray(ring_prompt_sources(
-                    n, bs, self._ring_blocks))}}
+                "sources": jnp.asarray(sources)}}
         elif self._slot_state_bytes:
             # a model with a state: the slot, and the position whose
             # rows it keeps, the last before the token that the first
             # decode step re-decodes (-1: a prompt of one token)
             block_ids = {"table": block_ids, "state": {
                 "slot": np.int32(slot), "keep": np.int32(n - 2)}}
+        if span.id is not None:
+            span.set(prefix_tokens=int(start), new_blocks=len(new),
+                     placed_bytes=int(placed_bytes))
+        return start, hit, new, row, toks, b, block_ids
+
+    def _admit_into(self, slot: int, req: Request) -> bool:
+        """Prefix lookup + block allocation + suffix prefill into
+        `slot`. False = insufficient pool blocks (caller requeues)."""
+        with self._span("seat_prepare", cat=_ADMIT_PARTS) as part:
+            evicted = self._stats["pool_evictions"]
+            seat = self._prepare_seat(slot, req, part)
+            if part.id is not None:
+                # a failed seating says these two all the same: what it
+                # evicted before it gave up is gone from the cache
+                part.set(request=req.id, evicted_blocks=self._stats[
+                    "pool_evictions"] - evicted)
+        if seat is None:
+            return False
+        start, hit, new, row, toks, b, block_ids = seat
+        n = len(req.prompt)
         tracer = obs.get_tracer()
         t_admit = self._clock()
         if tracer.enabled:
@@ -1667,27 +1722,35 @@ class InferenceEngine:
                 # THE one span that waits for the device, and only
                 # while it is being recorded (obs/spans.py): unfenced
                 # it times the dispatch and the prefill program lands
-                # in the next decode_step. Tracer off: never reached
+                # in the next decode_step. Tracer off: never reached.
+                # `launched_s` is read before the wait: the two
+                # placements and the call, which an untraced admission
+                # pays too
+                launched_s = span.elapsed()
                 jax.block_until_ready(self.pool)  # graftlint: disable=hidden-device-sync
                 span.set(request=req.id, slot=slot, bucket=int(b),
                          prefix_tokens=int(start), fenced=True,
+                         launched_s=launched_s,
                          **self._prefill_span_args(int(b)))
-        self._bump("prefill_calls")
-        if start:
-            self._bump("prefix_hits")
-            self._bump("prefix_blocks_reused", len(hit))
-            self._bump("prefix_tokens_saved", start)
-            self._bump("prefix_bytes_saved",
-                       start * self._kv_bytes_per_token)
-            obs.emit_event("prefix_hit", plane="serving",
-                           engine=self._obs_name, request=req.id,
-                           matched_tokens=start, blocks=len(hit),
-                           prompt_len=n, **self._trace_fields(req))
-        self._update_pool_gauge()
-        self._seat_slot(slot, req, hit, new)
-        if self._round_log is not None:
-            self._round_log["admitted"].append(req.id)
-        return True
+        with self._span("seat_commit", cat=_ADMIT_PARTS) as part:
+            if part.id is not None:
+                part.set(request=req.id)
+            self._bump("prefill_calls")
+            if start:
+                self._bump("prefix_hits")
+                self._bump("prefix_blocks_reused", len(hit))
+                self._bump("prefix_tokens_saved", start)
+                self._bump("prefix_bytes_saved",
+                           start * self._kv_bytes_per_token)
+                obs.emit_event("prefix_hit", plane="serving",
+                               engine=self._obs_name, request=req.id,
+                               matched_tokens=start, blocks=len(hit),
+                               prompt_len=n, **self._trace_fields(req))
+            self._update_pool_gauge()
+            self._seat_slot(slot, req, hit, new)
+            if self._round_log is not None:
+                self._round_log["admitted"].append(req.id)
+            return True
 
     def _prefill_span_args(self, bucket: int) -> dict:
         """What the model adds to a recorded `prefill` span."""
@@ -1945,15 +2008,13 @@ class InferenceEngine:
 
     def _report_aux(self, span) -> None:
         """What a recorded step's `aux` says, in the model's words
-        (`decode_aux_report`), onto the `decode_step` span and into the
-        engine's counters."""
+        (`decode_aux_report`), onto the `decode_step` span. Nothing of
+        it goes into a counter: the aux is fetched only while the
+        tracer records, and a counter that counts only then reads 0 on
+        a production engine's scrape."""
         aux, self._aux = self._aux, None
-        if aux is None:
-            return
-        span_args, counters = self.model.decode_aux_report(aux)
-        span.set(**span_args)
-        for key, n in counters.items():
-            self._bump(key, n)
+        if aux is not None:
+            span.set(**self.model.decode_aux_report(aux))
 
     def _ensure_blocks(self, horizons=None, exhaust: str = "finish"
                        ) -> Optional[List[GenerationResult]]:
@@ -2315,7 +2376,15 @@ class InferenceEngine:
             return self._step_prefill()
         # one span tree per scheduling round (obs/spans.py; PERF.md §3
         # has the table): round > admit > prefill, ensure_blocks,
-        # decode_step > upload / dispatch / fetch, emit
+        # decode_step > upload / dispatch / fetch, emit. That tree is
+        # category "serving" and CLOSED: the benchmark's readers take
+        # that category and sum self times by a fixed tuple of names,
+        # so a new child of `admit` in it would silently leave
+        # *_round_host_share. What an admission is made of
+        # (queue_expire, queue_pop, seat_prepare, seat_commit: leaves
+        # under `admit`, beside `prefill`) is category _ADMIT_PARTS,
+        # seen by who asks for every category and by the idle-gap
+        # attribution
         with self._span("round") as span:
             if span.id is None:
                 return self._round()

@@ -91,11 +91,13 @@ def test_its_own_routing_handed_back_is_its_own_forward():
 
 def test_the_report_of_a_step_with_a_skip_column():
     aux = np.array([[3, 0, 1, 0, 2], [0, 0, 0, 4, 2]])
-    args, counters = expert_load_report(aux, skip_column=True)
+    args = expert_load_report(aux, skip_column=True)
     assert args == {"experts_touched": [2, 1],
                     "expert_load_max_over_mean": [3.0, 4.0],
+                    "moe_assignments": 8,
                     "skipped_rows": [2, 2], "routed_rows": 12}
-    assert counters == {"moe_tokens_routed": 8}
-    plain, _ = expert_load_report(aux)
-    assert set(plain) == {"experts_touched", "expert_load_max_over_mean"}
+    plain = expert_load_report(aux)
+    assert set(plain) == {"experts_touched", "expert_load_max_over_mean",
+                          "moe_assignments"}
+    assert plain["moe_assignments"] == 12
     assert plain["experts_touched"] == [3, 2]
