@@ -1620,11 +1620,12 @@ class InferenceEngine:
     def _prepare_seat(self, slot: int, req: Request, span):
         """What an admission does on the host before its prefill is
         launched: prefix lookup, block allocation, the slot's table
-        row, the padded suffix and the placement of the prefill's
-        destinations. Returns what `_admit_into` hands to the prefill
-        and the seating, or None = insufficient pool blocks. `span` is
-        the `seat_prepare` span around the call: a seating that went
-        through leaves its counts on it."""
+        row, the padded suffix and the prefill's destinations, all
+        host arrays that `_prefill_step`'s call places. Returns what
+        `_admit_into` hands to the prefill and the seating, or None =
+        insufficient pool blocks. `span` is the `seat_prepare` span
+        around the call: a seating that went through leaves its counts
+        on it."""
         prompt = list(req.prompt)
         n = len(prompt)
         bs = self.block_size
@@ -1657,14 +1658,18 @@ class InferenceEngine:
         if new is None:
             self._pool_mgr.unref(hit)         # back to cached parking
             return None
-        row = self._point_table_row(slot, hit, new)
+        # the prefill's host operands stay NumPy, int32 made on the
+        # host: `_prefill_step`'s call places them (as the decode
+        # round's, `_dispatch_and_fetch`), and `jnp.asarray(list,
+        # dtype=)` would launch a jit(convert_element_type) besides.
+        # The row is a COPY: the call may read a NumPy operand after
+        # it returns (the CPU backend does), and `_ensure_blocks` can
+        # extend this slot's row of `_table` before the round's fetch
+        # fences it
+        row = self._point_table_row(slot, hit, new)[None, :].copy()
         toks = pad_tokens(suffix, b)[None, :]          # (1, bucket)
-        # int32 on the host: jnp.asarray(list, dtype=) dispatches a
-        # jit(convert_element_type) program of its own, one more
-        # launch an admission
-        ids = np.asarray(new, np.int32)
-        placed_bytes = ids.nbytes
-        block_ids = jnp.asarray(ids)
+        block_ids = np.asarray(new, np.int32)
+        placed_bytes = block_ids.nbytes
         if self._ring_blocks:
             # a model with rings takes its destinations by cache kind:
             # the fresh table blocks, and for the slot's rings the
@@ -1672,8 +1677,7 @@ class InferenceEngine:
             sources = ring_prompt_sources(n, bs, self._ring_blocks)
             placed_bytes += sources.nbytes
             block_ids = {"table": block_ids, "ring": {
-                "slot": np.int32(slot),
-                "sources": jnp.asarray(sources)}}
+                "slot": np.int32(slot), "sources": sources}}
         elif self._slot_state_bytes:
             # a model with a state: the slot, and the position whose
             # rows it keeps, the last before the token that the first
@@ -1716,16 +1720,15 @@ class InferenceEngine:
                     category=UserWarning)
                 self.pool = _prefill_step(
                     self.model, self._params, self.pool,
-                    jnp.asarray(toks), np.int32(start), block_ids,
-                    jnp.asarray(row[None, :]))
+                    toks, np.int32(start), block_ids, row)
             if span.id is not None:
                 # THE one span that waits for the device, and only
                 # while it is being recorded (obs/spans.py): unfenced
                 # it times the dispatch and the prefill program lands
                 # in the next decode_step. Tracer off: never reached.
-                # `launched_s` is read before the wait: the two
-                # placements and the call, which an untraced admission
-                # pays too
+                # `launched_s` is read before the wait: the call,
+                # which places its host operands and which an untraced
+                # admission pays too
                 launched_s = span.elapsed()
                 jax.block_until_ready(self.pool)  # graftlint: disable=hidden-device-sync
                 span.set(request=req.id, slot=slot, bucket=int(b),
@@ -1974,11 +1977,18 @@ class InferenceEngine:
                 # dispatch is beyond this guard — that is the failure
                 # mode the watchdog exists to convert.
                 return None
+            # the nine host operands go into the call as the NumPy
+            # arrays they are: its own argument path places one for
+            # 0.11 ms on the chip's host, a `jnp.asarray` each costs
+            # 0.25 whatever the bytes (PERF.md §6, PR 41). The call may
+            # still read them after it returns (the CPU backend does):
+            # nothing writes them before the fetch below has fenced
+            # the step. `upload` keeps its place and its `bytes` for
+            # the span tree's readers; the transfer is in `dispatch`
             host = (self._tok, self._pos, self._seed, self._nout,
                     self._temp, self._topk, self._topp, poison,
                     self._table)
             with self._span("upload", parent) as span:
-                args = [jnp.asarray(a) for a in host]
                 if span.id is not None:
                     span.set(bytes=sum(a.nbytes for a in host))
             with self._span("dispatch", parent), \
@@ -1986,7 +1996,7 @@ class InferenceEngine:
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat", category=UserWarning)
                 nxt, finite, pools, aux = _decode_step(
-                    self.model, self._params, self.pool, *args)
+                    self.model, self._params, self.pool, *host)
             # THE one deliberate per-step device→host fetch: the host
             # needs the token, so the fetch doubles as the fence for
             # the decode dispatch, inside the watchdog budget above.

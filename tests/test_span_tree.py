@@ -154,12 +154,18 @@ def test_every_round_has_one_decode_step_and_ordered_children(served):
 
 
 def test_decode_step_holds_upload_dispatch_fetch(served):
+    eng, _ = served
+    # the step's nine host operands: seven (slots,) vectors of 4 B an
+    # entry, the bool `poison` and the block table. `upload` says their
+    # bytes though the call places them (PR 41: the transfer is inside
+    # `dispatch`)
+    nine_nbytes = eng.slots * (7 * 4 + 1) + eng._table.nbytes
     for d in _spans("decode_step"):
         kids = sorted(_children(d), key=lambda e: e["ts"])
         assert [k["name"] for k in kids] == ["upload", "dispatch", "fetch"]
         for a, b in zip(kids, kids[1:]):
             assert _end(a) <= b["ts"]
-        assert kids[0]["args"]["bytes"] > 0
+        assert kids[0]["args"]["bytes"] == nine_nbytes
         assert d["args"]["active"] >= 1
 
 
