@@ -536,7 +536,8 @@ class CCAMoELM(Module):
     def prefill_span_args(self, bucket: int) -> dict:
         return {"moe_assignments": bucket}      # one expert a token
 
-    def slot_state_bytes(self) -> int:
+    def slot_state_bytes(self, cache_dtype=None) -> int:
         """What ONE seated slot keeps in the "state" entries, all
-        layers: the engine's `serving_slot_state_bytes` gauge."""
+        layers: the engine's `serving_slot_state_bytes` gauge. Float32
+        whatever `cache_dtype` (the engine's) is."""
         return 4 * self.state_width * len(self.cfg.layers)

@@ -630,7 +630,9 @@ class InferenceEngine:
         self._ring_blocks = model.ring_blocks(block_size) \
             if self._cache_kinds and "ring" in self._cache_kinds else 0
         # bytes ONE seated slot keeps in the model's "state" entries
-        self._slot_state_bytes = model.slot_state_bytes() \
+        # (a leaf of one may follow the cache's dtype:
+        # models/hybrid_ssm.py)
+        self._slot_state_bytes = model.slot_state_bytes(cache_dtype) \
             if self._cache_kinds and "state" in self._cache_kinds else 0
         self.pool = model.init_block_pool(
             pool_blocks, block_size, cache_dtype,
@@ -815,6 +817,18 @@ class InferenceEngine:
             "serving_slot_state_bytes",
             "bytes the seated slots keep in the model's per-slot state "
             "entries (cache kind 'state': no position axis)",
+            labelnames=("engine",)).labels(engine=self._obs_name)
+        # chunks the model's scan runs over a prompt, for each bucket (a
+        # model whose `prefill_span_args` names them: models/
+        # hybrid_ssm.py; no other has any): counted once an admission,
+        # whether or not the tracer records
+        self._scan_chunks = {
+            b: self._prefill_span_args(b).get("scan_chunks", 0)
+            for b in self.buckets}
+        self._m_scan_chunks = reg.counter(
+            "serving_prefill_scan_chunks_total",
+            "chunks of the model's state-space scan that admitted "
+            "prompts were prefilled in, a layer",
             labelnames=("engine",)).labels(engine=self._obs_name)
         self._m_tp_gauge = reg.gauge(
             "serving_tp_shards",
@@ -1739,6 +1753,8 @@ class InferenceEngine:
             if part.id is not None:
                 part.set(request=req.id)
             self._bump("prefill_calls")
+            if self._scan_chunks[b] and obs.enabled():
+                self._m_scan_chunks.inc(self._scan_chunks[b])
             if start:
                 self._bump("prefix_hits")
                 self._bump("prefix_blocks_reused", len(hit))
@@ -2421,11 +2437,15 @@ class InferenceEngine:
     def _decode_read_report(self) -> dict:
         """What the model adds to a recorded `decode_step` span about
         the step's read of the cache (models/window_moe.py: the rows
-        visible and gathered, by cache kind)."""
+        visible and gathered, by cache kind), and what the step reads
+        of the seated slots' state and writes again (`state_bytes`,
+        where the model keeps one)."""
         report = getattr(self.model, "decode_read_report", None)
-        if report is None:
-            return {}
-        return report(self._pos, self._table, self.block_size)
+        out = {} if report is None else report(
+            self._pos, self._table, self.block_size)
+        if self._slot_state_bytes:
+            out["state_bytes"] = self._slot_state_held()
+        return out
 
     def _round(self) -> List[GenerationResult]:
         self._admit()
