@@ -83,7 +83,10 @@ serving):
   with status ``shed``), or ``shed-lowest-priority`` (evict the
   lowest-`Request.priority` queued request — or the new request itself
   if it is lowest). Admission into free slots is highest-priority
-  first, FIFO within a priority.
+  first, FIFO within a priority. None of it walks the queue
+  (serving/request_queue.py, ISSUE 46): the next request, the expired
+  ones, the victim to shed and the ids in flight come out of an index,
+  at a cost that does not go by the queue's depth.
 * **Poison isolation.** The decode step returns a (B,) finite-logits
   health operand (utils/anomaly.rows_finite — one jit-side reduction,
   fetched alongside the token, no extra host sync). A NaN/inf row
@@ -146,7 +149,6 @@ import math
 import threading
 import time
 import warnings
-from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -161,6 +163,7 @@ from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
 from bigdl_tpu.serving.prefix_cache import RadixPrefixCache
+from bigdl_tpu.serving.request_queue import RequestQueue
 from bigdl_tpu.serving.sampler import (SAMPLER_PATHS, sample_logits,
                                        step_path)
 from bigdl_tpu.utils import faults
@@ -682,7 +685,7 @@ class InferenceEngine:
             "watchdog_trips": 0, "cancelled": 0,
             "prefix_hits": 0, "prefix_blocks_reused": 0,
             "prefix_tokens_saved": 0, "prefix_bytes_saved": 0,
-            "pool_evictions": 0,
+            "pool_evictions": 0, "pool_eviction_visits": 0,
             "kv_spill_blocks": 0, "kv_readmit_blocks": 0,
             "kv_host_evictions": 0, "admit_requeue_exhausted": 0,
             "handoffs_out": 0, "handoffs_in": 0,
@@ -727,6 +730,10 @@ class InferenceEngine:
                                   "prefix hits",
             "pool_evictions": "LRU prefix blocks evicted under pool "
                               "pressure",
+            "pool_eviction_visits": "entries of the prefix tree's LRU "
+                                    "index examined while choosing "
+                                    "eviction victims (about one an "
+                                    "eviction)",
             "kv_spill_blocks": "refcount-0 KV blocks spilled to the "
                                "host-RAM tier",
             "kv_readmit_blocks": "host-tier KV blocks re-admitted to "
@@ -840,7 +847,7 @@ class InferenceEngine:
         # finished results not yet handed back by a run(requests=...)
         # call — retrievable here (results are never silently dropped)
         self.completed: Dict[int, GenerationResult] = {}
-        self._queue: deque = deque()
+        self._queue = RequestQueue()
         self._ids = itertools.count()
         self._req: List[Optional[Request]] = [None] * slots
         self._gen: List[List[int]] = [[] for _ in range(slots)]
@@ -1108,18 +1115,14 @@ class InferenceEngine:
             raise ValueError("max_new_tokens must be >= 1 (the engine "
                              "always samples at least one token)")
         bucket_for(n, self.buckets)      # raises if no bucket fits
-        # duplicate-id guard scans the queue, OCCUPIED SLOTS, and
-        # unclaimed results — a resubmitted in-flight id must never be
+        # duplicate-id guard: a resubmitted in-flight id must never be
         # accepted (it would collide in `completed`)
-        in_flight = {r.id for r in self._queue} \
-            | {r.id for r in self._req if r is not None} \
-            | set(self.completed)
         if request.id is None:
             rid = next(self._ids)
-            while rid in in_flight:      # user-chosen ids may have
+            while self._in_flight(rid):  # user-chosen ids may have
                 rid = next(self._ids)    # claimed counter values
             request.id = rid
-        elif request.id in in_flight:
+        elif self._in_flight(request.id):
             raise ValueError(f"request id {request.id} already in flight "
                              "or completed-unclaimed")
         if request.trace_id is None:
@@ -1142,7 +1145,7 @@ class InferenceEngine:
             if request.id in self.completed:     # new request was shed
                 return request.id
         self._meta[request.id] = {"t": self._clock()}
-        self._queue.append(request)
+        self._queue.append(request, self._expires_at(request))
         obs.emit_event("request_submit", plane="serving",
                        engine=self._obs_name, request=request.id,
                        prompt_len=n, priority=request.priority,
@@ -1165,12 +1168,12 @@ class InferenceEngine:
                 f"queue full ({self.max_queue}); request {request.id} "
                 "rejected (overload_policy='reject')")
         if self.overload_policy == "shed-lowest-priority":
-            victim = min(self._queue, key=lambda r: r.priority)
+            victim = self._queue.lowest()
             if request.priority <= victim.priority:
                 # the new arrival is (joint-)lowest — shed it instead
                 self._terminal(request, "shed", "shed")
                 return
-            self._queue.remove(victim)
+            self._queue.remove(victim.id)
         else:                                     # shed-oldest
             victim = self._queue.popleft()
         self._terminal(victim, "shed", "shed")
@@ -1180,11 +1183,10 @@ class InferenceEngine:
         steps). The result (status 'shed', finish_reason 'cancelled',
         partial tokens if it was decoding) lands in `completed` and is
         returned. KeyError if the id is not queued or in flight."""
-        for r in self._queue:
-            if r.id == request_id:
-                self._queue.remove(r)
-                self._bump("cancelled")
-                return self._terminal(r, "cancelled", "shed")
+        if self._queue.holds(request_id):
+            self._bump("cancelled")
+            return self._terminal(self._queue.remove(request_id),
+                                  "cancelled", "shed")
         for i, r in enumerate(self._req):
             if r is not None and r.id == request_id:
                 self._bump("cancelled")
@@ -1197,7 +1199,7 @@ class InferenceEngine:
         """Give up to `k` queued requests (with their original submit
         stamps) to the fleet router for rebalancing — the ones THIS
         engine's scheduler would serve last (lowest priority; youngest
-        within a priority — the exact inverse of _pop_next), so work
+        within a priority — the exact inverse of pop_next), so work
         moves from the back of a long line to an engine with idle
         capacity. A request that actually moves is restamped by the
         receiving engine's submit (deadline TTLs restart — the
@@ -1206,12 +1208,7 @@ class InferenceEngine:
         extends a TTL. Never touches in-flight slots."""
         out: List[Tuple[Request, float]] = []
         for _ in range(min(k, len(self._queue))):
-            best_i, best_p = 0, None
-            for i, r in enumerate(self._queue):
-                if best_p is None or r.priority <= best_p:
-                    best_i, best_p = i, r.priority
-            req = self._queue[best_i]
-            del self._queue[best_i]
+            req = self._queue.pop_last()
             meta = self._meta.pop(req.id, None)
             out.append((req, meta["t"] if meta else self._clock()))
         return out
@@ -1224,7 +1221,7 @@ class InferenceEngine:
         stamp — a bounced move must not restart the TTL clock."""
         self._meta[request.id] = {"t": self._clock() if t is None
                                  else t}
-        self._queue.append(request)
+        self._queue.append(request, self._expires_at(request))
 
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self._req) if r is None]
@@ -1233,6 +1230,22 @@ class InferenceEngine:
         if req.deadline_s is None or req.id not in self._meta:
             return math.inf
         return self._meta[req.id]["t"] + req.deadline_s
+
+    def _expires_at(self, req: Request) -> float:
+        """When a QUEUED request expires: its deadline or its
+        max-queue-wait TTL, whichever comes first (inf: never). Read
+        once, as the request joins the queue."""
+        if req.max_queue_wait_s is None:
+            return self._deadline_at(req)
+        return min(self._deadline_at(req),
+                   self._meta[req.id]["t"] + req.max_queue_wait_s)
+
+    def _in_flight(self, request_id) -> bool:
+        """Queued, seated, or completed and not yet claimed."""
+        return self._queue.holds(request_id) \
+            or request_id in self.completed \
+            or any(r is not None and r.id == request_id
+                   for r in self._req)
 
     @staticmethod
     def _trace_fields(req: Request) -> Dict[str, object]:
@@ -1327,28 +1340,10 @@ class InferenceEngine:
 
     def _expire_queued(self, now: float) -> None:
         """Drop queued requests whose deadline or max-queue-wait TTL
-        passed — status 'expired', zero tokens."""
-        keep: deque = deque()
-        for r in self._queue:
-            t0 = self._meta[r.id]["t"]
-            dl = self._deadline_at(r)
-            qw = t0 + r.max_queue_wait_s \
-                if r.max_queue_wait_s is not None else math.inf
-            if now >= min(dl, qw):
-                self._terminal(r, "expired", "expired")
-            else:
-                keep.append(r)
-        self._queue = keep
-
-    def _pop_next(self) -> Request:
-        """Highest priority first; FIFO within a priority."""
-        best_i, best_p = 0, None
-        for i, r in enumerate(self._queue):
-            if best_p is None or r.priority > best_p:
-                best_i, best_p = i, r.priority
-        req = self._queue[best_i]
-        del self._queue[best_i]
-        return req
+        passed — status 'expired', zero tokens. One comparison when
+        nothing is due (serving/request_queue.py)."""
+        for r in self._queue.pop_expired(now):
+            self._terminal(r, "expired", "expired")
 
     def _alloc_blocks(self, n: int,
                       protect: frozenset = frozenset()
@@ -1361,6 +1356,7 @@ class InferenceEngine:
         `protect` excludes the chain an in-flight re-admission holds
         from both the spill and host-eviction scans."""
         evicted = 0
+        visits = self._prefix.visits
         while self._pool_mgr.free_count < n:
             if self._spill_blocks(n - self._pool_mgr.free_count,
                                   protect):
@@ -1369,6 +1365,9 @@ class InferenceEngine:
             if b is None:
                 break
             evicted += 1
+        if self._prefix.visits > visits:
+            self._bump("pool_eviction_visits",
+                       self._prefix.visits - visits)
         if evicted:
             self._bump("pool_evictions", evicted)
             obs.emit_event("prefix_evict", plane="serving",
@@ -1528,11 +1527,11 @@ class InferenceEngine:
     def _admit(self):
         with self._span("admit") as span:
             # what a recorded `admit` counts: the queue's length on
-            # entry, the entries its walks ran over (the expiry's
-            # rebuild and every pop's scan take the whole queue), the
-            # requests it seated
-            queued = scanned = len(self._queue) if span.id is not None \
-                else 0
+            # entry, the entries its expiry and its pops examined (the
+            # queue keeps the count: the top of the expiry heap a
+            # round, the head of a line a pop), the requests it seated
+            queued = len(self._queue)
+            examined = self._queue.examined
             admitted = 0
             with self._span("queue_expire", cat=_ADMIT_PARTS) as part:
                 self._expire_queued(self._clock())
@@ -1549,9 +1548,8 @@ class InferenceEngine:
                         with self._span("queue_pop",
                                         cat=_ADMIT_PARTS) as part:
                             if part.id is not None:
-                                scanned += len(self._queue)
                                 part.set(queued=len(self._queue))
-                            req = self._pop_next()
+                            req = self._queue.pop_next()
                             blocked = self._quota_blocked(req)
                             if part.id is not None:
                                 part.set(request=req.id)
@@ -1577,16 +1575,17 @@ class InferenceEngine:
                             self._terminal(req, "pool_exhausted", "done")
                             continue          # try the next queued request
                         self._admit_fails[req.id] = fails
-                        self._queue.appendleft(req)
+                        self._queue.appendleft(req,
+                                               self._expires_at(req))
                         return
                     if not self._queue:
                         return
             finally:
                 for r in reversed(quota_skipped):
-                    self._queue.appendleft(r)
+                    self._queue.appendleft(r, self._expires_at(r))
                 if span.id is not None:
-                    span.set(queued=queued, scanned=scanned,
-                             admitted=admitted)
+                    span.set(queued=queued, admitted=admitted,
+                             scanned=self._queue.examined - examined)
 
     def _point_table_row(self, slot: int, hit: List[int],
                          new: List[int]) -> np.ndarray:
@@ -1710,13 +1709,14 @@ class InferenceEngine:
         """Prefix lookup + block allocation + suffix prefill into
         `slot`. False = insufficient pool blocks (caller requeues)."""
         with self._span("seat_prepare", cat=_ADMIT_PARTS) as part:
-            evicted = self._stats["pool_evictions"]
+            before = self._eviction_counts() \
+                if part.id is not None else None
             seat = self._prepare_seat(slot, req, part)
-            if part.id is not None:
-                # a failed seating says these two all the same: what it
+            if before is not None:
+                # a failed seating says these all the same: what it
                 # evicted before it gave up is gone from the cache
-                part.set(request=req.id, evicted_blocks=self._stats[
-                    "pool_evictions"] - evicted)
+                part.set(request=req.id,
+                         **self._eviction_counts(before))
         if seat is None:
             return False
         start, hit, new, row, toks, b, block_ids = seat
@@ -1774,6 +1774,16 @@ class InferenceEngine:
             if self._round_log is not None:
                 self._round_log["admitted"].append(req.id)
             return True
+
+    def _eviction_counts(self, before: Optional[dict] = None) -> dict:
+        """`evicted_blocks` and `evict_visits` (the LRU index's entries
+        examined to choose them) since `before`, an earlier reading of
+        this: what `seat_prepare` and `ensure_blocks` say of the
+        evictions their allocations made."""
+        now = {"evicted_blocks": self._stats["pool_evictions"],
+               "evict_visits": self._stats["pool_eviction_visits"]}
+        return now if before is None else {
+            k: v - before[k] for k, v in now.items()}
 
     def _expert_matmul(self, tokens: int) -> dict:
         """`expert_matmul`: the form the model's grouped expert matmuls
@@ -2079,26 +2089,33 @@ class InferenceEngine:
         shadow mirror must never emit a request_terminal (the quiesce
         contract); blocks already granted stay registered on their
         slots and release with them."""
-        with self._span("ensure_blocks"):
-            done: List[GenerationResult] = []
-            for i, req in enumerate(self._req):
-                if req is None:
-                    continue
-                h = 0 if horizons is None else int(horizons[i])
-                lo = int(self._pos[i]) // self.block_size
-                hi = (int(self._pos[i]) + h) // self.block_size
-                for bi in range(lo, hi + 1):
-                    if self._table[i, bi] != 0:
+        with self._span("ensure_blocks") as span:
+            before = self._eviction_counts() \
+                if span.id is not None else None
+            try:
+                done: List[GenerationResult] = []
+                for i, req in enumerate(self._req):
+                    if req is None:
                         continue
-                    new = self._alloc_blocks(1)
-                    if new is None:
-                        if exhaust == "abort":
-                            return None
-                        done.append(self._finish(i, "pool_exhausted"))
-                        break
-                    self._table[i, bi] = new[0]
-                    self._slot_blocks[i][1].append(new[0])
-            return done
+                    h = 0 if horizons is None else int(horizons[i])
+                    lo = int(self._pos[i]) // self.block_size
+                    hi = (int(self._pos[i]) + h) // self.block_size
+                    for bi in range(lo, hi + 1):
+                        if self._table[i, bi] != 0:
+                            continue
+                        new = self._alloc_blocks(1)
+                        if new is None:
+                            if exhaust == "abort":
+                                return None
+                            done.append(
+                                self._finish(i, "pool_exhausted"))
+                            break
+                        self._table[i, bi] = new[0]
+                        self._slot_blocks[i][1].append(new[0])
+                return done
+            finally:
+                if before is not None:
+                    span.set(**self._eviction_counts(before))
 
     def rollback_slot(self, slot: int) -> int:
         """Cache rollback hook (ISSUE 15): detach and free the slot's
@@ -2310,10 +2327,7 @@ class InferenceEngine:
                 "engine is draining (stop-admission): hand off to "
                 "another engine in the pool")
         req = pkg.request
-        in_flight = {r.id for r in self._queue} \
-            | {r.id for r in self._req if r is not None} \
-            | set(self.completed)
-        if req.id in in_flight:
+        if self._in_flight(req.id):
             raise ValueError(f"request id {req.id} already in flight "
                              "or completed-unclaimed")
         got, ref = _first_leaf(pkg.kv), _first_leaf(self.pool)

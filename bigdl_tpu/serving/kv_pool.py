@@ -19,7 +19,9 @@ only moves integers:
   content is still registered in the prefix tree stays OUT of the free
   list — it costs nothing to keep and may save a whole prefill. Under
   pool pressure the prefix tree evicts its LRU leaves back to the free
-  list (RadixPrefixCache.evict_one).
+  list (RadixPrefixCache.evict_one, which finds its victim in an index
+  of the evictable leaves and not by a walk of the tree: the pool tells
+  the tree through `on_park` when a block it owns reaches refcount 0).
 
 Everything here is deterministic: the free list is LIFO over an
 initially ascending range, eviction order comes from the tree's
@@ -29,7 +31,7 @@ serve_prefix drill replays bit-identically.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Optional
+from typing import Callable, Dict, Iterable, List, Optional
 
 import numpy as np
 
@@ -61,6 +63,12 @@ class BlockPool:
         # blocks with ref > 0 whose content the tree ALSO knows
         # (inserted at prefill while the prefiller still held them)
         self._tree_refd: set = set()
+        # called with each tree-owned block that just dropped to
+        # refcount 0 and parked (set by the RadixPrefixCache built over
+        # this pool: its LRU index of evictable leaves learns here that
+        # the block's node may be a victim again). The 0 -> 1 way needs
+        # no call: the index tests the refcount of what surfaces
+        self.on_park: Optional[Callable[[int], None]] = None
 
     # ------------------------------------------------------------ views
     @property
@@ -130,6 +138,8 @@ class BlockPool:
                 if b in self._tree_refd:
                     self._tree_refd.discard(b)
                     self._cached.add(b)
+                    if self.on_park is not None:
+                        self.on_park(int(b))
                 else:
                     self._free.append(b)
                     freed.append(b)

@@ -25,7 +25,10 @@ Contracts (the engine relies on all three):
 * **Eviction is LRU over refcount-0 LEAVES only** — interior nodes
   wait for their subtree, so a cached chain never dangles. Order is a
   logical clock (no wall time), making eviction bit-deterministic
-  (graftlint nondeterministic-drill clean by construction).
+  (graftlint nondeterministic-drill clean by construction). The
+  victim is found in a heap of the evictable leaves keyed (stamp,
+  registration order), never by a walk of the tree: freeing n blocks
+  costs O(n log nodes) however large the pool (ISSUE 46).
 
 Host-RAM spill tier (ISSUE 16): with `host_blocks > 0` the tree spans
 TWO tiers. A node either owns a device pool block (`block` set,
@@ -47,6 +50,7 @@ the device (all methods stay pure host bookkeeping).
 
 from __future__ import annotations
 
+import heapq
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -71,7 +75,7 @@ def chunk_hash(tokens: Sequence[int], prev: int = 0) -> int:
 
 class _Node:
     __slots__ = ("tokens", "hash", "block", "parent", "children",
-                 "stamp", "host")
+                 "stamp", "host", "seq", "queued")
 
     def __init__(self, tokens: Tuple[int, ...], h: int,
                  block: Optional[int],
@@ -86,6 +90,12 @@ class _Node:
         # HandoffPackage per-layer {'k','v'} layout — set exactly when
         # `block` is None
         self.host = None
+        # the LRU index's bookkeeping: `seq` is the node's place in
+        # _by_block's insertion order (the tie-break of equal stamps;
+        # None while the node is not on the device tier), `queued`
+        # whether the index holds a live entry for it
+        self.seq: Optional[int] = None
+        self.queued = False
 
 
 class RadixPrefixCache:
@@ -106,8 +116,24 @@ class RadixPrefixCache:
         self._clock = itertools.count(1)
         self._by_block: Dict[int, _Node] = {}
         # host-tier nodes by identity; insertion-ordered dict, so
-        # LRU tie-breaks are deterministic (like _by_block's scan)
+        # LRU tie-breaks are deterministic (evict_host_one and
+        # spill_victims still walk their tier: no cell runs the spill
+        # tier, and neither order falls out of the index below)
         self._host: Dict[int, _Node] = {}
+        # ---- the LRU index of evictable leaves (ISSUE 46): a heap of
+        # (stamp when pushed, seq, node), at most ONE live entry a
+        # node, offered where a device node may have become a
+        # childless refcount-0 one (the pool's park, a child's detach,
+        # a registration). What only makes a node LESS evictable costs
+        # nothing when it happens: a touch (stamps only grow, so an
+        # entry's key is never over its node's), a new child, a ref.
+        # evict_one mends or drops such an entry when it surfaces, and
+        # every entry surfaces at most once a push: `visits` counts
+        # the entries it looked at, about one an eviction
+        self._lru: List[Tuple[int, int, _Node]] = []
+        self._seq = itertools.count()
+        self.visits = 0
+        pool.on_park = self._block_parked
 
     # ------------------------------------------------------------ views
     @property
@@ -213,30 +239,78 @@ class RadixPrefixCache:
             child = _Node(chunk, h, int(block), node)
             child.stamp = stamp
             node.children[h] = child
-            self._by_block[int(block)] = child
+            self._register(child)
             owned.append(int(block))
             node = child
         return owned
 
     # ---------------------------------------------------------- evict
+    def _register(self, node: _Node) -> None:
+        """A node joins the device tier (insert, readmit): last in
+        _by_block's order, and offered to the index (a no-op while its
+        prefiller or re-admitter still holds the block)."""
+        self._by_block[node.block] = node
+        node.seq = next(self._seq)
+        self._offer(node)
+
+    def _unregister(self, node: _Node) -> None:
+        """A node leaves the device tier (park, detach): an entry the
+        index still holds for it is dead by its seq."""
+        del self._by_block[node.block]
+        node.seq = None
+        node.queued = False
+
+    def _offer(self, node: _Node) -> None:
+        """Give the index an entry for `node` if it is evictable now
+        (device tier, childless, refcount 0) and has none."""
+        if node.queued or node.seq is None or node.children \
+                or self.pool.refcount(node.block) > 0:
+            return
+        if len(self._lru) > 2 * len(self._by_block) + 64:
+            # dead entries (a parked or detached node's) leave only
+            # when they surface, and an engine that spills instead of
+            # evicting never pops: keep the heap within twice the tier
+            self._lru = [e for e in self._lru
+                         if e[2].queued and e[2].seq == e[1]]
+            heapq.heapify(self._lru)
+        node.queued = True
+        heapq.heappush(self._lru, (node.stamp, node.seq, node))
+
+    def _block_parked(self, block: int) -> None:
+        """BlockPool.on_park: a tree-owned block reached refcount 0."""
+        node = self._by_block.get(block)
+        if node is not None:
+            self._offer(node)
+
     def evict_one(self) -> Optional[int]:
         """Evict the least-recently-used refcount-0 LEAF back to the
         free list; returns its block id (for the caller's counters) or
-        None when nothing is evictable. O(nodes) scan — pools are
-        hundreds of blocks, and eviction only runs under pressure.
+        None when nothing is evictable. The victim is the one a scan
+        of _by_block would choose (lowest stamp, then insertion
+        order), taken off the top of the LRU index: O(log nodes)
+        amortised, each entry looked at is counted in `visits`.
         `node.children` includes host-tier children, so a device node
         whose subtree spilled is still interior — never detached."""
-        best: Optional[_Node] = None
-        for node in self._by_block.values():
-            if node.children or self.pool.refcount(node.block) > 0:
-                continue
-            if best is None or node.stamp < best.stamp:
-                best = node
-        if best is None:
-            return None
-        self._detach(best)
-        self.pool.release_cached(best.block)
-        return best.block
+        while self._lru:
+            stamp, seq, node = self._lru[0]
+            self.visits += 1
+            if not node.queued or node.seq != seq:
+                heapq.heappop(self._lru)    # a dead entry
+            elif node.children \
+                    or self.pool.refcount(node.block) > 0:
+                # interior or pinned since it was offered: its child's
+                # detach or the pool's park offers it again
+                node.queued = False
+                heapq.heappop(self._lru)
+            elif node.stamp != stamp:
+                # touched since: back in under its stamp of now
+                heapq.heapreplace(self._lru, (node.stamp, seq, node))
+            else:
+                heapq.heappop(self._lru)
+                self._detach(node)
+                self.pool.release_cached(node.block)
+                return node.block
+        return None
 
     # ------------------------------------------------- host tier (ISSUE 16)
     def spill_victims(self, k: int, protect: frozenset = frozenset()
@@ -248,7 +322,9 @@ class RadixPrefixCache:
         — and a leaf-only rule would jam the cascade, since a spilled
         leaf remains a child forever. `protect` excludes the chain an
         in-flight re-admission holds. Selection only — `park` commits
-        each victim after the engine fetched its bytes."""
+        each victim after the engine fetched its bytes. A walk of the
+        device tier (interior nodes are fair game here, so the index
+        of evictable LEAVES does not hold the candidates)."""
         cands = [(node.stamp, i, node)
                  for i, node in enumerate(self._by_block.values())
                  if node not in protect
@@ -262,7 +338,7 @@ class RadixPrefixCache:
         fetched by the engine) park on the node. Returns the freed
         device block id."""
         block = node.block
-        del self._by_block[block]
+        self._unregister(node)
         self.pool.release_cached(block)
         node.block = None
         node.host = host_data
@@ -275,7 +351,8 @@ class RadixPrefixCache:
         (childless-only: detaching an interior node would orphan its
         subtree — progress is still guaranteed, because the deepest
         node of any chain is childless and lives in one tier or the
-        other). False when no host node is evictable."""
+        other). False when no host node is evictable. A walk of the
+        host tier."""
         best: Optional[_Node] = None
         for node in self._host.values():
             if node.children or node in protect:
@@ -297,7 +374,7 @@ class RadixPrefixCache:
         node.host = None
         node.block = int(block)
         del self._host[id(node)]
-        self._by_block[node.block] = node
+        self._register(node)
         return data
 
     # ------------------------------------------------ migration (ISSUE 16)
@@ -370,9 +447,11 @@ class RadixPrefixCache:
         return True
 
     def _detach(self, node: _Node) -> None:
-        del node.parent.children[node.hash]
+        parent = node.parent
+        del parent.children[node.hash]
         if node.block is not None:
-            del self._by_block[node.block]
+            self._unregister(node)
         else:
             del self._host[id(node)]
             node.host = None
+        self._offer(parent)                 # it may be a leaf now

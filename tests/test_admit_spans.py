@@ -2,8 +2,10 @@
 tree keeps its place and its `prefill` child, and holds four kinds of leaf
 in a category of their own ("serving.admit": `queue_expire`, `queue_pop`,
 `seat_prepare`, `seat_commit`), so that the readers of the "serving" tree
-read what they read before; `admit` counts its queue walk (`queued`,
-`scanned`, `admitted`) and `prefill` says where its launch ended
+read what they read before; `admit` counts its queue (`queued`, `admitted`,
+and `scanned`: since ISSUE 46 the entries its expiry and its pops EXAMINED,
+the head of a line a pop and the top of the expiry heap a round, where it
+was the whole queue a walk) and `prefill` says where its launch ended
 (`launched_s`). With the tracer off none of it exists. CPU, tiny sizes."""
 
 import jax
@@ -144,8 +146,9 @@ def test_the_old_readers_read_what_they_read(served):
 
 
 def test_scanned_is_the_queue_walk_counted_by_hand(lm):
-    """Seven queued, two free slots, priorities known: the expiry walks 7,
-    the first pop 7, the second 6; then one slot frees at a time."""
+    """Seven queued, two free slots, priorities known, no request with an
+    expiry: the expiry examines 0 (its heap is empty), each pop 1, the head
+    of the highest priority's line; then one slot frees at a time."""
     obs.set_tracer(obs.SpanTracer(enabled=True))
     eng = _engine(lm)
     reqs = _requests(lens=(3, 4, 5, 6, 3, 4, 5), new=(1, 2, 1, 2, 1, 2, 1),
@@ -154,7 +157,7 @@ def test_scanned_is_the_queue_walk_counted_by_hand(lm):
     eng.step()
     first = _x("admit")[0]["args"]
     assert (first["queued"], first["scanned"], first["admitted"]) == \
-        (7, 7 + 7 + 6, 2)
+        (7, 0 + 1 + 1, 2)
     pops = _x("queue_pop")
     assert [p["args"]["queued"] for p in pops] == [7, 6]
     # highest priority first, FIFO within one
@@ -164,10 +167,8 @@ def test_scanned_is_the_queue_walk_counted_by_hand(lm):
     eng.run()
     for a in _x("admit"):
         a = a["args"]
-        # no free slot, or an empty queue: the expiry's walk alone
-        assert a["scanned"] >= a["queued"] >= a["admitted"]
-        if not a["admitted"]:
-            assert a["scanned"] == a["queued"]
+        # one entry a pop, and no free slot or an empty queue: nothing
+        assert a["queued"] >= a["scanned"] == a["admitted"]
     assert sum(a["args"]["admitted"] for a in _x("admit")) == \
         eng.stats["prefill_calls"] == 7
     assert len(_x("queue_pop")) == 7
@@ -186,9 +187,12 @@ def test_an_expired_request_is_counted_and_not_scanned_twice(lm):
     expire = _x("queue_expire")[0]["args"]
     assert (expire["queued"], expire["expired"]) == (3, 1)
     admit = _x("admit")[0]["args"]
-    # the expiry walks 3, the pops the 2 and then the 1 that are left
+    # the expiry examines the 1 entry of its heap (due, and then the heap
+    # is empty) and takes the request out of the MIDDLE of the line, where
+    # its entry stays, dead; the first pop examines the head and the dead
+    # entry that it uncovers, which goes with it; the second pop the head
     assert (admit["queued"], admit["scanned"], admit["admitted"]) == \
-        (3, 3 + 2 + 1, 2)
+        (3, 1 + 2 + 1, 2)
 
 
 def test_admitted_sums_to_the_prefill_calls(served):
@@ -222,11 +226,12 @@ def test_a_failed_seating_still_closes_seat_prepare(lm, monkeypatch):
     eng.step()
     (prep,) = _x("seat_prepare")
     assert prep["args"] == {"request": rid, "evicted_blocks": 0,
-                            "id": prep["args"]["id"],
+                            "evict_visits": 0, "id": prep["args"]["id"],
                             "parent": _x("admit")[0]["args"]["id"]}
     assert not _x("prefill") and not _x("seat_commit")
     admit = _x("admit")[0]["args"]
-    assert (admit["queued"], admit["scanned"], admit["admitted"]) == (1, 2, 0)
+    # the one pop examined the one entry; the requeue examines nothing
+    assert (admit["queued"], admit["scanned"], admit["admitted"]) == (1, 1, 0)
     assert eng.queue_depth == 1                     # requeued at the front
 
 
@@ -241,6 +246,13 @@ def test_seat_prepare_counts_the_blocks_it_evicted(lm):
     evicted = [e["args"]["evicted_blocks"] for e in _x("seat_prepare")]
     assert evicted[0] == 0 and evicted[1] > 0
     assert sum(evicted) == eng.stats["pool_evictions"]
+    # the index hands over a chain leaf first: one entry a victim
+    visits = [e["args"]["evict_visits"] for e in _x("seat_prepare")]
+    assert visits == evicted
+    assert sum(visits) == eng.stats["pool_eviction_visits"]
+    for e in _x("ensure_blocks"):
+        assert set(e["args"]) == {"id", "parent", "evicted_blocks",
+                                  "evict_visits"}
 
 
 def test_the_speculative_mirror_seats_under_whatever_is_open(lm):
