@@ -48,11 +48,27 @@ class StepTelemetry:
             "steps excluded)")
         self._records = reg.counter(
             "training_records_total", "training records consumed")
+        self._prefetched = reg.counter(
+            "training_batches_prefetched_total",
+            "steps that found their batch already placed on the mesh "
+            "(DistriOptimizer places batch n + 1 while step n runs)")
+        self._prefetch_dropped = reg.counter(
+            "training_batches_prefetch_dropped_total",
+            "batches placed ahead and never fed to a step (the run's "
+            "end, a recovery, a poisoned batch placed again)")
         self._loss = reg.gauge("training_loss", "last step loss")
         self._lr = reg.gauge("training_learning_rate",
                              "last step learning rate")
         self._thr = reg.gauge("training_throughput_records_per_sec",
                               "last step throughput")
+
+    def batch_prefetched(self) -> None:
+        if obs.enabled():
+            self._prefetched.inc()
+
+    def prefetch_dropped(self) -> None:
+        if obs.enabled():
+            self._prefetch_dropped.inc()
 
     def emit_step(self, *, epoch: int, step: int,
                   loss: Optional[float], lr: float, throughput: float,

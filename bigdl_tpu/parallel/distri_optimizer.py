@@ -49,7 +49,20 @@ logger = logging.getLogger("bigdl_tpu.optim")
 
 
 class DistriOptimizer(LocalOptimizer):
-    """Mesh data-parallel optimizer (reference: optim/DistriOptimizer.scala)."""
+    """Mesh data-parallel optimizer (reference: optim/DistriOptimizer.scala).
+
+    The loop's input side runs one batch ahead of the step: the host
+    batch of step n + 1 is taken from the dataset's iterator at the top
+    of iteration n and placed on the mesh right after step n's call,
+    while the devices run it and before anything fetches its results.
+    Device memory therefore holds two batches; a dataset whose iterator
+    has side effects sees one `next()` more than the steps that ran
+    (the batch the end trigger leaves over is dropped before `run()`
+    returns); a failure of the loader met ahead is raised by the step
+    that would have consumed the batch, and a recovery drops what was
+    placed ahead with the iterator it came from. How often it engages:
+    `training_batches_prefetched_total` (steps - 1 in a healthy run) and
+    `training_batches_prefetch_dropped_total` (1)."""
 
     def __init__(self, opt: Optimizer, mesh: Mesh, axis: str = "data",
                  grad_dtype: Optional[str] = "bfloat16", max_retries: int = 3,
@@ -291,6 +304,7 @@ class DistriOptimizer(LocalOptimizer):
             policy (the reference's reload-last-checkpoint recovery,
             SURVEY.md §5.3)."""
             nonlocal flat_w, mod_state, slots, batches
+            drop_ahead()  # it came from the iterator replaced below
             o.checkpoint.wait()  # surface pending async-save errors
             saved_vars, saved_slots, saved_ts, om = o.checkpoint.load(
                 with_optim_meta=True)
@@ -325,6 +339,33 @@ class DistriOptimizer(LocalOptimizer):
         # checkpointed run consumed (bit-for-bit resume; no-op fresh)
         batches = _batch_iterator(o.dataset, True, self._local_bs,
                                   skip=train_state["neval"])
+        # The input side runs one batch ahead of the step: `ahead` is the
+        # NEXT step's (host batch, its placement on the mesh), fetched
+        # at the top of the running iteration and placed in the shadow
+        # of its step, before anything fetches that step's results. A
+        # failure of the loader or of the placement is held in the host
+        # batch's place and raised by the step that would have consumed
+        # it, inside its retry scope (`data@<position>` keeps its
+        # meaning). None on the first iteration and after a recover().
+        ahead = None
+
+        def fetch():
+            with Timer(self.metrics, "data_fetch_s"):
+                try:
+                    return next(batches)
+                except Exception as e:  # held for the consuming step
+                    return e
+
+        def place(mb):
+            with Timer(self.metrics, "h2d_place_s"):
+                return self._global(mb.input), self._global(mb.target)
+
+        def drop_ahead():
+            nonlocal ahead
+            if ahead is not None and ahead[1] is not None:
+                self.telemetry.prefetch_dropped()
+            ahead = None
+
         iter_start = time.perf_counter()
         retries = 0
 
@@ -344,10 +385,20 @@ class DistriOptimizer(LocalOptimizer):
                 raise
             try:
                 plan.maybe_raise("step", train_state["neval"])
-                with Timer(self.metrics, "data_fetch_s"):
-                    mb = next(batches)
+                mb, placed = ahead or (fetch(), None)
+                ahead = None
+                if isinstance(mb, Exception):
+                    raise mb
+                # batch n + 1 leaves the iterator here; batch n stays
+                # referenced as `mb` until step n has fenced, so a
+                # dataset that frees or reuses a batch's buffer once it
+                # is unreferenced cannot do so under a transfer
+                nxt = fetch()
                 if plan.fires("nan", train_state["neval"]):
                     mb = faults.poison_minibatch(mb)
+                    if placed is not None:  # placed clean: place again
+                        self.telemetry.prefetch_dropped()
+                        placed = None
                 # schedules and the optimizer's step counter advance per
                 # APPLIED update, not per (micro-)batch (mirrors
                 # LocalOptimizer): a guard-discarded update re-uses its
@@ -360,11 +411,16 @@ class DistriOptimizer(LocalOptimizer):
                 thr = None if guard is None else jnp.asarray(
                     guard.threshold(), jnp.float32)
                 with Timer(self.metrics, "dispatch_s"):
-                    # dispatch = h2d_place (the host batch placed on
-                    # the mesh) + the step's call (PERF.md §3)
-                    with Timer(self.metrics, "h2d_place_s"):
-                        x = self._global(mb.input)
-                        y = self._global(mb.target)
+                    # dispatch = the step's call + h2d_place (the NEXT
+                    # host batch placed on the mesh while the devices
+                    # run this one; PERF.md §3). Only the first step,
+                    # and the one after a recover() or a poisoning,
+                    # places its own batch before it calls
+                    if placed is None:
+                        placed = place(mb)
+                    else:
+                        self.telemetry.batch_prefetched()
+                    x, y = placed
                     if accum == 1:
                         step_args = (
                             flat_w, slots, mod_state, x, y,
@@ -382,14 +438,22 @@ class DistriOptimizer(LocalOptimizer):
                             flat_w, g_acc, mod_state, x, y, step_rng)
                         if guard is None:
                             g_acc, mod_state, loss = micro_fn(*micro_args)
-                            micro_n += 1
                         else:
                             (g_acc, mod_state, loss, ok_d,
                              gnorm_d) = micro_fn(*micro_args, thr)
-                            # an anomalous micro-gradient was zeroed out
-                            # of the accumulator on device; don't count
-                            # it toward the cycle either
-                            micro_n += int(bool(ok_d))
+                    # in the shadow of the step just launched, before
+                    # anything below fetches a result of it
+                    ahead = (nxt, None)
+                    if not isinstance(nxt, Exception):
+                        try:
+                            ahead = (nxt, place(nxt))
+                        except Exception as e:  # held, like the loader's
+                            ahead = (e, None)
+                    if accum > 1:
+                        # an anomalous micro-gradient was zeroed out of
+                        # the accumulator on device; don't count it
+                        # toward the cycle either (the guard's fetch)
+                        micro_n += 1 if guard is None else int(bool(ok_d))
                         if micro_n == accum:
                             flat_w, slots, g_acc = apply_fn(
                                 flat_w, slots, g_acc,
@@ -560,6 +624,9 @@ class DistriOptimizer(LocalOptimizer):
                     multihost_utils.sync_global_devices(
                         f"ckpt-{train_state['neval']}")
                 logger.info("checkpoint -> %s", path)
+
+        # the batch placed for the step that never came: its HBM goes now
+        drop_ahead()
 
         # end trigger may fire mid-accumulation-cycle: flush the partial
         # accumulator (mean over micro-batches actually seen) so that

@@ -345,7 +345,10 @@ def test_dispatch_contains_h2d_place(mesh):
     opt.optimize()
     dispatches = _spans("dispatch")
     places = _spans("h2d_place")
-    assert len(dispatches) == len(places) == 3
+    # the mesh loop places one batch ahead, inside the dispatch of the
+    # step before: the end trigger leaves one placed and never fed
+    n_places = 4 if mesh else 3
+    assert len(dispatches) == 3 and len(places) == n_places
     by_id = {d["args"]["id"]: d for d in dispatches}
     for p in places:
         d = by_id[p["args"]["parent"]]
@@ -353,7 +356,7 @@ def test_dispatch_contains_h2d_place(mesh):
     snap = obs.get_registry().snapshot()["metrics"]
     phases = {s["labels"]["phase"]: s["count"]
               for s in snap["training_phase_seconds"]["series"]}
-    assert phases["h2d_place_s"] == phases["dispatch_s"] == 3
+    assert phases["dispatch_s"] == 3 and phases["h2d_place_s"] == n_places
 
 
 # ------------------------------------------------ the compile listener
