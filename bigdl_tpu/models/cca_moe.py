@@ -74,14 +74,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.models.latent_moe import _mm, rms_norm
+from bigdl_tpu.models.latent_moe import (_mm, require_source_values,
+                                         rms_norm)
 from bigdl_tpu.models.window_moe import (grouped_prompt_attention,
                                          rope_half_split)
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.kv_cache import (attended_blocks, grouped_paged_attention,
                                     init_row_pool, write_decode_rows,
                                     write_prompt_rows)
-from bigdl_tpu.parallel.moe import DroplessMoE, expert_load_report
+from bigdl_tpu.parallel.moe import DroplessMoE, ExpertsReport
+from bigdl_tpu.serving.protocol import ServedModel
 
 LAYER_KINDS = ("hybrid",)
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -137,11 +139,7 @@ class CCAMoEConfig:
                 "tie_word_embeddings": True, "attention_bias": False,
                 "lm_head_bias": False, "hidden_act": "silu",
                 "sliding_window": None}
-        for key, value in only.items():
-            if cfg.get(key, value) != value:
-                raise NotImplementedError(
-                    f"{key}={cfg[key]!r}: this model does {key}={value!r} "
-                    "only")
+        require_source_values(cfg, only)
         kinds = tuple(cfg["layer_types"])
         if len(kinds) != cfg["num_hidden_layers"]:
             raise ValueError(
@@ -169,12 +167,17 @@ def _shift(x):
     return jnp.pad(x, ((1, 0), (0, 0)))[:-1]
 
 
-class CCAMoELM(Module):
+class CCAMoELM(ExpertsReport, Module, ServedModel):
     """See the module docstring. Parameters are per layer from the
     start: `{"embed" (V, d), "res_out", "norm" (d,), "layers":
     (dict,) * L}`, every matrix (in, out); a merge's `res_*` is
     `{"s_r", "b_r", "s_y", "b_y"}` (d,) each, the first sublayer's
     without the `_r` pair; layer 0's router has no `gamma`."""
+
+    # a layer's experts are under "experts", and the step's aux has a
+    # seventeenth column: the rows the router sent to no expert
+    experts_key = "experts"
+    aux_skip_column = True
 
     def __init__(self, config: CCAMoEConfig, name=None):
         super().__init__(name=name)
@@ -387,40 +390,6 @@ class CCAMoELM(Module):
 
     # ------------------------------------------------------ the paged trio
 
-    def check_serving_options(self, weight_dtype="fp32", tp=False,
-                              speculative=False, prefix_cache=False,
-                              spill=False, role="both"):
-        """What `InferenceEngine` and `SpeculativeEngine` ask a model
-        that has limits; raises for what this one does not do."""
-        state = ("the previous token's convolution and value rows live "
-                 "in the slot's state (cache_kinds), which no block "
-                 "carries")
-        for bad, what, why in (
-                (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
-                 "serving/quant.py repacks TransformerLM's block leaves"),
-                (tp, "tp_mesh",
-                 "serving/tp.py splits TransformerLM's K and V pools by "
-                 "head and knows no state leaf"),
-                (speculative, "SpeculativeEngine",
-                 "a rejected draft suffix has already overwritten the "
-                 "slot's state: rollback cannot bring it back"),
-                (prefix_cache, "prefix_cache=True",
-                 f"{state}: a hit would need the state at the shared "
-                 "prefix's end, a snapshot per tree node"),
-                (spill, "spill=True",
-                 f"it parks prefix-cache blocks on the host, and {state}"),
-                (role != "both", f"role={role!r}",
-                 f"a handoff package carries table blocks, and {state}")):
-            if bad:
-                raise NotImplementedError(
-                    f"CCAMoELM does not serve with {what}: {why}")
-
-    def decode_attn_form(self) -> str:
-        """`InferenceEngine`'s `attn_form` label: rows are attended as
-        they are stored, through each slot's own live chunks
-        (ops/kv_cache.grouped_paged_attention)."""
-        return "rows"
-
     def cache_kinds(self) -> Tuple[str, ...]:
         """For each entry of `init_block_pool`'s tuple: a layer's
         "table" entry (its key and value rows) and then its "state"
@@ -513,9 +482,6 @@ class CCAMoELM(Module):
 
     # ------------------------------------------------- what the spans say
 
-    def decode_aux_report(self, aux):
-        return expert_load_report(aux, skip_column=True)
-
     def decode_read_report(self, pos, table, block_size: int) -> dict:
         """What a decode step at these clocks (host, NumPy: `pos` (B,),
         `table` (B, max_blocks) with an unseated slot's row zero) reads
@@ -532,15 +498,6 @@ class CCAMoELM(Module):
                 "attended_rows": int(
                     block_size * len(self.cfg.layers)
                     * attended_blocks(pos, table, block_size))}
-
-    def expert_matmul_form(self, params, tokens: int) -> str:
-        """`InferenceEngine`'s `expert_matmul` label for a program of
-        `tokens` rows (`DroplessMoE.expert_matmul`)."""
-        lp = next(lp for lp in params["layers"] if "experts" in lp)
-        return self.moe.expert_matmul(lp["experts"], tokens)
-
-    def prefill_span_args(self, bucket: int) -> dict:
-        return {"moe_assignments": bucket}      # one expert a token
 
     def slot_state_bytes(self, cache_dtype=None) -> int:
         """What ONE seated slot keeps in the "state" entries, all

@@ -72,13 +72,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.models.latent_moe import _mm, rms_norm
+from bigdl_tpu.models.latent_moe import (_mm, require_source_values,
+                                         rms_norm)
 from bigdl_tpu.models.window_moe import grouped_prompt_attention
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.kv_cache import (attended_blocks, grouped_paged_attention,
                                     init_row_pool, write_decode_rows,
                                     write_prompt_rows)
 from bigdl_tpu.ops.ssm import causal_conv, conv_taps, ssd_chunked, ssm_step
+from bigdl_tpu.serving.protocol import ServedModel
 
 LAYER_KINDS = ("mamba", "attention")
 
@@ -142,11 +144,7 @@ class HybridSSMConfig:
                 "tie_word_embeddings": True, "attention_bias": False,
                 "mamba_proj_bias": False, "mamba_conv_bias": True,
                 "hidden_act": "silu", "normalization_function": "rmsnorm"}
-        for key, value in only.items():
-            if cfg.get(key, value) != value:
-                raise NotImplementedError(
-                    f"{key}={cfg[key]!r}: this model does {key}={value!r} "
-                    "only")
+        require_source_values(cfg, only)
         kinds = tuple(cfg["layer_types"])
         if len(kinds) != cfg["num_hidden_layers"]:
             raise ValueError(
@@ -161,7 +159,7 @@ class HybridSSMConfig:
         return cls(layers=kinds, **{k: cfg[k] for k in names if k in cfg})
 
 
-class HybridSSMLM(Module):
+class HybridSSMLM(Module, ServedModel):
     """See the module docstring. Parameters are per layer from the
     start: `{"embed" (V, d), "norm" (d,), "layers": (dict,) * L}`, every
     matrix (in, out); a layer has `ln_mixer`, `ln_mlp`, `w_in`
@@ -353,41 +351,6 @@ class HybridSSMLM(Module):
             tokens), variables.get("state", {}))
 
     # ------------------------------------------------------ the paged trio
-
-    def check_serving_options(self, weight_dtype="fp32", tp=False,
-                              speculative=False, prefix_cache=False,
-                              spill=False, role="both"):
-        """What `InferenceEngine` and `SpeculativeEngine` ask a model
-        that has limits; raises for what this one does not do."""
-        state = ("a mamba layer keeps no rows: what it knows of a "
-                 "context is the slot's recurrent state (cache_kinds), "
-                 "which no block carries")
-        for bad, what, why in (
-                (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
-                 "serving/quant.py repacks TransformerLM's block leaves"),
-                (tp, "tp_mesh",
-                 "serving/tp.py splits TransformerLM's K and V pools by "
-                 "head and knows no state leaf"),
-                (speculative, "SpeculativeEngine",
-                 "a rejected draft suffix has already moved the slot's "
-                 "state: rollback cannot bring it back without a "
-                 "snapshot"),
-                (prefix_cache, "prefix_cache=True",
-                 f"{state}: a hit would need the state at the shared "
-                 "prefix's end, a snapshot per tree node"),
-                (spill, "spill=True",
-                 f"it parks prefix-cache blocks on the host, and {state}"),
-                (role != "both", f"role={role!r}",
-                 f"a handoff package carries table blocks, and {state}")):
-            if bad:
-                raise NotImplementedError(
-                    f"HybridSSMLM does not serve with {what}: {why}")
-
-    def decode_attn_form(self) -> str:
-        """`InferenceEngine`'s `attn_form` label: rows are attended as
-        they are stored, through each slot's own live chunks
-        (ops/kv_cache.grouped_paged_attention)."""
-        return "rows"
 
     def cache_kinds(self) -> Tuple[str, ...]:
         """For each entry of `init_block_pool`'s tuple, ONE a layer in

@@ -54,8 +54,8 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.kv_cache import (block_attention, gather_block_rows,
                                     init_row_pool, latent_paged_attention,
                                     write_decode_rows, write_prompt_rows)
-from bigdl_tpu.parallel.moe import (DroplessMoE, expert_load_report,
-                                    gated_ffn)
+from bigdl_tpu.parallel.moe import DroplessMoE, ExpertsReport, gated_ffn
+from bigdl_tpu.serving.protocol import ServedModel
 
 LAYER_KINDS = ("dense", "moe")
 
@@ -106,11 +106,7 @@ class LatentMoEConfig:
             "rope_interleave": True, "hidden_act": "silu",
             "attention_bias": False, "tie_word_embeddings": False,
             "moe_layer_freq": 1}
-        for key, value in only.items():
-            if cfg.get(key, value) != value:
-                raise NotImplementedError(
-                    f"{key}={cfg[key]!r}: this model does {key}={value!r} "
-                    "only")
+        require_source_values(cfg, only)
         if cfg.get("num_nextn_predict_layers", 0):
             raise NotImplementedError(
                 "num_nextn_predict_layers > 0: the multi-token-prediction "
@@ -121,6 +117,17 @@ class LatentMoEConfig:
                        for i in range(cfg["num_hidden_layers"]))
         names = [f for f in cls.__dataclass_fields__ if f != "layers"]
         return cls(layers=layers, **{k: cfg[k] for k in names if k in cfg})
+
+
+def require_source_values(cfg: dict, only: dict) -> None:
+    """`from_source`'s refusal of what a list model does not build: a
+    key of `only` that the source's `config.json` gives another value
+    (absent: the value it has here)."""
+    for key, value in only.items():
+        if cfg.get(key, value) != value:
+            raise NotImplementedError(
+                f"{key}={cfg[key]!r}: this model does {key}={value!r} "
+                "only")
 
 
 def rms_norm(x, gain, eps):
@@ -147,11 +154,17 @@ def _mm(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-class LatentMoELM(Module):
+class LatentMoELM(ExpertsReport, Module, ServedModel):
     """See the module docstring. Parameters are per layer from the
     start (layers differ), so the serving engine makes no second copy:
     `{"embed" (V, D), "head" (D, V), "norm" (D,), "layers": (dict,)*L}`,
-    every matrix (in, out)."""
+    every matrix (in, out). Its rows live in table blocks like
+    `TransformerLM`'s: the prefix cache, the spill tier and the handoff
+    roles serve it (serving/protocol.py)."""
+
+    unserved = {"speculative": "the verify step's rows of one slot "
+                               "would route through the experts "
+                               "together: not validated"}
 
     def __init__(self, config: LatentMoEConfig, name=None):
         super().__init__(name=name)
@@ -293,39 +306,13 @@ class LatentMoELM(Module):
 
     # ------------------------------------------------------ the paged trio
 
-    def check_serving_options(self, weight_dtype="fp32", tp=False,
-                              speculative=False, prefix_cache=False,
-                              spill=False, role="both"):
-        """What `InferenceEngine` and `SpeculativeEngine` ask a model
-        that has limits; raises for what this one does not do (the
-        prefix cache, the spill tier and the handoff roles it does:
-        its rows live in table blocks like any other model's)."""
-        for bad, what, why in (
-                (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
-                 "serving/quant.py repacks TransformerLM's block leaves"),
-                (tp, "tp_mesh",
-                 "serving/tp.py splits K and V pools by head; a latent "
-                 "row is shared by all heads"),
-                (speculative, "SpeculativeEngine",
-                 "the verify step's rows of one slot would route "
-                 "through the experts together: not validated")):
-            if bad:
-                raise NotImplementedError(
-                    f"LatentMoELM does not serve with {what}: {why}")
-
-    def decode_attn_form(self) -> str:
-        """`InferenceEngine`'s `attn_form` label: latent rows are
-        shared by all heads and attended as they are stored
-        (ops/kv_cache.latent_paged_attention)."""
-        return "rows"
-
     def init_block_pool(self, num_blocks: int, block_size: int,
-                        dtype=jnp.float32):
+                        dtype=jnp.float32, slots: int = 1):
         """Per-layer latent pools: a TUPLE of L dicts {'kv'}, each
         (num_blocks, block_size, W): blocks are axis 0 and block 0 is
-        scratch (ops/kv_cache.init_block_pool). A row is `[c_kv ;
-        k_rope ; zeros]`, W = rank + rope rounded up to whole 128-lane
-        tiles (576 -> 640). One leaf a layer, because the decode score
+        scratch (ops/kv_cache.init_block_pool); `slots` is not used.
+        A row is `[c_kv ; k_rope ; zeros]`, W = rank + rope rounded up
+        to whole 128-lane tiles (576 -> 640). One leaf a layer, because the decode score
         contracts the row whole; padded, because a v5e lays
         `bf16[N, 16, 576]` out with the BLOCK dimension minor-most
         rather than pad 576 lanes, and both programs then transpose
@@ -413,17 +400,3 @@ class LatentMoELM(Module):
         h = rms_norm(x, p["norm"], c.rms_norm_eps)
         logits = _mm(h.astype(p["head"].dtype), p["head"])
         return logits, tuple(new_pools), jnp.stack(counts)
-
-    # ------------------------------------------------- what the spans say
-
-    def decode_aux_report(self, aux):
-        return expert_load_report(aux)
-
-    def expert_matmul_form(self, params, tokens: int) -> str:
-        """`InferenceEngine`'s `expert_matmul` label for a program of
-        `tokens` rows (`DroplessMoE.expert_matmul`)."""
-        lp = next(lp for lp in params["layers"] if "moe" in lp)
-        return self.moe.expert_matmul(lp["moe"], tokens)
-
-    def prefill_span_args(self, bucket: int) -> dict:
-        return {"moe_assignments": bucket * self.cfg.num_experts_per_tok}

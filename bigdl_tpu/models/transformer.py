@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu.nn.module import Module
+from bigdl_tpu.serving.protocol import ServedModel
 
 
 def _deq(w):
@@ -178,7 +179,7 @@ class TransformerConfig:
                 "be optimistic (see parallel/moe.py)")
 
 
-class TransformerLM(Module):
+class TransformerLM(Module, ServedModel):
     """apply(variables, tokens (B, S) int32) → log-probs (B, S, V).
 
     `sp_axis`: if set, attention runs as ring attention over that mesh
@@ -624,13 +625,14 @@ class TransformerLM(Module):
     # independent of which bucket (or which request) computed it.
 
     def init_block_pool(self, num_blocks: int, block_size: int,
-                        dtype=jnp.float32):
+                        dtype=jnp.float32, slots: int = 1):
         """Per-layer paged KV pools: a TUPLE of L dicts {'k','v'},
         each (num_blocks, block_size, H*D): blocks are axis 0, a
         block's rows are tokens, a row holds the heads side by side
         (ops/kv_cache.init_block_pool says why). Per-layer (not
         stacked) for the same reason as init_cache; block 0 is the
-        reserved scratch block (ops/kv_cache.py)."""
+        reserved scratch block (ops/kv_cache.py). `slots` is not used:
+        every entry is a "table" (serving/protocol.py)."""
         from bigdl_tpu.ops.kv_cache import init_block_pool
 
         self._serving_guard(tp_ok=True)

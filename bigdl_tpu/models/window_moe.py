@@ -74,7 +74,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.models.latent_moe import _mm, rms_norm
+from bigdl_tpu.models.latent_moe import (_mm, require_source_values,
+                                         rms_norm)
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.kv_cache import (attended_blocks,
                                     grouped_paged_attention,
@@ -82,8 +83,8 @@ from bigdl_tpu.ops.kv_cache import (attended_blocks,
                                     ring_window, ring_write_blocks,
                                     write_decode_rows, write_prompt_ring,
                                     write_prompt_rows)
-from bigdl_tpu.parallel.moe import (DroplessMoE, expert_load_report,
-                                    gated_ffn)
+from bigdl_tpu.parallel.moe import DroplessMoE, ExpertsReport, gated_ffn
+from bigdl_tpu.serving.protocol import ServedModel
 
 ATTENTION_KINDS = ("sliding_attention", "full_attention")
 FFN_KINDS = ("dense", "moe")
@@ -142,11 +143,7 @@ class WindowMoEConfig:
                 "num_expert_groups": 1, "num_limited_groups": 1,
                 "score_func": "sigmoid", "hidden_act": "silu",
                 "tie_word_embeddings": False}
-        for key, value in only.items():
-            if cfg.get(key, value) != value:
-                raise NotImplementedError(
-                    f"{key}={cfg[key]!r}: this model does {key}={value!r} "
-                    "only")
+        require_source_values(cfg, only)
         kinds = cfg["layer_types"]
         if len(kinds) != cfg["num_hidden_layers"]:
             raise ValueError(
@@ -212,7 +209,7 @@ def grouped_prompt_attention(q, k, v, kv_heads: int, sm_scale: float,
     return out.reshape(s, hq * dh)
 
 
-class WindowMoELM(Module):
+class WindowMoELM(ExpertsReport, Module, ServedModel):
     """See the module docstring. Parameters are per layer from the
     start, as `LatentMoELM`'s: `{"embed" (V, d), "head" (d, V), "norm"
     (d,), "layers": (dict,) * L}`, every matrix (in, out)."""
@@ -344,39 +341,6 @@ class WindowMoELM(Module):
 
     # ------------------------------------------------------ the paged trio
 
-    def check_serving_options(self, weight_dtype="fp32", tp=False,
-                              speculative=False, prefix_cache=False,
-                              spill=False, role="both"):
-        """What `InferenceEngine` and `SpeculativeEngine` ask a model
-        that has limits; raises for what this one does not do."""
-        ring = ("a sliding layer's rows live in its slot's ring "
-                "(cache_kinds), which keeps the last window only")
-        for bad, what, why in (
-                (weight_dtype != "fp32", f"weight_dtype={weight_dtype!r}",
-                 "serving/quant.py repacks TransformerLM's block leaves"),
-                (tp, "tp_mesh",
-                 "serving/tp.py splits TransformerLM's K and V pools by "
-                 "head and knows no ring leaf"),
-                (speculative, "SpeculativeEngine",
-                 "a rejected draft suffix has already overwritten the "
-                 "ring's oldest rows: rollback cannot bring them back"),
-                (prefix_cache, "prefix_cache=True",
-                 f"{ring}: a hit would need the shared prefix's rows of "
-                 "every sliding layer"),
-                (spill, "spill=True",
-                 f"it parks prefix-cache blocks on the host, and {ring}"),
-                (role != "both", f"role={role!r}",
-                 f"a handoff package carries table blocks, and {ring}")):
-            if bad:
-                raise NotImplementedError(
-                    f"WindowMoELM does not serve with {what}: {why}")
-
-    def decode_attn_form(self) -> str:
-        """`InferenceEngine`'s `attn_form` label: rows are attended as
-        they are stored, through each slot's own live chunks
-        (ops/kv_cache.grouped_paged_attention)."""
-        return "rows"
-
     def cache_kinds(self) -> Tuple[str, ...]:
         """What the engine asks instead of a model's name: for each
         entry of `init_block_pool`'s tuple, "table" (rows in blocks the
@@ -501,18 +465,6 @@ class WindowMoELM(Module):
         return logits, tuple(new_pools), jnp.stack(counts)
 
     # ------------------------------------------------- what the spans say
-
-    def decode_aux_report(self, aux):
-        return expert_load_report(aux)
-
-    def expert_matmul_form(self, params, tokens: int) -> str:
-        """`InferenceEngine`'s `expert_matmul` label for a program of
-        `tokens` rows (`DroplessMoE.expert_matmul`)."""
-        lp = next(lp for lp in params["layers"] if "moe" in lp)
-        return self.moe.expert_matmul(lp["moe"], tokens)
-
-    def prefill_span_args(self, bucket: int) -> dict:
-        return {"moe_assignments": bucket * self.cfg.num_experts_per_tok}
 
     def decode_read_report(self, pos, table, block_size: int) -> dict:
         """What a decode step at these clocks (host, NumPy: `pos` (B,),

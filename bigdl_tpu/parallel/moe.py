@@ -383,6 +383,32 @@ def expert_load_report(aux, skip_column: bool = False):
             "moe_assignments": int(aux.sum()), **args}
 
 
+class ExpertsReport:
+    """What the experts of a served model tell the engine's spans
+    (serving/protocol.py, "what the spans say"): a mixin for a model
+    whose expert sublayers are `self.moe`, a `DroplessMoE`, listed
+    before `ServedModel` among its bases."""
+
+    # the key of a layer's parameters that holds its experts' weights
+    experts_key = "moe"
+    # the aux's last column is the rows a router sent to no expert
+    # (`expert_load_report`'s `skip_column`)
+    aux_skip_column = False
+
+    def decode_aux_report(self, aux) -> dict:
+        return expert_load_report(aux, skip_column=self.aux_skip_column)
+
+    def expert_matmul_form(self, params, tokens: int) -> str:
+        """The engine's `expert_matmul` label for a program of
+        `tokens` rows (`DroplessMoE.expert_matmul`)."""
+        experts = next(lp[self.experts_key] for lp in params["layers"]
+                       if self.experts_key in lp)
+        return self.moe.expert_matmul(experts, tokens)
+
+    def prefill_span_args(self, bucket: int) -> dict:
+        return {"moe_assignments": bucket * self.moe.top_k}
+
+
 def gated_ffn(x, w_gate, w_up, w_down):
     """(silu(x W_g) * x W_u) W_d with float32 accumulation; the hidden
     activation goes back to x's dtype between the matmuls."""

@@ -19,6 +19,7 @@ from bigdl_tpu.serving.engine import (STATUSES, EngineDegraded,
                                       StepTimeout)
 from bigdl_tpu.serving.kv_pool import BlockPool
 from bigdl_tpu.serving.prefix_cache import RadixPrefixCache
+from bigdl_tpu.serving.protocol import ServedModel
 from bigdl_tpu.serving.router import (EngineRouter, NoHealthyEngine,
                                       ROUTER_LATENCY_BUCKETS)
 from bigdl_tpu.serving.sampler import filter_logits, sample_logits
@@ -29,20 +30,33 @@ from bigdl_tpu.serving.sim import CostModel, SimulatedEngine
 from bigdl_tpu.serving.speculative import SpeculativeEngine
 from bigdl_tpu.serving.tenancy import (TenancyController, TenantSpec,
                                        TokenBucket)
-from bigdl_tpu.serving.tp import (TPServingLM, gather_serving_params,
-                                  shard_serving_params,
-                                  tp_serving_model, tp_serving_specs)
 from bigdl_tpu.serving.vision import VisionEngine
 
+# serving/tp.py imports models/ and parallel/, and a served model
+# derives from serving/protocol.ServedModel: importing this package
+# (which `import bigdl_tpu.serving.protocol` does) must import no
+# model, so tp's names resolve on first use
+_TP_NAMES = ("TPServingLM", "tp_serving_model", "tp_serving_specs",
+             "gather_serving_params", "shard_serving_params")
+
+
+def __getattr__(name):
+    if name in _TP_NAMES:
+        from bigdl_tpu.serving import tp
+
+        return getattr(tp, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
 __all__ = [
-    "InferenceEngine", "Request", "GenerationResult", "STATUSES",
+    "InferenceEngine", "ServedModel", "Request", "GenerationResult",
+    "STATUSES",
     "OverloadError", "StepTimeout", "EngineDegraded", "EngineDraining",
     "HandoffPackage", "EngineRouter", "NoHealthyEngine",
     "ROUTER_LATENCY_BUCKETS",
     "SpeculativeEngine", "DraftDistiller",
     "TenancyController", "TenantSpec", "TokenBucket", "VisionEngine",
-    "TPServingLM", "tp_serving_model", "tp_serving_specs",
-    "gather_serving_params", "shard_serving_params",
+    *_TP_NAMES,
     "CostModel", "SimulatedEngine", "BUILTIN_SCENARIOS",
     "compile_scenario", "load_scenario", "list_scenarios",
     "Autoscaler", "BlockPool", "RadixPrefixCache",

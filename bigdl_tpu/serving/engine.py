@@ -105,14 +105,11 @@ serving):
   surfaces the snapshot: slot occupancy, queue depth/buckets, p50/p95
   decode latency, deadline misses, sheds, retries, watchdog trips.
 
-The engine is model-agnostic over anything exposing
-`init_block_pool(num_blocks, block_size, dtype)` /
-`prefill_paged(variables, tokens, pools, table, block_ids, start)` /
-`decode_step_paged(variables, tokens, pos, pools, table)` whose pools
-are a pytree of block-leading leaves — `(num_blocks, block_size, ...)`,
-blocks on axis 0 and nothing here looks past it (and, optionally,
-`serving_params(variables)` for a fast weight layout) — the paged
-trio models/transformer.py implements.
+The engine serves any `ServedModel` (serving/protocol.py: every name
+it asks of a model, with its arguments, its result and its default,
+is written there and nowhere else) and refuses what is not one. Of a
+pool's leaves it knows the leading axis and the entry's cache kind,
+and nothing past them.
 
 Tensor-parallel sharding (ISSUE 10): `tp_mesh=` swaps the model for
 the memoized `serving/tp.py` wrapper — weights and the per-layer KV
@@ -163,6 +160,7 @@ from bigdl_tpu.serving.bucketing import (bucket_for, bucket_histogram,
                                          default_buckets, pad_tokens)
 from bigdl_tpu.serving.kv_pool import BlockPool
 from bigdl_tpu.serving.prefix_cache import RadixPrefixCache
+from bigdl_tpu.serving.protocol import ServedModel
 from bigdl_tpu.serving.request_queue import RequestQueue
 from bigdl_tpu.serving.sampler import (SAMPLER_PATHS, sample_logits,
                                        step_path)
@@ -473,14 +471,16 @@ class InferenceEngine:
                  weight_dtype: str = "fp32",
                  model_tag: Optional[str] = None,
                  tenant_kv_quotas: Optional[Dict[str, int]] = None):
-        check = getattr(model, "check_serving_options", None)
-        if check is not None:
-            # a model that does not serve under every option says so
-            # here, before anything is built (models/latent_moe.py,
-            # models/window_moe.py)
-            check(weight_dtype=weight_dtype, tp=tp_mesh is not None,
-                  prefix_cache=bool(prefix_cache), spill=bool(spill),
-                  role=role)
+        if not isinstance(model, ServedModel):
+            raise TypeError(
+                f"{type(model).__name__} is not a ServedModel: what the "
+                "engine asks of a model is serving/protocol.py's, and a "
+                "model derives from it")
+        # what the model does not serve under is refused here, before
+        # anything is built
+        model.check_serving_options(
+            weight_dtype=weight_dtype, tp=tp_mesh is not None,
+            prefix_cache=bool(prefix_cache), spill=bool(spill), role=role)
         if tp_mesh is not None:
             # memoized: engines over the same (model, mesh, axis)
             # share one wrapper and therefore every jitted executable
@@ -489,7 +489,7 @@ class InferenceEngine:
             from bigdl_tpu.serving.tp import tp_serving_model
 
             model = tp_serving_model(model, tp_mesh, tp_axis)
-        elif getattr(model, "tp_axis", None) is not None:
+        elif model.tp_axis is not None:
             # a training-TP model's paged trio would trace
             # tp_shard_gather's all_gather with no mesh bound to the
             # axis — a cryptic deep-trace failure; refuse here with
@@ -556,9 +556,8 @@ class InferenceEngine:
         self.tenant_kv_quotas = dict(tenant_kv_quotas or {})
         self._quota_noted: set = set()
         self.model = model
-        # tp degree for telemetry/provenance (1 = unsharded); the
-        # serving/tp.py wrapper carries it, plain models don't
-        self.tp = int(getattr(model, "tp", 1))
+        # tp degree for telemetry/provenance (1 = unsharded)
+        self.tp = int(model.tp)
         self.variables = variables if variables is not None \
             else model.variables
         # one-time repack into the per-layer serving layout (stacked
@@ -614,33 +613,20 @@ class InferenceEngine:
             raise ValueError("admit_requeue_budget must be >= 1")
         self.admit_requeue_budget = admit_requeue_budget
         self._admit_fails: Dict[int, int] = {}
-        # the KIND of each entry of the model's pool tuple, asked of
-        # the model (`cache_kinds`; a model without the method has
-        # "table" entries only): "table" rows live in blocks the slot's
-        # table names, allocated, grown and released below; "ring" rows
-        # in a region of the leaf the slot owns for good
-        # (ops/kv_cache.init_ring_pool), `_ring_blocks` blocks of it,
-        # which nothing here allocates or releases; a "state" entry is
-        # one row a slot, with no position axis (models/cca_moe.py):
-        # the prefill sets the slot's whole row, the decode step
-        # rewrites it, `_release_slot` scrubs a poisoned request's.
-        # What indexes every leaf by a TABLE block id (spill, handoff,
-        # migration, the prefix tree) is for table-only models, and a
-        # model with a ring or a state refuses it
-        # (`check_serving_options`, `import_handoff`)
-        kinds = getattr(model, "cache_kinds", None)
-        self._cache_kinds = kinds() if kinds is not None else None
-        self._ring_blocks = model.ring_blocks(block_size) \
-            if self._cache_kinds and "ring" in self._cache_kinds else 0
-        # bytes ONE seated slot keeps in the model's "state" entries
-        # (a leaf of one may follow the cache's dtype:
-        # models/hybrid_ssm.py)
-        self._slot_state_bytes = model.slot_state_bytes(cache_dtype) \
-            if self._cache_kinds and "state" in self._cache_kinds else 0
-        self.pool = model.init_block_pool(
-            pool_blocks, block_size, cache_dtype,
-            **({"slots": slots} if self._ring_blocks
-               or self._slot_state_bytes else {}))
+        # the KIND of each entry of the model's pool tuple ("table",
+        # "ring", "state": serving/protocol.py, "the cache"). Blocks of
+        # a "table" entry are allocated, grown and released below; a
+        # slot's ring (`_ring_blocks` blocks) and its state row
+        # (`_slot_state_bytes`) are its own for good: nothing here
+        # allocates them, `_release_slot` scrubs a poisoned request's
+        # state. What indexes every leaf by a TABLE block id (spill,
+        # handoff, migration, the prefix tree) the model has refused
+        # for them above
+        self._cache_kinds = tuple(model.cache_kinds())
+        self._ring_blocks = model.ring_blocks(block_size)
+        self._slot_state_bytes = model.slot_state_bytes(cache_dtype)
+        self.pool = model.init_block_pool(pool_blocks, block_size,
+                                          cache_dtype, slots=slots)
         self._pool_mgr = BlockPool(pool_blocks, block_size)
         self._prefix = RadixPrefixCache(self._pool_mgr,
                                         host_blocks=self.host_blocks)
@@ -649,8 +635,7 @@ class InferenceEngine:
         # — model-agnostic
         self._kv_bytes_per_token = int(sum(
             leaf.nbytes // leaf.shape[0]
-            for kind, layer in zip(
-                self._cache_kinds or ("table",) * len(self.pool), self.pool)
+            for kind, layer in zip(self._cache_kinds, self.pool)
             if kind != "state"      # a slot's row, not a token's
             for leaf in jax.tree_util.tree_leaves(layer))
             // block_size)
@@ -830,7 +815,7 @@ class InferenceEngine:
         # hybrid_ssm.py; no other has any): counted once an admission,
         # whether or not the tracer records
         self._scan_chunks = {
-            b: self._prefill_span_args(b).get("scan_chunks", 0)
+            b: model.prefill_span_args(b).get("scan_chunks", 0)
             for b in self.buckets}
         self._m_scan_chunks = reg.counter(
             "serving_prefill_scan_chunks_total",
@@ -891,9 +876,7 @@ class InferenceEngine:
         shard_serving_params), then the int8 block-leaf repack when
         quantized (serving/quant.py). The constructor and
         `swap_params` run the IDENTICAL build — one spine, no drift."""
-        params = self.model.serving_params(variables) \
-            if hasattr(self.model, "serving_params") \
-            else variables["params"]
+        params = self.model.serving_params(variables)
         if self.weight_dtype == "int8":
             from bigdl_tpu.serving.quant import quantize_serving_params
 
@@ -1444,10 +1427,9 @@ class InferenceEngine:
                     [d[li][k] for d in datas])))
                  for k, leaf in layer.items()}
                 for li, layer in enumerate(self.pool))
-            if hasattr(self.model, "place_pools"):
-                # keep the tp head-axis placement through the eager
-                # scatter, like import_handoff does
-                self.pool = self.model.place_pools(self.pool)
+            # keep the tp head-axis placement through the eager
+            # scatter, like import_handoff does
+            self.pool = self.model.place_pools(self.pool)
             for b in new:
                 self._pool_mgr.mark_cached(b)
             self._bump("kv_readmit_blocks", len(new))
@@ -1752,7 +1734,7 @@ class InferenceEngine:
                          prefix_tokens=int(start), fenced=True,
                          launched_s=launched_s,
                          **self._expert_matmul(int(b)),
-                         **self._prefill_span_args(int(b)))
+                         **self.model.prefill_span_args(int(b)))
         with self._span("seat_commit", cat=_ADMIT_PARTS) as part:
             if part.id is not None:
                 part.set(request=req.id)
@@ -1792,14 +1774,8 @@ class InferenceEngine:
         platform and the static shapes, so a label like `attn_form`),
         for `health()` and the `decode_step` and `prefill` spans.
         Nothing for a model without experts."""
-        form = getattr(self.model, "expert_matmul_form", None)
-        return {} if form is None else {
-            "expert_matmul": form(self._params, tokens)}
-
-    def _prefill_span_args(self, bucket: int) -> dict:
-        """What the model adds to a recorded `prefill` span."""
-        extra = getattr(self.model, "prefill_span_args", None)
-        return extra(bucket) if extra is not None else {}
+        form = self.model.expert_matmul_form(self._params, tokens)
+        return {} if form is None else {"expert_matmul": form}
 
     def _finish(self, slot: int, reason: str,
                 status: str = "done") -> GenerationResult:
@@ -1880,15 +1856,13 @@ class InferenceEngine:
         (ops/kv_cache.write_prompt_ring). A state leaf has none
         either: `_release_slot` zeroes the poisoned slot's row."""
         idx = jnp.asarray(blocks, jnp.int32)
-        kinds = self._cache_kinds or ("table",) * len(self.pool)
         self.pool = tuple(
             jax.tree_util.tree_map(
                 lambda leaf: leaf.at[idx].set(jnp.zeros((), leaf.dtype)),
                 layer) if kind == "table" else layer
-            for kind, layer in zip(kinds, self.pool))
-        if hasattr(self.model, "place_pools"):
-            # keep the tp head-axis placement through the eager scrub
-            self.pool = self.model.place_pools(self.pool)
+            for kind, layer in zip(self._cache_kinds, self.pool))
+        # keep the tp head-axis placement through the eager scrub
+        self.pool = self.model.place_pools(self.pool)
 
     def _cache_consumed(self) -> bool:
         """True if any pool leaf's buffer was donated/deleted by a
@@ -2317,7 +2291,8 @@ class InferenceEngine:
         if self._ring_blocks or self._slot_state_bytes:
             raise NotImplementedError(
                 "import_handoff into an engine whose model keeps ring "
-                "or state leaves: a package carries table blocks only")
+                "or state leaves: a package carries table blocks, and "
+                f"{self.model.kept_outside_blocks()}")
         if self._degraded:
             raise EngineDegraded(
                 f"engine degraded ({self._degraded}); hand off to a "
@@ -2393,10 +2368,9 @@ class InferenceEngine:
             {k: leaf.at[idx].set(jnp.asarray(pkg.kv[li][k][nh:]))
              for k, leaf in layer.items()}
             for li, layer in enumerate(self.pool))
-        if hasattr(self.model, "place_pools"):
-            # host-side scatter may drop the tp head-axis placement —
-            # re-commit so the jitted steps keep their shardings
-            self.pool = self.model.place_pools(self.pool)
+        # host-side scatter may drop the tp head-axis placement —
+        # re-commit so the jitted steps keep their shardings
+        self.pool = self.model.place_pools(self.pool)
         self._point_table_row(slot, hit, new)
         self._seat_slot(slot, req, hit, new)
         self._meta[req.id] = {"t": pkg.submit_t}
@@ -2469,8 +2443,7 @@ class InferenceEngine:
         visible and gathered, by cache kind), and what the step reads
         of the seated slots' state and writes again (`state_bytes`,
         where the model keeps one)."""
-        report = getattr(self.model, "decode_read_report", None)
-        out = {} if report is None else report(
+        out = self.model.decode_read_report(
             self._pos, self._table, self.block_size)
         if self._slot_state_bytes:
             out["state_bytes"] = self._slot_state_held()

@@ -39,7 +39,7 @@ Per-engine constructor choice (`InferenceEngine(weight_dtype=
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +79,19 @@ def quantize_weight(w: jax.Array, axis: int = 0) -> QuantWeight:
     (nn/quantized scheme: scale = max|w| / 127 over `axis`)."""
     q, scale = _quantize_weight(w, axis)
     return QuantWeight(q, scale)
+
+
+def why_not(model) -> Optional[str]:
+    """Why this module does not repack `model`'s serving weights, or
+    None where it does (`ServedModel.serving_refusals` asks): it knows
+    the block leaves `TransformerLM.serving_params` returns
+    (`_BLOCK_GEMMS`) and no other layout."""
+    from bigdl_tpu.models.transformer import TransformerLM
+
+    if isinstance(model, TransformerLM):
+        return None
+    return ("serving/quant.py repacks the block leaves of an unsharded "
+            "TransformerLM's serving_params and knows no other layout")
 
 
 def quantize_serving_params(params):

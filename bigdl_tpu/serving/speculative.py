@@ -173,9 +173,7 @@ class SpeculativeEngine:
             if eng.degraded:
                 raise ValueError(f"{name} engine is already degraded "
                                  f"({eng.degraded})")
-            check = getattr(eng.model, "check_serving_options", None)
-            if check is not None:
-                check(speculative=True)
+            eng.model.check_serving_options(speculative=True)
         if draft is target:
             raise ValueError("draft and target must be distinct "
                              "engines (self-speculation would pay the "

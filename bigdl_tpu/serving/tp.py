@@ -90,6 +90,7 @@ from bigdl_tpu.parallel.param_layout import (gather_tree,
                                              tp_serving_block_specs,
                                              tp_serving_specs)
 from bigdl_tpu.parallel.tensor_parallel import shard_params
+from bigdl_tpu.serving.protocol import ServedModel
 
 
 def gather_serving_params(params):
@@ -110,12 +111,25 @@ def shard_serving_params(mesh: Mesh, params, axis: str = "model"):
     return shard_params(mesh, tp_serving_specs(params, axis), params)
 
 
-class TPServingLM:
-    """Drop-in sharded serving backend: duck-types the paged trio
-    (`init_block_pool` / `prefill_paged` / `decode_step_paged`) plus
-    `serving_params`, so `InferenceEngine` serves through it unchanged
-    — the engine's jitted steps take it as their static `model`
-    argument and trace shard_map'd bodies instead of single-mesh ones.
+def why_not(model) -> Optional[str]:
+    """Why this module does not wrap `model`, or None where it does
+    (`ServedModel.serving_refusals` asks): it splits `TransformerLM`'s
+    K and V pools by head and shards its block leaves, and knows no
+    other pool and no other layout."""
+    if isinstance(model, (TransformerLM, TPServingLM)):
+        return None
+    return ("serving/tp.py splits TransformerLM's K and V pools by "
+            "head and shards its block leaves; it knows no other "
+            "model's")
+
+
+class TPServingLM(ServedModel):
+    """Drop-in sharded serving backend: a `ServedModel`
+    (serving/protocol.py) whose paged trio, `serving_params` and
+    `place_pools` are the wrapped model's on the mesh, so
+    `InferenceEngine` serves through it unchanged — the engine's
+    jitted steps take it as their static `model` argument and trace
+    shard_map'd bodies instead of single-mesh ones.
 
     Divisibility: `num_heads % tp == 0` (head-parallel attention) and
     `(dim * mlp_ratio) % tp == 0` (ffn column split). MoE and
@@ -176,11 +190,12 @@ class TPServingLM:
         return shard_params(self.mesh, self._param_specs(sp), sp)
 
     def init_block_pool(self, num_blocks: int, block_size: int,
-                        dtype=jnp.float32):
+                        dtype=jnp.float32, slots: int = 1):
         """The per-layer paged pools, head-sharded on the mesh: each
         shard holds (num_blocks, block_size, (H/tp)*D) per layer —
         1/tp KV residency, the serving memory win. Block ids/tables
-        are untouched host integers, identical across shards."""
+        are untouched host integers, identical across shards. `slots`
+        is not used (every entry is a "table")."""
         pools = self.model.init_block_pool(num_blocks, block_size,
                                            dtype)
         return self.place_pools(pools)

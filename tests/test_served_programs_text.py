@@ -1,4 +1,4 @@
-"""The lowered text of the three older served families' decode and prefill
+"""The lowered text of the five served families' decode and prefill
 programs at toy sizes, pinned by its sha256.
 
 The PREFILL hashes are the parent's parent's (ac7b450) still, letter for
@@ -10,7 +10,9 @@ they were: they do not call the ragged core. The DECODE hashes of the
 programs that do call it (afmoe, mla_moe, and gpt2 at a width that takes
 the rows form) are taken from ISSUE 39's change, on the parent ad46bad: one
 more branch of the one `lax.switch` a layer. gpt2's toy decode (dim 32:
-the head-split form, no ragged core) is ac7b450's still.
+the head-split form, no ragged core) is ac7b450's still. The cca_moe and
+granite_hybrid hashes (the "state" cache kind) are ISSUE 48's, taken from
+its parent 883be96 before the protocol's moves were made.
 
 To take the hashes of another checkout (the parent's, say), run this file
 there: `cd <checkout> && PYTHONPATH=. python <this file>` prints them as
@@ -36,6 +38,10 @@ PARENTS = {
     ("gpt2", "decode"): "4fb2103bb9d60ab3",
     ("gpt2", "prefill"): "cc70aade132de162",
     ("gpt2_rows", "decode"): "f15800e0430e101b",
+    ("cca_moe", "decode"): "4853094ded5b6eee",
+    ("cca_moe", "prefill"): "bc05c35bc0b08507",
+    ("granite_hybrid", "decode"): "7805fb1a4f471208",
+    ("granite_hybrid", "prefill"): "3cf0aebf9a3e4bb9",
 }
 
 
@@ -59,11 +65,10 @@ def _toy(family):
                               prefill_buckets=(16, 32))
         return (eng.model, jax.eval_shape(lambda: eng._params),
                 jax.eval_shape(lambda: eng.pool), _vec(i32, 8))
-    from benchmarks.families import afmoe, mla_moe
+    import importlib
 
-    fam, tiny = {"afmoe": (afmoe, "tiny_afmoe/configs/tiny-afmoe.json"),
-                 "mla_moe": (mla_moe,
-                             "tiny_mla_moe/configs/tiny-mla-moe.json")}[family]
+    fam = importlib.import_module(f"benchmarks.families.{family}")
+    tiny = f"tiny_{family}/configs/tiny-{family.replace('_', '-')}.json"
     with open(os.path.join(REPO, "tests", "bench", tiny)) as f:
         cfg = json.load(f)
     model = fam.program_model(cfg)
@@ -73,6 +78,10 @@ def _toy(family):
             lambda: model.init_block_pool(33, 4, jnp.float32)), _vec(i32, 8))
     pools = jax.eval_shape(
         lambda: model.init_block_pool(33, 4, jnp.float32, slots=3))
+    if family in ("cca_moe", "granite_hybrid"):
+        return model, params, pools, {
+            "table": _vec(i32, 8),
+            "state": {"slot": _vec(i32), "keep": _vec(i32)}}
     return model, params, pools, {
         "table": _vec(i32, 8),
         "ring": {"slot": _vec(i32),
