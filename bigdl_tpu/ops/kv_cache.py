@@ -386,7 +386,21 @@ def paged_attention_form(num_heads: int, head_dim: int) -> str:
     not whole tiles are the compiler's business already
     (`init_block_pool`). Static per compiled program: the engine
     reports it as a label (`health()["attn_form"]`, the `round`
-    span)."""
+    span).
+
+    This is `paged_attention`'s rule, `TransformerLM`'s read. The list
+    models' rows are grouped-query rows and they read them through
+    `grouped_paged_attention`, the rows form at ANY head width, because
+    what that form buys is not the padding alone: it reads each slot's
+    live chunks where the head-split form gathers the table's full
+    extent, relays it by head and widens it to float32. At 16 heads of
+    128 (no padding either way), 16 slots of 48 blocks at mixed depths
+    (the half read), bfloat16 rows, one layer's read took 0.247 ms in
+    the grouped rows form and 0.951 ms in the head-split form (chip
+    readings, PERF.md, PR 49: the first model with 128-wide heads,
+    `models/loop_lm.py`, 192 such reads a step). The head-split form
+    stays `TransformerLM`'s for a 128-wide head: it is the dense
+    `cached_attention` bit for bit, which its tests hold it to."""
     rows = (num_heads * head_dim) % 128 == 0 and head_dim % 128 != 0
     return "rows" if rows else "heads"
 

@@ -1015,6 +1015,9 @@ class InferenceEngine:
             # and which form the decode program's grouped expert
             # matmuls take (no key: a model without experts)
             **self._expert_matmul(self.slots),
+            # what the model says of itself (serving/protocol.py: a
+            # looped model's passes and row sets; most say nothing)
+            **self.model.health_report(),
             # the share of the block table a decode step reads at the
             # slots' current clocks
             "attended_share": round(

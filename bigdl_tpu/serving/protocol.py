@@ -7,10 +7,11 @@ names below and by no other, and never through `getattr`: a model that
 derives from `ServedModel` answers every one, with the default where
 it has nothing of its own to say, and a model that does not derive
 from it is refused at construction. `TransformerLM`, `LatentMoELM`,
-`WindowMoELM`, `CCAMoELM`, `HybridSSMLM` and `serving/tp.TPServingLM`
-implement it. This module imports nothing of the package at import
-time (`models/` imports it; it asks `serving/quant.py` and
-`serving/tp.py` only inside `serving_refusals`).
+`WindowMoELM`, `CCAMoELM`, `HybridSSMLM`, `LoopLM` and
+`serving/tp.TPServingLM` implement it. This module imports nothing of
+the package at import time (`models/` imports it; it asks
+`serving/quant.py` and `serving/tp.py` only inside
+`serving_refusals`).
 """
 
 from __future__ import annotations
@@ -83,7 +84,10 @@ class ServedModel:
     float32 answer), 0 exactly where no entry is a "state".
     `init_block_pool(num_blocks, block_size, dtype, slots=1)` -> the
     pools, a tuple of dicts of leaves: a "table" leaf `(num_blocks,
-    block_size, ...)` with block 0 scratch, a "ring" leaf `(1 + slots *
+    block_size, ...)` with block 0 scratch (blocks are axis 0 for
+    every holder of a pool; `import_handoff` alone reads the block
+    size off axis 1, and a model whose leaves put something else there,
+    `LoopLM`'s passes, names `role` in `unserved`), a "ring" leaf `(1 + slots *
     ring_blocks, block_size, ...)`, a "state" leaf `(slots, ...)`; the
     engine always passes `slots=`, a table-only model ignores it.
     `place_pools(pools)` -> the pools, committed again to the placement
@@ -123,8 +127,13 @@ class ServedModel:
     `routed_rows`). `decode_read_report(pos, table, block_size)` ->
     arguments of the `decode_step` span from the host's clocks and
     table (`window_rows`, `full_rows`, `attended_rows`); the engine
-    adds `state_bytes` for a model with a state. Defaults: `{}`. The
-    experts' three are `parallel/moe.ExpertsReport`'s."""
+    adds `state_bytes` for a model with a state; a model whose layers
+    run several times adds `ut_steps`, `cache_entries` and
+    `weight_bytes_streamed` (models/loop_lm.py). Defaults: `{}`. The
+    experts' three are `parallel/moe.ExpertsReport`'s.
+    `health_report()` -> keys the model adds to the engine's
+    `health()` (asked at every `health()`; `ut_steps`,
+    `cache_entries`). Default: `{}`."""
 
     tp = 1
     tp_axis = None
@@ -226,4 +235,7 @@ class ServedModel:
         return {}
 
     def decode_read_report(self, pos, table, block_size: int) -> dict:
+        return {}
+
+    def health_report(self) -> dict:
         return {}

@@ -1,4 +1,4 @@
-"""The lowered text of the five served families' decode and prefill
+"""The lowered text of the six served families' decode and prefill
 programs at toy sizes, pinned by its sha256.
 
 The PREFILL hashes are the parent's parent's (ac7b450) still, letter for
@@ -12,7 +12,10 @@ the rows form) are taken from ISSUE 39's change, on the parent ad46bad: one
 more branch of the one `lax.switch` a layer. gpt2's toy decode (dim 32:
 the head-split form, no ragged core) is ac7b450's still. The cca_moe and
 granite_hybrid hashes (the "state" cache kind) are ISSUE 48's, taken from
-its parent 883be96 before the protocol's moves were made.
+its parent 883be96 before the protocol's moves were made. The loop_lm
+hashes are ISSUE 49's own, the PR that brought the model (its parent has
+none to take): they pin the looped programs for the PRs after it, and the
+eleven above did not move under it.
 
 To take the hashes of another checkout (the parent's, say), run this file
 there: `cd <checkout> && PYTHONPATH=. python <this file>` prints them as
@@ -42,6 +45,8 @@ PARENTS = {
     ("cca_moe", "prefill"): "bc05c35bc0b08507",
     ("granite_hybrid", "decode"): "7805fb1a4f471208",
     ("granite_hybrid", "prefill"): "3cf0aebf9a3e4bb9",
+    ("loop_lm", "decode"): "0720d75c11b74fd3",
+    ("loop_lm", "prefill"): "01e1f27897b61758",
 }
 
 
@@ -73,7 +78,7 @@ def _toy(family):
         cfg = json.load(f)
     model = fam.program_model(cfg)
     params = jax.eval_shape(lambda: fam.make_variables(5, cfg)["params"])
-    if family == "mla_moe":
+    if family in ("mla_moe", "loop_lm"):    # tables only: plain block ids
         return (model, params, jax.eval_shape(
             lambda: model.init_block_pool(33, 4, jnp.float32)), _vec(i32, 8))
     pools = jax.eval_shape(

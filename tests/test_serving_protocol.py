@@ -1,5 +1,5 @@
 """`serving/protocol.ServedModel`: what the engine asks of a model, asked
-of each of the six classes that implement it, at toy sizes.
+of each of the seven classes that implement it, at toy sizes.
 
 Every name of the protocol answers with the documented type; the cache
 kinds, the ring and the state agree with the pool the model builds; the
@@ -26,14 +26,15 @@ from bigdl_tpu.serving import InferenceEngine, ServedModel, SpeculativeEngine
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK, SLOTS, BLOCKS = 4, 2, 9
 FAMILIES = {"mla_moe": "LatentMoELM", "afmoe": "WindowMoELM",
-            "cca_moe": "CCAMoELM", "granite_hybrid": "HybridSSMLM"}
+            "cca_moe": "CCAMoELM", "granite_hybrid": "HybridSSMLM",
+            "loop_lm": "LoopLM"}
 MODELS = ["gpt2", "gpt2_tp2", *FAMILIES]
 # what shares, moves or rolls back TABLE blocks
 BLOCK_OPTIONS = {"speculative", "prefix_cache", "spill", "role"}
 
 
 def _built(name):
-    """(model, variables as shapes) of one of the six at a toy size."""
+    """(model, variables as shapes) of one of the seven at a toy size."""
     if name.startswith("gpt2"):
         from bigdl_tpu.models.transformer import build_lm
         from bigdl_tpu.parallel import make_mesh
@@ -61,7 +62,7 @@ def served(request):
     return (request.param,) + _built(request.param)
 
 
-def test_the_six_classes_derive_from_the_protocol(served):
+def test_the_seven_classes_derive_from_the_protocol(served):
     name, model, _ = served
     assert isinstance(model, ServedModel)
     assert type(model).__name__ == FAMILIES.get(
@@ -137,9 +138,15 @@ def test_the_weights_and_the_programs_answer(served):
     table[0, :2] = (1, 2)
     read = model.decode_read_report(pos, table, BLOCK)
     assert isinstance(read, dict)
-    assert set(read) in (set(), {"window_rows", "full_rows",
-                                 "attended_rows"})
+    rows = {"window_rows", "full_rows", "attended_rows"}
+    # a model whose layers run several times says so beside the rows
+    loop = {"ut_steps", "cache_entries", "weight_bytes_streamed"}
+    assert set(read) in (set(), rows, rows | loop)
     assert all(isinstance(v, int) for v in read.values())
+    health = model.health_report()
+    assert isinstance(health, dict)
+    assert set(health) == ({"ut_steps", "cache_entries"}
+                           if loop <= set(read) else set())
 
 
 def test_the_refusals_follow_from_the_cache_kinds(served):
@@ -152,11 +159,14 @@ def test_the_refusals_follow_from_the_cache_kinds(served):
         assert model.kept_outside_blocks() is None
         assert set(refusals) == {
             "gpt2": set(), "gpt2_tp2": {"weight_dtype"},
-            "mla_moe": {"weight_dtype", "tp", "speculative"}}[name]
-        assert refusals.get("speculative") \
-            == type(model).unserved.get("speculative")
-        model.check_serving_options(prefix_cache=True, spill=True,
-                                    role="prefill")
+            "mla_moe": {"weight_dtype", "tp", "speculative"},
+            "loop_lm": {"weight_dtype", "tp", "role"}}[name]
+        for option in ("speculative", "role"):
+            assert refusals.get(option) \
+                == type(model).unserved.get(option)
+        model.check_serving_options(
+            prefix_cache=True, spill=True,
+            role="both" if "role" in refusals else "prefill")
         return
     # a ring and a state refuse the same four, each for its own reason
     assert set(refusals) == BLOCK_OPTIONS | {"weight_dtype", "tp"}
@@ -180,7 +190,7 @@ def test_the_refusals_follow_from_the_cache_kinds(served):
     model.check_serving_options()       # the defaults are served
 
 
-def test_the_engine_takes_each_of_the_six(served):
+def test_the_engine_takes_each_of_the_seven(served):
     name, model, variables = served
     real = jax.tree_util.tree_map(
         lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), variables)
@@ -193,6 +203,7 @@ def test_the_engine_takes_each_of_the_six(served):
     assert eng._slot_state_bytes == model.slot_state_bytes(jnp.float32)
     assert eng.tp == model.tp
     assert ("expert_matmul" in eng.health()) == hasattr(model, "moe")
+    assert model.health_report().items() <= eng.health().items()
 
 
 class _Ducks:
@@ -231,6 +242,7 @@ def test_the_defaults_are_a_table_only_model_with_nothing_to_report():
     assert bare.expert_matmul_form({}, 4) is None
     assert bare.prefill_span_args(16) == {} == bare.decode_aux_report(None)
     assert bare.decode_read_report(np.zeros(1), np.zeros((1, 4)), 4) == {}
+    assert bare.health_report() == {}
     assert bare.kept_outside_blocks() is None
     # neither quant.py nor tp.py knows its leaves, and both say so
     assert set(bare.serving_refusals()) == {"weight_dtype", "tp"}
