@@ -150,7 +150,8 @@ def train_leg(clock, *, vocab, dim, layers, heads, seq, batch, steps,
     from bigdl_tpu.dataset import DataSet
     from bigdl_tpu.dataset.text import synthetic_next_token
     from bigdl_tpu.models.transformer import TransformerConfig, TransformerLM
-    from bigdl_tpu.ops.flash_attention import _resolve_impl_and_blocks
+    from bigdl_tpu.ops.flash_attention import (_default_impl,
+                                               flash_attention_plan)
     from bigdl_tpu.optim import Adam, Optimizer, Trigger
 
     leg = Leg(name, clock)
@@ -160,13 +161,13 @@ def train_leg(clock, *, vocab, dim, layers, heads, seq, batch, steps,
     model = TransformerLM(cfg, attn_impl=attn_impl)
     model.build(jax.random.PRNGKey(0))
 
-    # which attention will the step trace? Ask the resolver the model
-    # asks, at the shape the step uses (per-device batch under a mesh)
+    # which attention will the step trace? Ask what the model asks, at
+    # the shape the step uses (per-device batch under a mesh)
     local_batch = batch // (mesh.size if mesh is not None else 1)
-    qkv = jax.ShapeDtypeStruct((local_batch, heads, seq, dim // heads),
-                               jnp.bfloat16)
-    impl, block_q, block_k = _resolve_impl_and_blocks(qkv, qkv, None, None,
-                                                      attn_impl)
+    impl = attn_impl or _default_impl()
+    plan = flash_attention_plan(seq, seq, dim // heads, local_batch * heads,
+                                2, True)
+    block_q, block_k = plan.block_q, plan.block_k
     leg.check(impl == expect_attn,
               f"attention impl resolved to {impl!r}, expected "
               f"{expect_attn!r}")

@@ -162,6 +162,14 @@ def main():
     flops_tok = lm_matmul_flops_per_token(cfg)
     report["train_flops_per_token"] = flops_tok
 
+    # what the Mosaic attention of this model compiles to: tiles, cells,
+    # the backward's form, how far the causal skip engages
+    from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+    plan = flash_attention_plan(S, S, D, B * H, 2, cfg.causal)
+    report["flash_attention_plan"] = plan._asdict()
+    print(json.dumps({"flash_attention_plan": plan._asdict()}), flush=True)
+
     # ---- loss on logits ---------------------------------------------
     def lm_loss(p, tokens, targets):
         pc = policy.cast_to_compute(p)

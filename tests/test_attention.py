@@ -111,6 +111,202 @@ class TestFlashKernel:
                                    atol=2e-5, rtol=2e-5)
 
 
+def _walk_qkv(sq, sk, dim, seed=7):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(2, n, dim), jnp.float32)
+                 for n in (sq, sk, sk))
+
+
+class TestCausalWalk:
+    """The kernels walk the tiles of the score square inside a grid
+    cell: only those at or under the diagonal, only the straddling ones
+    masked, at the head's own width where that is 64. Several tiles a
+    row at 128-row tiles, in one cell a batch-head (static trip counts)
+    and in 2 x 2 cells (trip counts from program_id, clamped index
+    maps)."""
+
+    @pytest.mark.parametrize("cell_rows", [1024, 256],
+                             ids=["one_cell", "four_cells"])
+    @pytest.mark.parametrize("dim", [64, 128, 80])
+    @pytest.mark.parametrize("sq,sk", [(512, 512), (256, 512)])
+    def test_forward_lse_and_grads(self, monkeypatch, sq, sk, dim,
+                                   cell_rows):
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_MAX_CELL_ROWS", cell_rows)
+        monkeypatch.setattr(fa, "_LONG_CELL_ROWS", cell_rows)
+        q, k, v = _walk_qkv(sq, sk, dim)
+        tiles = dict(block_q=128, block_k=128)
+        ref, lse_ref = attention_reference(q, k, v, causal=True,
+                                           return_lse=True)
+        out, lse = flash_attention_with_lse(q, k, v, causal=True,
+                                            impl="interpret", **tiles)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   atol=2e-5, rtol=2e-5)
+
+        def loss(attention):
+            return lambda q, k, v: jnp.sum(jnp.cos(attention(q, k, v)))
+
+        g1 = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, impl="interpret", bwd_tiles=(128, 128),
+            **tiles)), argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(lambda q, k, v: attention_reference(
+            q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(g1, g2, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("sq,sk", [(384, 384), (256, 384)])
+    def test_a_long_sequence_takes_one_tile_a_cell(self, monkeypatch, sq,
+                                                   sk):
+        # past _MAX_CELL_ROWS the plan's own tiles are the cell's rows
+        # (the chip table at 16 x 8,192: a walk inside cells of 1,024
+        # lost to the parent's grid of whole tiles); here 3 x 3 cells
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_MAX_CELL_ROWS", 128)
+        monkeypatch.setattr(fa, "_LONG_CELL_ROWS", 128)
+        plan = fa.flash_attention_plan(sq, sk, 64, 2, 4, True)
+        assert (plan.block_q, plan.block_k, plan.cell_q, plan.cell_k,
+                plan.bwd_block_q, plan.bwd_block_k, plan.bwd_cell_q,
+                plan.bwd_cell_k) == (128,) * 8
+        q, k, v = _walk_qkv(sq, sk, 64)
+
+        def loss(attention):
+            return lambda q, k, v: jnp.sum(jnp.cos(attention(q, k, v)))
+
+        g1 = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, impl="interpret")),
+            argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(lambda q, k, v: attention_reference(
+            q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(g1, g2, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("sq,sk,block", [(384, 384, 128),
+                                             (200, 330, 128),
+                                             (256, 256, 256)])
+    def test_non_causal_visits_every_tile(self, sq, sk, block):
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+        plan = flash_attention_plan(sq, sk, 64, 2, 4, False, block, block)
+        assert plan.kv_tiles_visited == plan.kv_tiles_total
+        # only the tiles that hold the padded columns take a mask
+        assert plan.kv_tiles_masked == (
+            -(-sq // block) if sk % block else 0)
+        q, k, v = _walk_qkv(sq, sk, 64)
+        out = flash_attention(q, k, v, block_q=block, block_k=block,
+                              impl="interpret")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(attention_reference(q, k, v)),
+            atol=2e-5, rtol=2e-5)
+
+
+class TestPlan:
+    """`flash_attention_plan`: the one place a call's tiles come from,
+    and the counter of how far the causal skip engages."""
+
+    def test_the_train_cell(self):
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+        plan = flash_attention_plan(1024, 1024, 64, 128, 2, True)
+        assert plan.head_pad == 0
+        # the backward goes by the area it visits: 10 of 16 tiles. The
+        # forward pays a segment more than the tiles it skips: the chip
+        # table chose 512-row q tiles, 3 of 4 (PERF.md, PR 50)
+        assert plan.bwd_tiles_visited / plan.bwd_tiles_total <= 0.65
+        assert plan.kv_tiles_visited / plan.kv_tiles_total <= 0.75
+        assert 0 < plan.kv_tiles_masked < plan.kv_tiles_visited
+        assert 0 < plan.bwd_tiles_masked < plan.bwd_tiles_visited
+        assert plan.bwd_form == "fused"
+        # one cell a batch-head: a static walk
+        assert (plan.cell_q, plan.cell_k) == (1024, 1024)
+        assert (plan.bwd_cell_q, plan.bwd_cell_k) == (1024, 1024)
+        flat = flash_attention_plan(1024, 1024, 64, 128, 2, False)
+        assert flat.kv_tiles_visited == flat.kv_tiles_total
+        assert flat.bwd_tiles_visited == flat.bwd_tiles_total
+        assert flat.kv_tiles_masked == flat.bwd_tiles_masked == 0
+        # past 4,096 rows: cells of 1,024, one tile each by default,
+        # and an explicit tile is still the size of a tile
+        far = flash_attention_plan(8192, 8192, 64, 16, 2, True)
+        assert (far.block_q, far.cell_q, far.bwd_block_k,
+                far.bwd_cell_k) == (1024,) * 4
+        assert far.kv_tiles_visited / far.kv_tiles_total <= 0.6
+        fine = flash_attention_plan(8192, 8192, 64, 16, 2, True, 256, 256,
+                                    (256, 256))
+        assert (fine.block_q, fine.cell_q, fine.bwd_block_k,
+                fine.bwd_cell_k) == (256, 1024, 256, 1024)
+        # at 256-row forward tiles the walk is the issue's 10 of 16
+        fine = flash_attention_plan(1024, 1024, 64, 128, 2, True, 256, 256)
+        assert (fine.kv_tiles_visited, fine.kv_tiles_total,
+                fine.kv_tiles_masked) == (10, 16, 4)
+
+    @pytest.mark.parametrize("given,env,want", [
+        (dict(), None, (1024, 1024)),
+        (dict(block_q=512, block_k=256), None, (512, 256)),
+        (dict(), (256, 512), (256, 512)),
+        (dict(bwd_tiles=(128, 128)), None, (1024, 1024)),
+    ], ids=["default", "explicit", "env", "bwd_tiles_do_not_apply"])
+    def test_split_form_keeps_the_forward_s_explicit_tiles(
+            self, monkeypatch, given, env, want):
+        # past the resident cap the two-kernel backward tiles at the
+        # forward's explicit tiles (argument, else env snapshot), as it
+        # did before the plan; `bwd_tiles` are the FUSED form's
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+        from bigdl_tpu.utils import envknobs
+
+        monkeypatch.setattr(envknobs, "FLASH_FWD_TILES", env)
+        plan = flash_attention_plan(32768, 32768, 64, 1, 4, True, **given)
+        assert plan.bwd_form == "split"
+        assert (plan.bwd_block_q, plan.bwd_block_k) == want
+        assert (plan.bwd_cell_q, plan.bwd_cell_k) == want
+
+    @pytest.mark.parametrize("dim,pad", [(64, 0), (128, 0), (256, 0),
+                                         (80, 48), (96, 32), (16, 112)])
+    def test_head_pad(self, dim, pad):
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+        assert flash_attention_plan(512, 512, dim, 8, 2,
+                                    True).head_pad == pad
+
+    @pytest.mark.parametrize("sq,sk,visited,masked", [
+        (512, 512, 10, 4),      # the triangle of a 4 x 4 square
+        (256, 512, 7, 2),       # bottom-right alignment: rows see 256 more
+        (512, 256, 3, 2),       # the first 256 rows see nothing at all
+    ])
+    def test_counts_the_triangle(self, sq, sk, visited, masked):
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+        plan = flash_attention_plan(sq, sk, 64, 2, 4, True, 128, 128)
+        assert (plan.kv_tiles_visited, plan.kv_tiles_masked) == (visited,
+                                                                 masked)
+
+    def test_explicit_tiles_and_the_split_form(self):
+        from bigdl_tpu.ops.flash_attention import flash_attention_plan
+
+        plan = flash_attention_plan(2048, 2048, 64, 8, 2, True, 512, 256,
+                                    (256, 512))
+        assert (plan.block_q, plan.block_k) == (512, 256)
+        assert (plan.bwd_block_q, plan.bwd_block_k) == (256, 512)
+        # a short sequence runs one tile, whatever was asked
+        short = flash_attention_plan(100, 100, 64, 8, 2, True, 512, 512)
+        assert (short.block_q, short.cell_q) == (128, 128)
+        # past the resident cap the backward is two kernels at their
+        # own tiles, and `bwd_tiles` does not apply
+        long = flash_attention_plan(32768, 32768, 64, 1, 4, True,
+                                    bwd_tiles=(256, 256))
+        assert long.bwd_form == "split"
+        assert long.bwd_block_q == long.bwd_cell_q == 1024
+        # the split kernels skip what lies above the diagonal and mask
+        # every tile they visit
+        assert long.bwd_tiles_masked == long.bwd_tiles_visited == 528
+        # a sequence past one cell: cells of 1,024 rows
+        assert (long.cell_q, long.cell_k) == (1024, 1024)
+
+
 class TestMultiHeadAttention:
     def test_forward_shape_and_oracle(self):
         m = nn.MultiHeadAttention(32, 4, name="mha")
@@ -303,11 +499,12 @@ class TestFusedBackward:
         q = jnp.asarray(rng.randn(2, 64, 16).astype(np.float32))
         k = jnp.asarray(rng.randn(2, 64, 16).astype(np.float32))
         v = jnp.asarray(rng.randn(2, 64, 16).astype(np.float32))
-        o, lse = fa._flash_fwd_pallas(q, k, v, causal, 0.25, 32, 32,
-                                      interpret=True)
+        o, lse = fa._flash_fwd_pallas(q, k, v, causal, 0.25, 32, 32, 64,
+                                      64, interpret=True)
         do = jnp.asarray(rng.randn(2, 64, 16).astype(np.float32))
         fused = fa._flash_bwd_pallas_fused(q, k, v, o, lse, do, causal,
-                                           0.25, 32, 32, interpret=True)
+                                           0.25, 32, 32, 64, 64,
+                                           interpret=True)
         split = fa._flash_bwd_pallas_split(q, k, v, o, lse, do, causal,
                                            0.25, 32, 32, interpret=True)
         for a, b, name in zip(fused, split, ("dq", "dk", "dv")):
@@ -329,12 +526,13 @@ class TestFusedBackward:
             lambda *a, **k: calls.append("fused") or
             (a[0], a[1], a[2]))
         small = jnp.zeros((1, 128, 64))
-        fa._flash_bwd_pallas(small, small, small, small,
-                             jnp.zeros((1, 128)), small, True, 1.0,
-                             128, 128, True)
-        # 8M / (128 lanes * 4B) = 16384 rows: S beyond that splits
+        fa._flash_bwd_pallas(
+            small, small, small, small, jnp.zeros((1, 128)), small, True,
+            1.0, fa.flash_attention_plan(128, 128, 64, 1, 4, True), True)
+        # 13 MiB / (128 lanes * 8 B a float32 row) = 13312 rows: S
+        # beyond that splits
         big = jnp.zeros((1, 32768, 64))
-        fa._flash_bwd_pallas(big, big, big, big,
-                             jnp.zeros((1, 32768)), big, True, 1.0,
-                             1024, 1024, True)
+        fa._flash_bwd_pallas(
+            big, big, big, big, jnp.zeros((1, 32768)), big, True, 1.0,
+            fa.flash_attention_plan(32768, 32768, 64, 1, 4, True), True)
         assert calls == ["fused", "split"]

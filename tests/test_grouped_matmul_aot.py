@@ -82,3 +82,40 @@ def test_the_kernel_compiles_for_a_v5e(shape, one_chip):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == 4 * m * n
     assert mem.temp_size_in_bytes < 2 * k * n * 2 + 4 * m * n
+
+
+# The flash-attention kernels ride in this file because ONE worker holds
+# libtpu: a second file of such compiles can land on another worker, where
+# its fixture skips every test.
+FLASH = {
+    # gpt2m-train's own: 128 batch-heads, one cell a batch-head
+    "train_cell_d64": (128, 1024, 1024, 64, jnp.bfloat16),
+    # the longest sequence that is still one cell, at the widest head
+    "s4096_d128": (4, 4096, 4096, 128, jnp.bfloat16),
+    # several cells a batch-head: the walk under program_id's predicates
+    "s8192_d64": (2, 8192, 8192, 64, jnp.bfloat16),
+    # a head width that still pads, bottom-right alignment, float32
+    "d80_cross_f32": (4, 512, 1024, 80, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", list(FLASH))
+def test_the_flash_kernels_compile_for_a_v5e(shape, one_chip):
+    """`jax.grad` of a causal call at the plan's own tiles: one forward
+    and one fused backward Mosaic kernel, nothing refused by the chip's
+    compiler (an unaligned slice, VMEM past the kernel's limit). A
+    compile that passes is not a chip run."""
+    from bigdl_tpu.ops.flash_attention import flash_attention
+
+    bh, sq, sk, dim, dtype = FLASH[shape]
+    q = jax.ShapeDtypeStruct((bh, sq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((bh, sk, dim), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               impl="pallas").astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd_fused" in text

@@ -74,6 +74,26 @@ def test_flash_backward_split(monkeypatch):
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
+_TENSOR = re.compile(r"tensor<((?:\d+x)+)(?:bf16|f32)>")
+
+
+def test_flash_kernels_cross_hbm_at_the_heads_own_width():
+    """gpt2m-train's attention (D = 64, bfloat16, causal): one forward
+    and one fused backward kernel, and no operand or result of either is
+    128 lanes wide — neither a padded head nor a lane-broadcast lse can
+    come back unseen."""
+    text = jax.jit(jax.grad(_attn_loss, argnums=(0, 1, 2))).trace(
+        *_qkv(seq=1024)).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [l for l in text.splitlines() if "@tpu_custom_call" in l]
+    assert sorted(_NAME.search(l).group(1) for l in calls) == [
+        "flash_bwd_fused", "flash_fwd"]
+    for line in calls:
+        shapes = [tuple(int(n) for n in m.group(1).split("x") if n)
+                  for m in _TENSOR.finditer(line)]
+        assert len(shapes) >= 5, line[:200]
+        assert all(shape[-1] in (64, 1024) for shape in shapes), shapes
+
+
 @pytest.mark.parametrize("scan,args,family", [
     (fr.lstm_scan, (_f32(16, 4, 512), _f32(128, 512)), "fused_lstm"),
     (fr.bilstm_scan, (_f32(16, 4, 512), _f32(16, 4, 512), _f32(128, 512),
